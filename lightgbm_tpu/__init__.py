@@ -1,6 +1,6 @@
 """lightgbm_tpu: a TPU-native gradient-boosting framework.
 
-A from-scratch JAX/XLA/Pallas re-design of the LightGBM GBDT framework
+A from-scratch JAX/XLA re-design of the LightGBM GBDT framework
 (reference: /root/reference) for TPU hardware: the tree learner is a fully
 device-resident jitted program (histograms on the MXU, vectorized split
 scans, row->leaf partition vector), distributed training uses XLA

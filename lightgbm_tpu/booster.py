@@ -124,8 +124,7 @@ class Booster:
         self.config = Config(params or {})
         # persistent-compile-cache bring-up + compile counters: every
         # training Booster warm-starts its jit compiles from (and
-        # contributes to) the on-disk cache unless compile_cache=false;
-        # a pre-set JAX_COMPILATION_CACHE_DIR is respected
+        # contributes to) the on-disk cache unless compile_cache=false
         from .utils.compile_cache import maybe_enable_from_config
         maybe_enable_from_config(self.config)
         # reference _update_params semantics (basic.py: train-time params

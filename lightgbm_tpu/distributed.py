@@ -98,10 +98,24 @@ def run(entry: str, num_workers: int = 2, *,
       this process, must start them); default localhost spawning.
     backend: "cpu" pins workers to the CPU backend with gloo collectives
       (the test topology; also what the reference's distributed tests
-      do over localhost sockets); "" leaves device selection to JAX
-      (TPU pod workers).
+      do over localhost sockets); "" leaves device selection to JAX and
+      is for one worker per HOST of a real cluster — on localhost it is
+      refused (see below).
     """
-    if hosts is not None and set(hosts) - {"127.0.0.1", "localhost"}:
+    local = hosts is None or not (set(hosts) - {"127.0.0.1", "localhost"})
+    if local and backend != "cpu":
+        # a chip belongs to one process: N local workers that leave
+        # device selection to JAX would each try to claim every chip of
+        # this host, and all but the first fail or hang
+        raise ValueError(
+            f"distributed.run(backend={backend!r}) would start "
+            f"{num_workers} processes on this host, each claiming every "
+            "local chip; a chip belongs to one process.  The multi-chip "
+            "topology of one host is ONE process over an in-process "
+            "mesh: call lgb.train with tree_learner=data|feature|voting "
+            "(GBDTModel._resolve_mesh).  backend=\"cpu\" runs the "
+            "local N-process topology on CPU devices.")
+    if not local:
         ports = [base_port or 12400] * len(hosts)
         machines = build_machines(hosts, ports)
         lines = [

@@ -235,7 +235,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
       (serial_tree_learner.cpp:283-323 smaller-leaf discipline;
       cuda_histogram_constructor's leaf-indexed construction) instead of a
       full-N masked pass.  Below ``min_gather_rows`` tiers stop (compile
-      cost isn't worth it).  DEFAULT OFF: measured on TPU v5e (PROFILE.md)
+      cost isn't worth it).  DEFAULT OFF: measured on TPU v5e
       XLA's row gather costs ~22 ns/row and ``nonzero`` ~3 ms/1M rows, so
       the tiered path is ~2.4x SLOWER than the masked full pass it tries
       to avoid; it also multiplies compile time by the tier count.
@@ -292,7 +292,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
       one.  Each step picks the top-K leaves by cached best gain, applies
       all K splits in one row-partition pass, and builds all K smaller
       children's histograms in ONE one-hot contraction with C=3K channels.
-      PROFILE.md §2-6: the histogram matmul is sublane-bound at M=3 (3 of
+      The histogram matmul is sublane-bound at M=3 (3 of
       8 sublanes, ~4.6 TFLOP/s ceiling), so batching K leaves raises the
       ceiling ~K× while amortizing the one-hot generation — per-split cost
       drops toward 1/K.  Trees differ slightly from strict leaf-wise

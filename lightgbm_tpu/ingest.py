@@ -22,7 +22,7 @@ Pipeline, per chunk (:class:`IngestRunner`):
    resumed model text equals the uninterrupted run's).
 2. **Read + parse** under ``resilience.retry_call`` (jittered backoff,
    ``ingest_retries``) and a raise-mode ``resilience.Watchdog``
-   (``ingest_read_timeout_s``): a reader wedged on a dead filesystem is
+   (``ingest_read_timeout_s``): a reader hung on a dead filesystem is
    abandoned at the deadline and the WatchdogTimeout — like any
    transient read error — is retried; exhaustion raises
    ``ElasticFailure("ingest", ...)`` so the elastic recovery ladder

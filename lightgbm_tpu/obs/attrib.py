@@ -8,9 +8,8 @@ already exist separately:
 
 - ``flops.total`` / ``flops.hbm_bytes`` counters (obs/flops.py ledger,
   recorded per iteration by ``ObsSession.record_flops``),
-- ``train.phase_seconds{phase=...}`` histograms (the fenced spans
-  PROFILE.md's methodology mandates — wall time attributed to the
-  phase that queued the work),
+- ``train.phase_seconds{phase=...}`` histograms (fenced spans: wall
+  time attributed to the phase that queued the work),
 - the peak table below (extending the one bench.py used to carry
   privately, with HBM bandwidth added so the roofline has both axes).
 

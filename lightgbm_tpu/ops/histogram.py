@@ -114,8 +114,8 @@ def compute_histogram(binned: jax.Array, vals: jax.Array, *, num_bins: int,
     hand-written Pallas kernel was built and measured SLOWER on TPU v5e
     (8.2 vs 4.7 ms/pass at 1M x 28 x 64 bins: XLA fuses the one-hot
     generation into the dot's operand load better than the explicit
-    kernel, and the matmul already sits at the M-axis sublane ceiling
-    PROFILE.md documents), so it was removed rather than shipped as dead
+    kernel, and the matmul already sits at the M-axis sublane
+    ceiling), so it was removed rather than shipped as dead
     code; the batched multi-leaf contraction (grower.py split_batch) is
     the path past that ceiling.
     """
@@ -214,10 +214,17 @@ def _compute_histogram_matmul(binned: jax.Array, vals: jax.Array, *,
             .astype(op_dt).reshape(block_rows, f * bp)
         # [C, block] x [block, F*Bp] -> [C, F*Bp]: the narrow C=3 axis maps
         # to output SUBLANES (padded 3->8) instead of lanes (3->128), a
-        # measured ~2.2x win over the transposed orientation
+        # measured ~2.2x win over the transposed orientation.
+        # HIGHEST for f32 accumulands: at default precision a TPU rounds
+        # f32 matmul operands to bf16 (8 mantissa bits), so every grad and
+        # hess would be rounded before it is summed (observed on v5e:
+        # rows of 1 + 2^-12 sum to the row count).  The 0/1 operand is
+        # exact either way; HIGHEST carries all 24 bits of the accumuland
+        # into the f32 accumulator.  Integer operands are exact as is.
         h = lax.dot_general(
             vals_blk, onehot,
             dimension_numbers=(((0,), (0,)), ((), ())),
+            precision=None if integer else lax.Precision.HIGHEST,
             preferred_element_type=acc_dt)
         return acc + h, None
 

@@ -49,7 +49,6 @@ from ..obs.comm import CommLedger
 from ..ops.histogram import pad_feature_axis
 from ..ops.split import (SplitParams, SplitResult, gather_best,
                          globalize_feature)
-from ..utils.jax_compat import shard_map
 from ..utils.memo import memo_get_or_build
 from .mesh import owner_shard_plan
 
@@ -139,7 +138,7 @@ def make_dp_grower(mesh: Mesh, *, num_leaves: int, num_bins: int,
     is set) and vals [N, 3] sharded on rows; feature metadata replicated.
     Output tree arrays are replicated; ``leaf_of_row`` stays row-sharded.
     Child histograms use the masked full pass (gather tiers measured slower
-    on TPU — PROFILE.md §2), which also keeps every shard's collective
+    on TPU), which also keeps every shard's collective
     schedule trivially congruent.
 
     owner_shard=True (default): reduce-scatter + owned-slice split scan +
@@ -321,8 +320,8 @@ def _make_dp_owner_grower(mesh: Mesh, *, num_leaves, num_bins, params,
             in_specs = (P(axis, None), P(axis, None),
                         P(), P(), P(), P(), P(), P(), P())
 
-        fn = jax.jit(shard_map(wrapped, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False))
+        fn = jax.jit(jax.shard_map(wrapped, mesh=mesh, in_specs=in_specs,
+                                   out_specs=out_specs, check_vma=False))
         return fn, plan
 
     def grow(binned, vals, feature_mask, num_bin, na_bin, is_cat=None,
@@ -398,7 +397,7 @@ def _make_dp_psum_grower(mesh: Mesh, *, num_leaves, num_bins, params,
                 return inner(SparseBinned(flat, db, stride, nf), vals,
                              fm, nb, nab, nabp, ic, rng_iter=ri,
                              max_leaves=ml)
-            return shard_map(
+            return jax.shard_map(
                 wrapped, mesh=mesh,
                 in_specs=(P(axis, None), P(None), P(axis, None),
                           P(), P(), P(), P(), P(), P(), P()),
@@ -427,7 +426,7 @@ def _make_dp_psum_grower(mesh: Mesh, *, num_leaves, num_bins, params,
         # must match that arity, not inner's
         return inner(b, v, fm, nb, na, na, ic, rng_iter=ri, max_leaves=ml)
 
-    f = shard_map(
+    f = jax.shard_map(
         _dense, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(), P(), P(), P(), P(),
                   P()),

@@ -2,8 +2,7 @@
 shrink-to-survive recovery (ROADMAP item 2, robustness half).
 
 The distributed learners (``parallel/``) had no mid-run failure story:
-a preempted host or wedged TPU claim — the failure that cost the TPU
-claim in 4 of 5 bench rounds (r03–r05) — hangs every ``psum`` /
+a preempted host or a hung device claim hangs every ``psum`` /
 ``psum_scatter`` forever, and the only resilience was bring-up retries
 plus ``dist_fallback_serial`` BEFORE training starts.  At the scale the
 distributed-GBDT literature assumes (arXiv:1804.06755 billions of
@@ -26,7 +25,7 @@ loop's one per-iteration host fetch (the point where every queued
 collective actually blocks — async dispatch means a hung ``psum``
 materializes at the ``device_get``) through
 ``resilience.Watchdog(on_timeout="raise")``: past
-``elastic_collective_timeout_s`` the wedged fetch is stack-dumped,
+``elastic_collective_timeout_s`` the hung fetch is stack-dumped,
 abandoned, and surfaced as ``ElasticFailure("collective_timeout")``.
 The device claim gets the same treatment in
 ``GBDTModel._resolve_mesh`` (``claim_wedge``).
@@ -486,7 +485,7 @@ def _on_failure(exc: ElasticFailure, site: str = "") -> None:
 
 def guarded_call(fn: Callable, timeout_s: float, site: str):
     """Run a blocking collective-backed call under the elastic
-    deadline: past ``timeout_s`` the wedged call is stack-dumped,
+    deadline: past ``timeout_s`` the hung call is stack-dumped,
     abandoned in its daemon worker, and re-raised in the caller as
     ``ElasticFailure("collective_timeout")``.  ``timeout_s <= 0`` runs
     plain.  Shared by :func:`guarded_get` (the per-iteration fetch) and
@@ -671,7 +670,7 @@ def elastic_train(params: dict, x, y=None, *, weight=None,
             try:
                 n = len(jax.local_devices()) if pc > 1 else \
                     len(jax.devices())
-            except Exception:   # noqa: BLE001 — a wedged claim: go serial
+            except Exception:   # noqa: BLE001 — a hung claim: go serial
                 return 1
             req = _requested_devices(cfg0)
             if req is not None:

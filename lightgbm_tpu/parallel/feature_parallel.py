@@ -37,7 +37,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..grower import TreeArrays, make_grower
 from ..obs.comm import CommLedger
 from ..ops.split import SplitParams, SplitResult, gather_best
-from ..utils.jax_compat import shard_map
 from ..utils.memo import memo_get_or_build
 
 # process-level memo of jitted feature-parallel growers (the voting
@@ -133,7 +132,7 @@ def _build(mesh: Mesh, *, num_features, num_leaves, num_bins, params,
         return inner(binned, vals, fm, nb, na, nabp, ic, rng_iter=ri,
                      max_leaves=ml)
 
-    f = shard_map(
+    f = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(P(None, None), P(None, None), P(axis), P(axis), P(axis),
                   P(None), P(axis), P(), P()),

@@ -73,7 +73,7 @@ def init(coordinator_address: Optional[str] = None,
     connect loop the same way, network/linkers_socket.cpp):
     ``retries`` jittered-backoff re-attempts for classified-transient
     failures (UNAVAILABLE, timeouts, refused connections), a hard
-    ``timeout_s`` deadline, and a faulthandler watchdog so a wedged
+    ``timeout_s`` deadline, and a faulthandler watchdog so a hung
     bring-up dumps stacks instead of hanging silently.  Fatal errors
     (bad arguments) surface immediately.
 

@@ -41,7 +41,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..grower import TreeArrays, make_grower
 from ..obs.comm import CommLedger
 from ..ops.split import SplitParams, dequantize_hist
-from ..utils.jax_compat import shard_map
 from ..utils.memo import memo_get_or_build
 
 # process-level memo of jitted voting growers (same role as grower.py's
@@ -171,7 +170,7 @@ def _build(mesh: Mesh, *, num_leaves, num_bins, params, top_k, max_depth,
         return inner(binned, vals, fm, nb, na, nabp, ic, rng_iter=ri,
                      max_leaves=ml)
 
-    f = shard_map(
+    f = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(), P(), P(), P(), P(),
                   P(), P()),

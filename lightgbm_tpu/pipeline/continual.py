@@ -214,7 +214,7 @@ def shadow_parity_probe(candidate, incumbent, batches: List[np.ndarray],
     scored within the objective-aware tolerance and the eval metric did
     not regress past ``shadow_probe_metric_tolerance``.  A probe that
     exceeds ``timeout_s`` (``continual_timeout_s``) is a FAILURE, not a
-    wait — a wedged candidate must roll back, not stall freshness."""
+    wait — a hung candidate must roll back, not stall freshness."""
     result: Dict[str, Any] = {}
 
     def _run() -> None:

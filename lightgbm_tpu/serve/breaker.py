@@ -3,7 +3,7 @@ side is failing.
 
 Retry (``serve_retries``) protects ONE batch from a transient blip; the
 breaker protects the SERVICE from a dependency that is actually down
-(device wedged, backend gone — the round-5 outage shape).  Without it,
+(device hung, backend gone).  Without it,
 every incoming request queues, waits out the full retry schedule, and
 fails — the bounded queue stays pinned at capacity doing work that
 cannot succeed.  With it, ``serve_breaker_failures`` consecutive batch

@@ -165,7 +165,10 @@ def histogram(sp: SparseBinned, vals: jax.Array, *, num_bins: int,
     if slot is not None:
         oh = (slot[:, None] == jnp.arange(num_slots, dtype=jnp.int32)) \
             .astype(jnp.float32)
+        # HIGHEST: at default precision a TPU rounds the f32 accumulands
+        # to bf16 before the sum (see ops/histogram.py)
         tot = lax.dot_general(vals, oh, (((0,), (0,)), ((), ())),
+                              precision=lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)  # [cv, S]
         tot = tot.reshape(cv * s)
     else:

@@ -1,25 +1,19 @@
 """Persistent XLA compilation cache + process-wide compile accounting.
 
 Two concerns live here because they are two halves of one feature —
-making compile time a managed, *measured* resource (ROADMAP item 4:
-BENCH_r02 paid 73.4 s of compile before the first iteration vs 84 s of
-steady state for 99 iterations):
+making compile time a managed, *measured* resource:
 
 1. :func:`enable_persistent_cache` points jax at an on-disk compilation
-   cache so later processes on the same host warm-start every compile
-   (train -> serve included).  The cache directory is keyed by the
-   host's CPU feature fingerprint because XLA:CPU AOT entries are
-   machine-specific and this can run in environments that migrate
-   between heterogeneous hosts — a cache written on one host fails
-   every load on another ("Target machine feature ... is not
-   supported"), costing the failed loads on top of the recompiles
-   (measured: 25 cold minutes for the test suite).  A user's pre-set
-   ``JAX_COMPILATION_CACHE_DIR`` (or an explicit ``compile_cache_dir``
-   param) is RESPECTED, never clobbered.  Config wiring:
-   ``compile_cache`` / ``compile_cache_dir`` /
-   ``compile_cache_min_compile_s`` / ``compile_cache_min_entry_bytes``
-   (engine.train / Booster / cli / serve bring-up via
-   :func:`maybe_enable_from_config`).
+   cache so later processes warm-start every compile (train -> serve
+   included).  The directory is chosen so that whoever runs the program
+   can place it from outside and every process agrees on it:
+   ``JAX_COMPILATION_CACHE_DIR`` when set, else the ``compile_cache_dir``
+   param, else ``<checkout>/.jax_cache`` (:func:`default_cache_dir`) —
+   the path is part of jax's cache key, so a directory that differs
+   between processes never hits.  Config wiring: ``compile_cache`` /
+   ``compile_cache_dir`` / ``compile_cache_min_compile_s`` /
+   ``compile_cache_min_entry_bytes`` (engine.train / Booster / cli /
+   serve bring-up via :func:`maybe_enable_from_config`).
 
 2. :func:`install_compile_counters` + :func:`trace_event` make
    warm-start observable instead of assumed: process-global counters of
@@ -33,64 +27,45 @@ steady state for 99 iterations):
 
 from __future__ import annotations
 
-import getpass
-import hashlib
 import os
-import tempfile
 import threading
 from typing import Dict, Optional
 
-
-def machine_tag() -> str:
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith("flags"):
-                    return hashlib.sha256(line.encode()).hexdigest()[:10]
-    except OSError:
-        pass
-    import platform
-    return hashlib.sha256(platform.processor().encode()).hexdigest()[:10]
-
-
 def default_cache_dir() -> str:
-    """The per-user, per-host-fingerprint cache path used when neither
-    the caller nor the environment chose one."""
-    return os.path.join(
-        tempfile.gettempdir(),
-        f"lgbtpu_jax_cache_{getpass.getuser()}_{machine_tag()}")
+    """``<checkout>/.jax_cache``: beside the package, identical in every
+    process and on every host that runs this checkout."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".jax_cache")
 
 
-def configured_cache_dir():
-    """The cache dir jax is ALREADY configured with (from a previous
-    enable, a user's ``jax.config.update``, or the
-    ``JAX_COMPILATION_CACHE_DIR`` env var), or None."""
-    try:
-        import jax
-        d = jax.config.jax_compilation_cache_dir
-    except Exception:
-        d = None
-    return d or os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+def resolve_cache_dir(param_dir: Optional[str] = None) -> str:
+    """The one directory rule shared by the compile cache and
+    ``hist_tune.json`` (ops/hist_tune.py): ``JAX_COMPILATION_CACHE_DIR``
+    > the ``compile_cache_dir`` param > :func:`default_cache_dir`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or param_dir \
+        or default_cache_dir()
 
 
 def enable_persistent_cache(min_compile_secs: float = 0.5,
                             cache_dir: Optional[str] = None,
                             min_entry_bytes: int = 0) -> str:
-    """Enable the persistent compilation cache; returns the path used.
+    """Enable the persistent compilation cache; returns the path used
+    (:func:`resolve_cache_dir` of ``cache_dir``).
 
-    Precedence for the directory: explicit ``cache_dir`` argument >
-    an already-configured dir (jax config or the
-    ``JAX_COMPILATION_CACHE_DIR`` env var — a user's choice is
-    respected, not clobbered) > the per-host default.  The persistence
-    thresholds are parameters (they used to be hardwired to
-    ``min_entry_size=0``, silently overriding a user's tuning), and a
-    threshold pinned via its jax env var
+    jax opens its cache once, on the first compile, and decides then
+    whether the process uses one at all; a directory set afterwards is
+    ignored.  So when the resolved path differs from what jax holds the
+    cache is reset and reopens at the new path on the next compile.
+
+    A persistence threshold pinned via its jax env var
     (``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` /
-    ``JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES``) is likewise left
-    alone."""
+    ``JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES``) is left alone."""
     import jax
-    path = cache_dir or configured_cache_dir() or default_cache_dir()
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = resolve_cache_dir(cache_dir)
+    if jax.config.jax_compilation_cache_dir != path:
+        from jax.experimental.compilation_cache import compilation_cache
+        jax.config.update("jax_compilation_cache_dir", path)
+        compilation_cache.reset_cache()
     if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(min_compile_secs))
@@ -122,11 +97,14 @@ def maybe_enable_from_config(config) -> Optional[str]:
 # Process-wide compile accounting
 # ---------------------------------------------------------------------------
 
-# jax.monitoring event names this build of jax emits (jax 0.4.x:
-# jax/_src/dispatch.py BACKEND_COMPILE_EVENT, jax/_src/compiler.py /
-# compilation_cache.py cache hit/miss record_event calls).  Matched by
-# substring so a renamed prefix degrades to "not counted", never to a
-# crash.
+# jax.monitoring event names jax 0.9.0 emits:
+# /jax/core/compile/backend_compile_duration (jax/_src/dispatch.py
+# BACKEND_COMPILE_EVENT), /jax/compilation_cache/cache_hits
+# (jax/_src/compiler.py) and /jax/compilation_cache/cache_misses
+# (jax/_src/compilation_cache.py).  Matched by substring; a renamed
+# event counts nothing, which chip_smoke.py and
+# tests/test_compile_cache.py refuse (both require non-zero counters
+# after a compile).
 _BACKEND_COMPILE = "backend_compile"
 _CACHE_HIT = "cache_hits"
 _CACHE_MISS = "cache_misses"

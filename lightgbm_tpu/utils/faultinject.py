@@ -18,8 +18,8 @@ Spec grammar — comma-separated ``site:hits[:action]`` entries:
   normal ``except Exception`` recovery cannot swallow — simulates the
   process dying at the site), ``exit`` (``os._exit(23)``, a REAL
   death for subprocess tests), or ``hang`` (the site blocks for
-  ``LGBM_TPU_FAULT_HANG_S`` seconds, default 30 — the wedged-collective
-  / wedged-claim simulation the elastic deadline layer exists to
+  ``LGBM_TPU_FAULT_HANG_S`` seconds, default 30 — the hung-collective
+  / hung-claim simulation the elastic deadline layer exists to
   bound; the sleeping thread is abandoned by the watchdog exactly like
   a real wedge), or ``bitflip`` (one deterministic bit of the named
   device array flips at the site — only meaningful at the SDC sites
@@ -87,7 +87,7 @@ Sites wired into the codebase:
                     mismatch class, not transient): quarantined per
                     ``ingest_bad_chunk``, never retried
 ``ingest_hang``     inside the chunk read (``ingest.IngestRunner``) —
-                    default action ``hang``: a reader wedged on a dead
+                    default action ``hang``: a reader hung on a dead
                     filesystem; the ``ingest_read_timeout_s`` watchdog
                     must abandon + classify it
 ``hist_sdc``        silent-data-corruption injection into the grower's
@@ -105,10 +105,6 @@ Sites wired into the codebase:
                     ``bitflip``; exercises the integrity layer's
                     score-path verification
 ==================  ========================================================
-
-Also exercisable from ``tools/tpu_watch.py`` probes: export
-``LGBM_TPU_FAULTS`` before starting the watcher and the probe child
-inherits it (its retry/backoff attempts are logged to the watch log).
 """
 
 from __future__ import annotations
@@ -334,5 +330,5 @@ def maybe_bitflip(site: str, arr, index: Optional[int] = None):
     return flat.reshape(jnp.shape(arr))
 
 
-# arm from the environment at import (subprocess tests / tpu_watch probes)
+# arm from the environment at import (subprocess tests)
 configure(os.environ.get(ENV_VAR))

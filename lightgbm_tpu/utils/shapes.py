@@ -1,9 +1,8 @@
 """Shared shape-bucketing policy for trace-relevant static dimensions.
 
 Every distinct static shape that reaches a jitted program is a fresh
-XLA trace + compile — BENCH_r02 measured 73 s of compile before the
-first training iteration, rivaling 99 iterations of steady state
-(ROADMAP item 4).  ``serve/engine.py`` already proved the fix for the
+XLA trace + compile, and compile before the first training iteration
+can rival the steady-state work of a short run.  ``serve/engine.py`` already proved the fix for the
 serving batch axis: round the dimension up to a power-of-two bucket so
 one trace covers a family of sizes.  This module is that policy
 extracted so every layer buckets the same way:
@@ -54,7 +53,7 @@ LEAF_BUCKET_FLOOR = 64
 
 # the shipped split_batch widths (grower super-step K): 1 = strict
 # leaf-wise reference growth, 8/16 = the measured MXU-sublane sweet
-# spots (PROFILE.md §2-6; models/gbdt.py auto-selection), 32/64 = the
+# spots (models/gbdt.py auto-selection), 32/64 = the
 # lane-padded wide widths (ROADMAP item 1: C = 3K channels bucket to
 # 128-lane tiles, ops/histogram.py) the on-device autotuner
 # (ops/hist_tune.py) selects from by measured ms/pass

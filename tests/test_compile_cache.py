@@ -1,9 +1,9 @@
 """Compile-wall management (ROADMAP item 4; docs/Compile-Cache.md):
 
 - shared shape-bucketing policy units (utils/shapes.py);
-- persistent-cache bring-up respects a pre-configured directory and
-  parameterizes the persistence thresholds (the old helper clobbered
-  both);
+- persistent-cache placement: JAX_COMPILATION_CACHE_DIR, else the
+  compile_cache_dir param, else <checkout>/.jax_cache, the same in every
+  process; the persistence thresholds are parameters;
 - the leaf-budget bucket: num_leaves 31/40/63 train through ONE padded
   L=64 grower trace with models byte-identical to the unbucketed
   per-shape path, across strict/batched growth and bagging/GOSS;
@@ -15,6 +15,11 @@ budget lint subprocess, dp parity) live in tests/test_zretrace.py —
 they spawn fresh interpreters and run late in the suite.
 """
 
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -23,6 +28,9 @@ from lightgbm_tpu.utils import shapes
 from lightgbm_tpu.utils.compile_cache import (compile_stats,
                                               enable_persistent_cache,
                                               trace_counts)
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _tree_text(model_str: str) -> str:
@@ -93,37 +101,132 @@ class TestShapes:
         assert eng._bucket(500) == shapes.bucket_rows(500, 16, 64) == 64
 
 
-class TestPersistentCacheConfig:
-    def test_respects_preconfigured_dir(self, tmp_path):
-        """The old enable unconditionally overwrote
-        jax_compilation_cache_dir; a pre-set dir must now win unless an
-        explicit cache_dir is passed."""
-        import jax
-        before = jax.config.jax_compilation_cache_dir
-        try:
-            mine = str(tmp_path / "pre")
-            jax.config.update("jax_compilation_cache_dir", mine)
-            assert enable_persistent_cache() == mine
-            assert jax.config.jax_compilation_cache_dir == mine
-            explicit = str(tmp_path / "explicit")
-            assert enable_persistent_cache(cache_dir=explicit) == explicit
-            assert jax.config.jax_compilation_cache_dir == explicit
-        finally:
-            jax.config.update("jax_compilation_cache_dir", before)
+# a fresh interpreter that trains two rounds and reports where jax's
+# cache, the tune table and the compile counters ended up
+_CHILD_TRAIN = """
+import json, sys
+import numpy as np
+import jax
+import lightgbm_tpu as lgb
+from lightgbm_tpu.ops.hist_tune import tune_dir
+from lightgbm_tpu.utils.compile_cache import compile_stats
+rs = np.random.RandomState(0)
+x = rs.randn(300, 5)
+y = (x[:, 0] > 0).astype(np.float32)
+p = {"objective": "binary", "num_leaves": 4, "max_bin": 15, "verbosity": -1,
+     "min_data_in_leaf": 5, "compile_cache_min_compile_s": 0.0}
+p.update(json.loads(sys.argv[1]))
+lgb.train(p, lgb.Dataset(x, label=y, params=p), num_boost_round=2)
+print(json.dumps({"cache_dir": jax.config.jax_compilation_cache_dir,
+                  "tune_dir": tune_dir(lgb.Config(p)),
+                  "stats": compile_stats()}))
+"""
 
-    def test_thresholds_are_parameters(self, tmp_path):
+_CHILD_DEFAULT = """
+import jax
+from lightgbm_tpu.utils.compile_cache import enable_persistent_cache
+assert enable_persistent_cache() == jax.config.jax_compilation_cache_dir
+print(jax.config.jax_compilation_cache_dir)
+"""
+
+
+def _child_env(**over):
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=REPO, **over)
+    return env
+
+
+@pytest.fixture(scope="module")
+def cache_children(tmp_path_factory):
+    """Three fresh interpreters, started together (each pays seconds of
+    imports): one trains with the variable set AND compile_cache_dir
+    passed, two only resolve the default from different directories."""
+    tmp = tmp_path_factory.mktemp("cache_children")
+    env_dir, param_dir = str(tmp / "env"), str(tmp / "param")
+
+    def spawn(script, *args, cwd, **env_over):
+        return subprocess.Popen(
+            [sys.executable, "-c", script, *args], env=_child_env(**env_over),
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+            cwd=cwd)
+
+    procs = {
+        "train": spawn(_CHILD_TRAIN,
+                       json.dumps({"compile_cache_dir": param_dir,
+                                   "tpu_learner": "masked"}),
+                       cwd=str(tmp), JAX_COMPILATION_CACHE_DIR=env_dir),
+        "default_tmp": spawn(_CHILD_DEFAULT, cwd=str(tmp)),
+        "default_repo": spawn(_CHILD_DEFAULT, cwd=REPO),
+    }
+    last = {}
+    for name, proc in procs.items():
+        out, err = proc.communicate(timeout=300)
+        assert proc.returncode == 0, (name, err[-2000:])
+        last[name] = out.strip().splitlines()[-1]
+    return {"env_dir": env_dir, "param_dir": param_dir, **last}
+
+
+class TestPersistentCacheConfig:
+    def test_precedence_env_over_param_over_default(self, tmp_path,
+                                                    monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR > compile_cache_dir > the checkout's
+        .jax_cache; conftest set the variable for the suite."""
         import jax
-        before = jax.config.jax_compilation_cache_dir
+        from lightgbm_tpu.utils.compile_cache import (default_cache_dir,
+                                                      resolve_cache_dir)
+        suite_dir = os.environ["JAX_COMPILATION_CACHE_DIR"]
+        explicit = str(tmp_path / "explicit")
+        try:
+            assert enable_persistent_cache(cache_dir=explicit) == suite_dir
+            assert jax.config.jax_compilation_cache_dir == suite_dir
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            assert enable_persistent_cache(
+                cache_dir=explicit, min_compile_secs=0.0) == explicit
+            assert jax.config.jax_compilation_cache_dir == explicit
+            # jax had already opened its cache at the suite's directory:
+            # the move must take effect, not only change the config value
+            jax.jit(lambda a: a * 3 + 1)(np.arange(7.0))
+            assert os.listdir(explicit)
+            assert resolve_cache_dir() == default_cache_dir() \
+                == os.path.join(REPO, ".jax_cache")
+        finally:
+            monkeypatch.undo()
+            enable_persistent_cache()
+        assert jax.config.jax_compilation_cache_dir == suite_dir
+
+    def test_env_var_outranks_compile_cache_dir_param(self,
+                                                      cache_children):
+        """With the variable set, a training process keeps its cache and
+        its tune table there even when compile_cache_dir is passed, and
+        the compile counters see the cache traffic (a renamed
+        jax.monitoring event would leave them at zero)."""
+        env_dir = cache_children["env_dir"]
+        rep = json.loads(cache_children["train"])
+        assert rep["cache_dir"] == env_dir and rep["tune_dir"] == env_dir
+        assert os.listdir(env_dir)
+        assert not os.path.exists(cache_children["param_dir"])
+        assert rep["stats"]["count"] > 0
+        assert rep["stats"]["cache_hits"] + rep["stats"]["cache_misses"] > 0
+
+    def test_default_dir_is_the_checkouts_in_every_process(self,
+                                                           cache_children):
+        """Without the variable two processes started from different
+        directories agree on <checkout>/.jax_cache."""
+        assert cache_children["default_tmp"] \
+            == cache_children["default_repo"] \
+            == os.path.join(REPO, ".jax_cache")
+
+    def test_thresholds_are_parameters(self):
+        import jax
         try:
             enable_persistent_cache(min_compile_secs=1.25,
-                                    cache_dir=str(tmp_path / "t"),
                                     min_entry_bytes=123)
             assert jax.config.jax_persistent_cache_min_compile_time_secs \
                 == 1.25
             assert jax.config.jax_persistent_cache_min_entry_size_bytes \
                 == 123
         finally:
-            jax.config.update("jax_compilation_cache_dir", before)
             enable_persistent_cache()     # restore conftest thresholds
 
     def test_config_rejects_negative_thresholds(self):

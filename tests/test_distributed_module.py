@@ -60,6 +60,15 @@ def test_multi_host_emits_commands():
     assert "--machines 10.0.0.1:12400,10.0.0.2:12400" in msg
 
 
+@pytest.mark.parametrize("hosts", [None, ["127.0.0.1", "localhost"]])
+def test_local_workers_refused_unless_pinned_to_cpu(hosts):
+    """N local processes that leave device selection to JAX would each
+    claim every chip of the host: refused before anything is spawned."""
+    with pytest.raises(ValueError, match="a chip belongs to one process"):
+        distributed.run("dist_worker:worker", num_workers=2, hosts=hosts,
+                        backend="")
+
+
 ESTIMATOR_PARAMS = dict(num_leaves=15, max_bin=63, min_data_in_leaf=5,
                         n_estimators=8, verbosity=-1)
 

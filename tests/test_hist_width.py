@@ -469,9 +469,13 @@ class TestAutotuner:
         key = next(iter(table))
         assert "kmax32" in key and table[key]["k"] == r1["k"]
 
-    def test_booster_hist_tune_on_uses_choice(self, data, tmp_path):
+    def test_booster_hist_tune_on_uses_choice(self, data, tmp_path,
+                                              monkeypatch):
         from lightgbm_tpu.ops import hist_tune
         x, y = data
+        # a private directory: the param only places the table where
+        # JAX_COMPILATION_CACHE_DIR (set by conftest) does not
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         d = str(tmp_path / "cache")
         c0 = hist_tune.tune_counts()["sweeps"]
         bst = _train(x, y, rounds=2, hist_tune="on",
@@ -500,7 +504,8 @@ class TestAutotuner:
         with pytest.raises(Exception):
             _train(x, y, rounds=1, hist_tune="sometimes")
 
-    def test_explicit_split_batch_wins_over_tuner(self, data, tmp_path):
+    def test_explicit_split_batch_wins_over_tuner(self, data, tmp_path,
+                                                  monkeypatch):
         """An explicit width is the user's choice: the tuner engages
         only for split_batch=0 — with an explicit width it must not
         even sweep (a tuned block_rows paired to a different K would
@@ -508,6 +513,9 @@ class TestAutotuner:
         pins)."""
         from lightgbm_tpu.ops import hist_tune
         x, y = data
+        # a private directory: the param only places the table where
+        # JAX_COMPILATION_CACHE_DIR (set by conftest) does not
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         d = str(tmp_path / "cache")
         c0 = hist_tune.tune_counts()["sweeps"]
         a = _train(x, y, split_batch=16, hist_tune="on",
@@ -518,11 +526,15 @@ class TestAutotuner:
         assert _strip_params(a.model_to_string()) == \
             _strip_params(b.model_to_string())
 
-    def test_tiny_budget_skips_sweep_cleanly(self, data, tmp_path):
+    def test_tiny_budget_skips_sweep_cleanly(self, data, tmp_path,
+                                             monkeypatch):
         """num_leaves <= 8 admits no set width: hist_tune=on must skip
         the sweep (not crash-and-warn every fit) and train strict."""
         from lightgbm_tpu.ops import hist_tune
         x, y = data
+        # a private directory: the param only places the table where
+        # JAX_COMPILATION_CACHE_DIR (set by conftest) does not
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         d = str(tmp_path / "cache")
         c0 = hist_tune.tune_counts()["sweeps"]
         a = _train(x, y, rounds=2, num_leaves=5, split_batch=0,

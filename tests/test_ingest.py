@@ -12,7 +12,7 @@ Pinned contracts:
 - A loader killed between chunk commits resumes from the manifests and
   trains a byte-identical model vs an uninterrupted run.
 - Transient read errors retry; corrupt chunks quarantine per
-  ``ingest_bad_chunk``; a wedged reader classifies as
+  ``ingest_bad_chunk``; a hung reader classifies as
   ``ElasticFailure("ingest")`` within the deadline; a torn allgather
   payload raises a classified PayloadIntegrityError, never raw
   unpickle behavior.

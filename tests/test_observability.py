@@ -110,7 +110,7 @@ class TestTelemetryOff:
 
     def test_device_get_count_per_iteration_unchanged(self, monkeypatch):
         """The masked per-iteration path performs exactly ONE batched
-        ``device_get`` per update (the small tree fetch — PROFILE.md's
+        ``device_get`` per update (the small tree fetch — the
         'fetch' phase); telemetry=false must not add any."""
         import jax
         x, y = _small_data()
@@ -299,15 +299,14 @@ class TestComm:
         # registration happens at trace time, idempotently
         from jax.sharding import PartitionSpec as P
         from lightgbm_tpu.parallel import make_mesh
-        from lightgbm_tpu.utils.jax_compat import shard_map
         import jax.numpy as jnp
         mesh = make_mesh((8,), ("data",))
 
         def f(x):
             return led.psum(x, "data", site="t.sum")
 
-        g = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("data"),),
-                              out_specs=P()))
+        g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("data"),),
+                                  out_specs=P()))
         out = g(jnp.ones(16, jnp.float32))
         assert float(out[0]) == 8.0
         (site,) = led.sites()
@@ -431,20 +430,24 @@ class TestSession:
         cfg = Config({"telemetry_profile_iters": [5]})
         assert cfg.telemetry_profile_iters == [5]
 
-    def test_profiler_window_failure_is_nonfatal(self, tmp_path,
-                                                 monkeypatch):
-        from lightgbm_tpu.obs.profiler import ProfilerWindow
+    def test_profile_capture_that_cannot_start_raises(self, tmp_path,
+                                                      monkeypatch):
+        """A capture the user asked for (telemetry_profile_iters) whose
+        start_trace fails ends the run; it is not logged and skipped."""
         import jax.profiler as jp
 
         def boom(*a, **kw):
             raise RuntimeError("no profiler service")
 
         monkeypatch.setattr(jp, "start_trace", boom)
-        w = ProfilerWindow(0, 1, str(tmp_path / "prof"))
-        w.on_iter_begin(0)                 # must not raise
-        assert w._dead and not w.active
-        w.on_iter_end(0)
-        w.finish()
+        x, y = _small_data()
+        params = {"objective": "binary", "num_leaves": 7, "max_bin": 31,
+                  "min_data_in_leaf": 5, "verbosity": -1,
+                  "telemetry": True, "telemetry_profile_iters": [1, 1],
+                  "telemetry_trace_file": str(tmp_path / "t.jsonl")}
+        with pytest.raises(RuntimeError, match="no profiler service"):
+            lgb.train(params, lgb.Dataset(x, label=y, params=params),
+                      num_boost_round=3)
 
 
 class TestLogTelemetryCallback:

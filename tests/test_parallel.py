@@ -137,7 +137,6 @@ class TestOwnerShard:
         # the per-shard histogram state after the reduce is the owned
         # [ceil(F/8), B, 3] chunk of the GLOBAL histogram — the shape
         # assertion behind the [L, F/n_shards, B, 3] grower carry
-        from lightgbm_tpu.utils.jax_compat import shard_map
         from jax.sharding import PartitionSpec as P
         F, B = 11, 16
         plan = owner_shard_plan(np.arange(F), 8)
@@ -146,7 +145,7 @@ class TestOwnerShard:
         rng = np.random.RandomState(0)
         local = rng.rand(8, F, B, 3).astype(np.float32)  # per-shard hists
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda h: red(h[0]), mesh=mesh8,
             in_specs=(P("data", None, None, None),),
             out_specs=P("data", None, None), check_vma=False))

@@ -241,7 +241,6 @@ class TestInt32HistogramIdentity:
         from jax.sharding import PartitionSpec as P
         from lightgbm_tpu.ops.histogram import compute_histogram
         from lightgbm_tpu.parallel import make_mesh
-        from lightgbm_tpu.utils.jax_compat import shard_map
 
         n, f, b = 512, 5, 16
         binned = _rs.randint(0, b, size=(n, f)).astype(np.uint8)
@@ -263,7 +262,7 @@ class TestInt32HistogramIdentity:
             return lax.psum(compute_histogram(bb, qq, num_bins=b),
                             "data")
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             shard_fn, mesh=mesh, in_specs=(P("data"), P("data")),
             out_specs=P(), check_vma=False))
         sharded = np.asarray(fn(binned, vals))

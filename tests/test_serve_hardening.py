@@ -158,7 +158,7 @@ class TestDeadlines:
         gate = MicroBatcher(fn, max_batch=4, max_wait_ms=0.0, metrics=m)
         try:
             f1 = gate.submit(np.zeros((1, 2)))
-            time.sleep(0.05)             # worker wedged on batch 1
+            time.sleep(0.05)             # worker stuck on batch 1
             f2 = gate.submit(np.zeros((2, 2)), deadline_ms=60.0)
             time.sleep(0.15)             # deadline lapses while queued
             hold.set()

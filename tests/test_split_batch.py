@@ -1,7 +1,7 @@
 """split_batch (K-way super-step grower, grower.py grow_tree_batched).
 
 The batched grower splits the top-K leaves per step and builds all K child
-histograms in one C=3K one-hot contraction (PROFILE.md: the histogram
+histograms in one C=3K one-hot contraction (the histogram
 matmul is sublane-bound at M=3, so batching is the only way past that
 ceiling).  K=1 keeps exact strict leaf-wise reference semantics; K>1 is a
 best-first variant between LightGBM's leaf-wise and XGBoost's depth-wise

@@ -401,7 +401,7 @@ class TestServeIntegration:
             fe.close()
             srv.close()
 
-    def test_unrelated_incumbent_skips_lineage_not_wedged(self, tmp_path):
+    def test_unrelated_incumbent_skips_lineage_not_stuck(self, tmp_path):
         # an operator hot-swaps an UNRELATED hotfix model in: the next
         # generation is a continuation of the SNAPSHOT lineage, not of
         # the incumbent — the lineage gate must stand down (checksum

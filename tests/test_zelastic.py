@@ -54,12 +54,12 @@ def _trees(bst_or_text):
 
 class TestWatchdogRaiseMode:
     def test_deadline_raises_classified_timeout_in_waiting_thread(self):
-        wd = Watchdog(0.3, label="wedged call", on_timeout="raise")
+        wd = Watchdog(0.3, label="hung call", on_timeout="raise")
         t0 = time.monotonic()
         with pytest.raises(WatchdogTimeout) as ei:
             wd.run(time.sleep, 5.0)
         assert time.monotonic() - t0 < 3.0       # not the sleep's 5 s
-        assert "wedged call" in str(ei.value)
+        assert "hung call" in str(ei.value)
         # the classifier must treat the abandoned call as transient so
         # retry/backoff and the elastic ladder re-attempt it
         assert is_retryable_device_error(ei.value)
@@ -189,7 +189,7 @@ class TestLiveness:
         finally:
             hb.stop()
 
-    def test_guarded_get_bounds_a_wedged_fetch(self, monkeypatch):
+    def test_guarded_get_bounds_a_hung_fetch(self, monkeypatch):
         import jax.numpy as jnp
         monkeypatch.setenv(faultinject.HANG_ENV_VAR, "5")
         faultinject.configure("collective_hang:1")

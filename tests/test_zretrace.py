@@ -36,14 +36,13 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 import lightgbm_tpu as lgb
 from lightgbm_tpu.utils.compile_cache import compile_stats
-cache_dir = sys.argv[1]
 rs = np.random.RandomState(0)
 x = rs.randn(300, 8)
 y = (x[:, 0] - x[:, 1] + 0.2 * rs.randn(300) > 0).astype(np.float32)
 p = {"objective": "binary", "num_leaves": 31, "verbosity": 0,
      "min_data_in_leaf": 5, "max_bin": 15, "tpu_learner": "masked",
      "fused_chunk": 0, "predict_bucketed": "true",
-     "compile_cache_dir": cache_dir, "compile_cache_min_compile_s": 0.0}
+     "compile_cache_min_compile_s": 0.0}
 ds = lgb.Dataset(x, label=y, params=p)
 bst = lgb.train(p, ds, num_boost_round=2)
 pred = bst.predict(x[:50])
@@ -53,9 +52,11 @@ print("PRED " + json.dumps(np.asarray(pred)[:4].round(8).tolist()))
 
 
 def _run_warm(cache_dir: str) -> dict:
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # a private cache, placed the way the driver places one
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=cache_dir)
     out = subprocess.run(
-        [sys.executable, "-c", _WARM_SCRIPT, cache_dir],
+        [sys.executable, "-c", _WARM_SCRIPT],
         capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
     assert out.returncode == 0, out.stderr[-3000:]
     stats = pred = None
@@ -100,14 +101,13 @@ jax.config.update("jax_platforms", "cpu")
 import lightgbm_tpu as lgb
 from lightgbm_tpu.ops import hist_tune
 from lightgbm_tpu.utils.compile_cache import compile_stats
-cache_dir = sys.argv[1]
 rs = np.random.RandomState(0)
 x = rs.randn(400, 6)
 y = (x[:, 0] - x[:, 1] + 0.2 * rs.randn(400) > 0).astype(np.float32)
 p = {"objective": "binary", "num_leaves": 33, "verbosity": 0,
      "min_data_in_leaf": 5, "max_bin": 15, "tpu_learner": "masked",
      "fused_chunk": 0, "hist_tune": "on", "split_batch": 0,
-     "compile_cache_dir": cache_dir, "compile_cache_min_compile_s": 0.0}
+     "compile_cache_min_compile_s": 0.0}
 ds = lgb.Dataset(x, label=y, params=p)
 bst = lgb.train(p, ds, num_boost_round=2)
 rec = {"sweeps": hist_tune.tune_counts()["sweeps"],
@@ -122,9 +122,10 @@ class TestAutotunerWarmStart:
         cache = str(tmp_path / "cache")
 
         def run():
-            env = dict(os.environ, JAX_PLATFORMS="cpu")
+            env = dict(os.environ, JAX_PLATFORMS="cpu",
+                       JAX_COMPILATION_CACHE_DIR=cache)
             out = subprocess.run(
-                [sys.executable, "-c", _TUNE_SCRIPT, cache],
+                [sys.executable, "-c", _TUNE_SCRIPT],
                 capture_output=True, text=True, timeout=420, env=env,
                 cwd=REPO)
             assert out.returncode == 0, out.stderr[-3000:]

@@ -27,12 +27,10 @@ bench record against an OLD one on exactly the metrics pinned in
   are dropped.
 
 Input files may be either the raw final bench line
-(``{"metric", "value", ..., "extra": {...}}``) or the round wrapper
-(``{"parsed": {...}}``, the BENCH_r*.json shape).  With one file
-argument the OLD side defaults to the newest ``BENCH_r*.json`` in the
-repo root that parses (current-vs-history mode).
+(``{"metric", "value", ..., "extra": {...}}``) or a wrapper around it
+(``{"parsed": {...}}``).
 
-Run: ``python tools/bench_diff.py NEW [OLD]`` — exit 1 on any
+Run: ``python tools/bench_diff.py NEW OLD`` — exit 1 on any
 violation or stale pin; tier-1 exercises green/tamper/stale on a
 synthetic pair (tests/test_perf_ledger.py, the test_zretrace lint
 mold).
@@ -41,7 +39,6 @@ mold).
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import re
@@ -209,33 +206,11 @@ def update(new: Dict, budget: Dict[str, Tuple[str, float]]
     return out
 
 
-def default_old(exclude: str) -> Optional[str]:
-    """Newest BENCH_r*.json in the repo root that parses (the
-    current-vs-history default when only NEW is given).  Ordered by
-    the ROUND NUMBER, not the filename string — lexicographic order
-    would put r99 after r100 once rounds outgrow the zero padding."""
-    def round_no(p):
-        m = re.search(r"BENCH_r(\d+)\.json$", p)
-        return int(m.group(1)) if m else -1
-    cands = sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json")),
-                   key=round_no, reverse=True)
-    for path in cands:
-        if os.path.abspath(path) == os.path.abspath(exclude):
-            continue
-        try:
-            load_record(path)
-            return path
-        except (ValueError, OSError):
-            continue
-    return None
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("new", help="new bench json (the candidate)")
     ap.add_argument("old", nargs="?", default=None,
-                    help="old bench json (default: newest parseable "
-                         "BENCH_r*.json in the repo root)")
+                    help="old bench json (required unless --update)")
     ap.add_argument("--budget", default=BUDGET,
                     help="pin file (tests point this at a temp copy)")
     ap.add_argument("--update", action="store_true",
@@ -249,13 +224,10 @@ def main() -> int:
         print(f"pinned {len(pins)} metric(s) to {args.budget}")
         return 0
 
-    old_path = args.old or default_old(args.new)
-    if old_path is None:
-        print("bench_diff: no old record to compare against "
-              "(no parseable BENCH_r*.json found)", file=sys.stderr)
-        return 2
-    old = load_record(old_path)
-    print(f"bench_diff: {os.path.basename(old_path)} -> "
+    if args.old is None:
+        ap.error("OLD is required: bench_diff compares two records")
+    old = load_record(args.old)
+    print(f"bench_diff: {os.path.basename(args.old)} -> "
           f"{os.path.basename(args.new)}")
     findings = check(old, new, load_budget(args.budget))
     if findings:
