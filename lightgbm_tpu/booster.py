@@ -87,7 +87,7 @@ class Booster:
                  train_set: Optional[Dataset] = None,
                  model_file: Optional[str] = None,
                  model_str: Optional[str] = None,
-                 hist_reduce=None):
+                 hist_reduce=None, _obs=None):
         self.best_iteration = -1
         self.best_score: Dict = {}
         self._valid_names: List[str] = []
@@ -122,6 +122,13 @@ class Booster:
             raise ValueError("Booster needs train_set, model_file or model_str")
 
         self.config = Config(params or {})
+        # telemetry session first, so that it sees this constructor's
+        # own work; ``_obs`` is the one ``lgb.cv`` opened for the fold
+        from .obs import maybe_session
+        obs = _obs if _obs is not None and self.config.telemetry \
+            else maybe_session(self.config)
+        if obs is not None:
+            _sp = obs.span("booster.init")
         # persistent-compile-cache bring-up + compile counters: every
         # training Booster warm-starts its jit compiles from (and
         # contributes to) the on-disk cache unless compile_cache=false
@@ -143,7 +150,7 @@ class Booster:
         self.train_set = train_set.construct(construct_cfg)
         self.objective = create_objective(self.config)
         self._model = create_boosting(self.config, self.train_set,
-                                      self.objective, hist_reduce)
+                                      self.objective, hist_reduce, obs)
         self._num_class = self.config.num_class
         self._num_tree_per_iteration = self.config.num_model_per_iteration
         self._average_output = getattr(self._model, "average_output", False)
@@ -152,6 +159,8 @@ class Booster:
 
         self._train_metrics = self._make_metrics(self.train_set.metadata,
                                                  self.train_set.num_data)
+        if obs is not None:
+            obs.end_setup(_sp)
 
     # ------------------------------------------------------------------
     def add_valid(self, data: Dataset, name: str) -> "Booster":
@@ -252,6 +261,10 @@ class Booster:
         byte-identity contract the tests pin); the host f64 ``eval_*``
         path stays available via ``fused_eval=false``."""
         m = self._model
+        obs = m._obs
+        if obs is not None:
+            _sp = obs.span("eval", rows=sum(
+                vs[0].num_data for vs in m.valid_sets))
         spec = tuple(
             (vi, name, mt.name, mt.is_higher_better)
             for vi, name in enumerate(self._valid_names)
@@ -261,6 +274,8 @@ class Booster:
         ops = tuple(m._se_valid_dev(vi)
                     for vi in range(len(m.valid_sets)))
         vals = m._eget(fn(svecs, ops), "traced_eval")
+        if obs is not None:
+            obs.end_eval(_sp)
         return [(name, mn, float(vals[e]), hib)
                 for e, (vi, name, mn, hib) in enumerate(spec)]
 
@@ -457,18 +472,31 @@ class Booster:
 
     # ------------------------------------------------------------------
     def eval_train(self, feval=None) -> List[Tuple]:
+        obs = self._model._obs
+        if obs is not None:
+            _sp = obs.span("eval", rows=self.train_set.num_data)
         score = self._model.train_score()
-        return self._eval_set(getattr(self, "_train_data_name", "training"),
-                              score, self._train_metrics,
-                              self.train_set, feval)
+        out = self._eval_set(getattr(self, "_train_data_name", "training"),
+                             score, self._train_metrics,
+                             self.train_set, feval)
+        if obs is not None:
+            obs.end_eval(_sp)
+        return out
 
     def eval_valid(self, feval=None) -> List[Tuple]:
+        obs = self._model._obs
+        if obs is not None:
+            # the score's fetch and the host metric: no fence needed
+            _sp = obs.span("eval", rows=sum(
+                vs[0].num_data for vs in self._model.valid_sets))
         out = []
         for i, name in enumerate(self._valid_names):
             score = self._model.valid_score(i)
             ds = self._model.valid_sets[i][0]
             out.extend(self._eval_set(name, score, self._valid_metrics[i],
                                       ds, feval))
+        if obs is not None:
+            obs.end_eval(_sp)
         return out
 
     def _eval_set(self, name, score, metrics, dataset, feval) -> List[Tuple]:

@@ -79,9 +79,10 @@ def log_telemetry(period: int = 10, collect: Dict = None) -> Callable:
     """Log (and optionally collect) obs metrics snapshots during
     training (docs/Observability.md).  Every ``period`` iterations the
     booster's aggregated snapshot is summarized via ``Log.info`` —
-    iteration count, mean per-phase milliseconds, cumulative comm wire
-    bytes — and, when ``collect`` is given, stored whole under the
-    1-based iteration number.  A no-op unless ``telemetry=true``."""
+    iteration count, mean per-phase milliseconds, the booster's set-up
+    stages and evaluations in seconds, cumulative comm wire bytes — and,
+    when ``collect`` is given, stored whole under the 1-based iteration
+    number.  A no-op unless ``telemetry=true``."""
 
     def _summary(snap: Dict) -> str:
         parts = []
@@ -94,6 +95,13 @@ def log_telemetry(period: int = 10, collect: Dict = None) -> Callable:
                 phase = key.split("phase=", 1)[1].rstrip("}")
                 parts.append(
                     f"{phase}={rec['sum'] / rec['count'] * 1e3:.1f}ms")
+            elif key.startswith("train.setup_seconds{") \
+                    and rec.get("count"):
+                stage = key.split("stage=", 1)[1].rstrip("}")
+                parts.append(f"{stage}={rec['sum']:.2f}s")
+        ev = snap.get("train.eval_seconds")
+        if ev and ev.get("count"):
+            parts.append(f"eval={ev['sum']:.2f}s")
         wire = sum(rec["value"] for key, rec in snap.items()
                    if key.startswith("comm.wire_bytes{"))
         if wire:

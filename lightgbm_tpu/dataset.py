@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import io
 import os
+import time
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 import numpy as np
@@ -286,6 +287,10 @@ class Dataset:
         self._built_bin_sig = self._bin_signature(cfg)
         if _is_seq_input(self._raw_input):
             return self._construct_from_seqs(cfg)
+        # the seconds of this construction's own stages, always kept
+        # (three clock reads): a booster's telemetry adopts them as
+        # data.construct_seconds{stage=}
+        t_start = time.perf_counter()
         sparse_in = _is_scipy_sparse(self._raw_input)
         if sparse_in:
             # CSR/CSC input (LGBM_DatasetCreateFromCSR/CSC, c_api.h:109-313
@@ -333,6 +338,7 @@ class Dataset:
         self._set_metadata_inputs()
         self._resolve_names(names)
         cat_idx = self._resolve_cats(cfg, pandas_cat)
+        t_numpy = time.perf_counter()
 
         if self._preset_mappers is not None:
             self.bin_mappers = list(self._preset_mappers)
@@ -349,8 +355,13 @@ class Dataset:
         else:
             self._fit_bin_mappers(colfn, cfg, cat_idx,
                                   sample_col_factory=sample_col_factory)
+        t_fit = time.perf_counter()
 
         self._bin_data(colfn, cfg, csc if sparse_in else None)
+        self.construct_seconds = {
+            "to_numpy": t_numpy - t_start,   # input conversion, f64 copy
+            "fit_bins": t_fit - t_numpy,     # sampling and find_bin
+            "bin_data": time.perf_counter() - t_fit}    # value_to_bin
         keep_raw = (not self.free_raw_data) or bool(cfg.linear_tree)
         self._built_linear_tree = bool(cfg.linear_tree)  # save_binary raw rule
         if sparse_in:
@@ -980,11 +991,15 @@ class Dataset:
             return np.asarray(src, np.float64)[idx]
         return None
 
-    def subset(self, used_indices, params=None) -> "Dataset":
+    def subset(self, used_indices, params=None, _obs=None) -> "Dataset":
         """Row-subset copy (Dataset::CopySubrow, dataset.h:486 analog).
         Indices are SORTED like the reference python subset (basic.py
-        used_indices sort) — rows keep their original relative order."""
+        used_indices sort) — rows keep their original relative order.
+        ``_obs``: the telemetry session of the booster this subset is
+        made for (``lgb.cv``), which times the gather."""
         self.construct()
+        if _obs is not None:
+            _sp = _obs.span("dataset.subset", rows=len(used_indices))
         idx = np.sort(np.asarray(used_indices, dtype=np.int64))
         sub = Dataset.__new__(Dataset)
         sub.__dict__.update({k: v for k, v in self.__dict__.items()})
@@ -1016,6 +1031,10 @@ class Dataset:
             qidx = np.searchsorted(qb, idx, side="right") - 1
             sub.metadata.set_group(np.unique(qidx, return_counts=True)[1])
         sub.reference = self
+        if _obs is not None:
+            _obs.end_setup(_sp, bytes=sum(
+                int(a.nbytes) for a in (sub.binned, sub.raw_data)
+                if isinstance(a, np.ndarray)))
         return sub
 
     def _group_from_parent(self, parent: "Dataset", idx: np.ndarray) -> None:

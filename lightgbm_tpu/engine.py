@@ -619,18 +619,27 @@ def cv(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100,
 
     cvbooster = CVBooster()
     results = collections.defaultdict(list)
-    for (tr_idx, te_idx) in folds:
+    from .obs import maybe_session
+    for fold, (tr_idx, te_idx) in enumerate(folds):
+        # the fold's telemetry session comes before its Booster: the
+        # set-up of a fold (two host gathers, a Booster, two uploads) is
+        # the first thing it times, and the Booster takes it over
+        obs = maybe_session(cfg)
+        if obs is not None:
+            _sp = obs.span("cv.fold_setup", fold=fold)
         # subset() reconstructs per-fold query groups from the parent's
         # boundaries itself
-        tr = train_set.subset(tr_idx)
-        te = train_set.subset(te_idx)
+        tr = train_set.subset(tr_idx, _obs=obs)
+        te = train_set.subset(te_idx, _obs=obs)
         fold_params = params
         if fpreproc is not None:
             tr, te, fold_params = fpreproc(tr, te, dict(params))
-        bst = Booster(params=dict(fold_params), train_set=tr)
+        bst = Booster(params=dict(fold_params), train_set=tr, _obs=obs)
         bst._train_data_name = "train"
         bst.add_valid(te, "valid")
         cvbooster.append(bst)
+        if obs is not None:
+            obs.end_setup(_sp)
 
     # lockstep boosting (the reference's CVBooster: every fold advances
     # one iteration, then the AGGREGATED metrics go to the callbacks as

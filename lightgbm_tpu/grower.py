@@ -62,6 +62,18 @@ def grower_trace_count() -> int:
 _SHARED_GROWERS: "OrderedDict[tuple, Callable]" = OrderedDict()
 _SHARED_GROWERS_MAX = 64
 _SHARED_GROWERS_LOCK = threading.Lock()
+# what the memo answered, over the process: hit (an earlier booster's
+# jitted grower is reused), miss (a new one is kept), unkeyable (a hook
+# or an object-valued argument: this grower jits privately)
+_MEMO_COUNTS = {"hit": 0, "miss": 0, "unkeyable": 0}
+
+
+def grower_memo_counts() -> dict:
+    """Copy of the memo's hit / miss / unkeyable counts (telemetry's
+    ``grower.memo{result=}`` is the difference around one
+    ``make_grower`` call)."""
+    with _SHARED_GROWERS_LOCK:
+        return dict(_MEMO_COUNTS)
 
 
 class _Unkeyable(Exception):
@@ -697,6 +709,10 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 jnp.arange(B, dtype=jnp.int32)[None], (nnode, B)) + 0,
         )
 
+    # every operation of a grower carries the scope ``lgbtpu.grow`` and,
+    # inside it, its phase's (partition, hist.onehot, hist.contract,
+    # hist.state, split): the device trace is folded by the innermost
+    @jax.named_scope("lgbtpu.grow")
     def grow_tree(binned, vals, feature_mask, num_bin, na_bin,
                   na_bin_part=None, is_cat=None,
                   rng_iter=None, cegb_used=None,
@@ -768,28 +784,30 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 # --- partition rows (CUDADataPartition::Split analog) -----
                 # decision rank unifies numerical (iota rank) and
                 # categorical (ratio-order rank) predicates
-                if efb is None:
-                    if isinstance(binned, _spd.SparseBinned):
-                        fcol = _spd.column(binned, feat)
+                with jax.named_scope("lgbtpu.partition"):
+                    if efb is None:
+                        if isinstance(binned, _spd.SparseBinned):
+                            fcol = _spd.column(binned, feat)
+                        else:
+                            fcol = jnp.take(binned, feat, axis=1) \
+                                .astype(jnp.int32)
                     else:
-                        fcol = jnp.take(binned, feat, axis=1) \
-                            .astype(jnp.int32)
-                else:
-                    # decode the feature's bins from its bundle column
-                    # (SubFeatureIterator analog, feature_group.h)
-                    gcol = jnp.take(binned, efb.group_of_feat[feat],
-                                    axis=1).astype(jnp.int32)
-                    off = efb_off_dev[feat]
-                    in_range = (gcol >= off) \
-                        & (gcol < off + num_bin_part[feat] - 1)
-                    fcol = jnp.where(off < 0, gcol,
-                                     jnp.where(in_range, gcol - off + 1, 0))
-                nb = na_bin_part[feat]
-                is_na = (nb >= 0) & (fcol == nb) & (~icat)
-                go_left = jnp.where(is_na, dleft, rank_vec[fcol] <= thr)
-                in_leaf = st.leaf_of_row == leaf
-                leaf_of_row = jnp.where(in_leaf & (~go_left), new_leaf,
-                                        st.leaf_of_row)
+                        # decode the feature's bins from its bundle column
+                        # (SubFeatureIterator analog, feature_group.h)
+                        gcol = jnp.take(binned, efb.group_of_feat[feat],
+                                        axis=1).astype(jnp.int32)
+                        off = efb_off_dev[feat]
+                        in_range = (gcol >= off) \
+                            & (gcol < off + num_bin_part[feat] - 1)
+                        fcol = jnp.where(
+                            off < 0, gcol,
+                            jnp.where(in_range, gcol - off + 1, 0))
+                    nb = na_bin_part[feat]
+                    is_na = (nb >= 0) & (fcol == nb) & (~icat)
+                    go_left = jnp.where(is_na, dleft, rank_vec[fcol] <= thr)
+                    in_leaf = st.leaf_of_row == leaf
+                    leaf_of_row = jnp.where(in_leaf & (~go_left), new_leaf,
+                                            st.leaf_of_row)
 
                 # --- histograms: smaller child + subtraction --------------
                 smaller_left = lsum[2] <= rsum[2]
@@ -797,7 +815,8 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 hist_small = child_hist(binned_view, vals, leaf_of_row,
                                         smaller_id)
                 if use_subtraction:
-                    hist_large = st.hist[leaf] - hist_small
+                    with jax.named_scope("lgbtpu.hist.state"):
+                        hist_large = st.hist[leaf] - hist_small
                 else:
                     # voting-parallel: per-split feature votes make the
                     # reduced hist feature sets differ between parent and
@@ -805,9 +824,11 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                     larger_id = jnp.where(smaller_left, new_leaf, leaf)
                     hist_large = child_hist(binned_view, vals, leaf_of_row,
                                             larger_id)
-                hl_leaf = jnp.where(smaller_left, hist_small, hist_large)
-                hl_new = jnp.where(smaller_left, hist_large, hist_small)
-                hist = st.hist.at[leaf].set(hl_leaf).at[new_leaf].set(hl_new)
+                with jax.named_scope("lgbtpu.hist.state"):
+                    hl_leaf = jnp.where(smaller_left, hist_small, hist_large)
+                    hl_new = jnp.where(smaller_left, hist_large, hist_small)
+                    hist = st.hist.at[leaf].set(hl_leaf) \
+                                  .at[new_leaf].set(hl_new)
 
                 # --- leaf stats -------------------------------------------
                 d = st.leaf_depth[leaf] + 1
@@ -942,6 +963,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
     # schedules) and tree shape, so padding must never change it
     K = max(1, min(int(split_batch), L_req - 1)) if L_req > 1 else 1
 
+    @jax.named_scope("lgbtpu.grow")
     def grow_tree_batched(binned, vals, feature_mask, num_bin, na_bin,
                           na_bin_part=None, is_cat=None,
                           rng_iter=None, cegb_used=None,
@@ -1026,59 +1048,64 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 parent_k = st.leaf_parent[leaf_sel]
 
                 # --- partition rows: ONE pass for all K splits ------------
-                slot_of_leaf = jnp.full(LP, -1, jnp.int32) \
-                    .at[leaf_sel].set(kidx)
-                slot = slot_of_leaf[st.leaf_of_row]          # [N]
-                active = slot >= 0
-                sl = jnp.maximum(slot, 0)
-                feat_r = feat_k[sl]                          # [N]
-                if efb is None:
-                    if isinstance(binned, _spd.SparseBinned):
-                        fcol = _spd.column_per_row(binned, feat_r)
+                with jax.named_scope("lgbtpu.partition"):
+                    slot_of_leaf = jnp.full(LP, -1, jnp.int32) \
+                        .at[leaf_sel].set(kidx)
+                    slot = slot_of_leaf[st.leaf_of_row]          # [N]
+                    active = slot >= 0
+                    sl = jnp.maximum(slot, 0)
+                    feat_r = feat_k[sl]                          # [N]
+                    if efb is None:
+                        if isinstance(binned, _spd.SparseBinned):
+                            fcol = _spd.column_per_row(binned, feat_r)
+                        else:
+                            fcol = jnp.take_along_axis(
+                                binned, feat_r[:, None], axis=1)[:, 0] \
+                                .astype(jnp.int32)
                     else:
-                        fcol = jnp.take_along_axis(
-                            binned, feat_r[:, None], axis=1)[:, 0] \
+                        grp_r = efb.group_of_feat[feat_r]
+                        gcol = jnp.take_along_axis(
+                            binned, grp_r[:, None], axis=1)[:, 0] \
                             .astype(jnp.int32)
-                else:
-                    grp_r = efb.group_of_feat[feat_r]
-                    gcol = jnp.take_along_axis(
-                        binned, grp_r[:, None], axis=1)[:, 0] \
-                        .astype(jnp.int32)
-                    off = efb_off_dev[feat_r]
-                    in_range = (gcol >= off) \
-                        & (gcol < off + num_bin_part[feat_r] - 1)
-                    fcol = jnp.where(off < 0, gcol,
-                                     jnp.where(in_range, gcol - off + 1, 0))
-                nb_r = na_bin_part[feat_r]
-                icat_r = icat_k[sl]
-                is_na = (nb_r >= 0) & (fcol == nb_r) & (~icat_r)
-                rv = rank_k[sl, fcol]
-                go_left = jnp.where(is_na, dleft_k[sl], rv <= thr_k[sl])
-                leaf_of_row = jnp.where(active & (~go_left),
-                                        new_leaf_sel[sl], st.leaf_of_row)
+                        off = efb_off_dev[feat_r]
+                        in_range = (gcol >= off) \
+                            & (gcol < off + num_bin_part[feat_r] - 1)
+                        fcol = jnp.where(
+                            off < 0, gcol,
+                            jnp.where(in_range, gcol - off + 1, 0))
+                    nb_r = na_bin_part[feat_r]
+                    icat_r = icat_k[sl]
+                    is_na = (nb_r >= 0) & (fcol == nb_r) & (~icat_r)
+                    rv = rank_k[sl, fcol]
+                    go_left = jnp.where(is_na, dleft_k[sl], rv <= thr_k[sl])
+                    leaf_of_row = jnp.where(active & (~go_left),
+                                            new_leaf_sel[sl], st.leaf_of_row)
 
                 # --- batched child histograms: one C=3K contraction -------
-                smaller_left = lsum_k[:, 2] <= rsum_k[:, 2]  # [K]
-                small_id = jnp.where(smaller_left, leaf_sel, new_leaf_sel)
-                targets = small_id if use_subtraction \
-                    else jnp.concatenate([leaf_sel, new_leaf_sel])
-                tslot_of_leaf = jnp.full(LP, -1, jnp.int32) \
-                    .at[targets].set(jnp.arange(nC, dtype=jnp.int32))
-                tslot = tslot_of_leaf[leaf_of_row]           # [N]
+                with jax.named_scope("lgbtpu.hist.state"):
+                    smaller_left = lsum_k[:, 2] <= rsum_k[:, 2]  # [K]
+                    small_id = jnp.where(smaller_left, leaf_sel,
+                                         new_leaf_sel)
+                    targets = small_id if use_subtraction \
+                        else jnp.concatenate([leaf_sel, new_leaf_sel])
+                    tslot_of_leaf = jnp.full(LP, -1, jnp.int32) \
+                        .at[targets].set(jnp.arange(nC, dtype=jnp.int32))
+                    tslot = tslot_of_leaf[leaf_of_row]       # [N]
                 hist_c = _hist(binned_view, vals, tslot, nC,
                                scales=scales)                # [Fh, Bh, 3nC]
-                hist_c = hist_c.reshape(fh, Bh, 3, nC) \
-                    .transpose(3, 0, 1, 2)                   # [nC, Fh, Bh, 3]
-                if use_subtraction:
-                    hist_small = hist_c
-                    hist_large = st.hist[leaf_sel] - hist_small
-                    sel = smaller_left[:, None, None, None]
-                    hl_leaf = jnp.where(sel, hist_small, hist_large)
-                    hl_new = jnp.where(sel, hist_large, hist_small)
-                else:
-                    hl_leaf, hl_new = hist_c[:K], hist_c[K:]
-                hist = st.hist.at[leaf_sel].set(hl_leaf) \
-                              .at[new_leaf_sel].set(hl_new)
+                with jax.named_scope("lgbtpu.hist.state"):
+                    hist_c = hist_c.reshape(fh, Bh, 3, nC) \
+                        .transpose(3, 0, 1, 2)               # [nC, Fh, Bh, 3]
+                    if use_subtraction:
+                        hist_small = hist_c
+                        hist_large = st.hist[leaf_sel] - hist_small
+                        sel = smaller_left[:, None, None, None]
+                        hl_leaf = jnp.where(sel, hist_small, hist_large)
+                        hl_new = jnp.where(sel, hist_large, hist_small)
+                    else:
+                        hl_leaf, hl_new = hist_c[:K], hist_c[K:]
+                    hist = st.hist.at[leaf_sel].set(hl_leaf) \
+                                  .at[new_leaf_sel].set(hl_new)
 
                 # --- leaf stats -------------------------------------------
                 d_k = st.leaf_depth[leaf_sel] + 1
@@ -1268,9 +1295,12 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             # the key must carry it; padded ones take it per call
             L_default=None if padded else L_req))
     if key is None:
+        with _SHARED_GROWERS_LOCK:
+            _MEMO_COUNTS["unkeyable"] += 1
         return jax.jit(fn)
     with _SHARED_GROWERS_LOCK:
         shared = _SHARED_GROWERS.get(key)
+        _MEMO_COUNTS["miss" if shared is None else "hit"] += 1
         if shared is None:
             shared = jax.jit(fn)
             _SHARED_GROWERS[key] = shared

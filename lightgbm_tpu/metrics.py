@@ -504,6 +504,7 @@ def build_traced_eval(eval_spec: Sequence[Tuple],
     from .utils.compile_cache import trace_event
 
     @jax.jit
+    @jax.named_scope("lgbtpu.eval")
     def teval(svecs, ops):
         trace_event("traced_eval")
         if not spec:

@@ -20,8 +20,9 @@ from ..predict_device import add_tree_score
 
 
 class DARTModel(GBDTModel):
-    def __init__(self, config, train_set, objective, hist_reduce=None):
-        super().__init__(config, train_set, objective, hist_reduce)
+    def __init__(self, config, train_set, objective, hist_reduce=None,
+                 obs=None):
+        super().__init__(config, train_set, objective, hist_reduce, obs)
         self._rng_drop = np.random.RandomState(config.drop_seed)
         self._drop_idx: List[int] = []
         self._drop_contrib_train = None     # [N, K] score of dropped trees

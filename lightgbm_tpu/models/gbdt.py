@@ -105,8 +105,17 @@ class GBDTModel:
 
     def __init__(self, config: Config, train_set: Dataset,
                  objective: Optional[ObjectiveFunction],
-                 hist_reduce=None):
+                 hist_reduce=None, obs=None):
         self.config = config
+        # telemetry (obs/): None when telemetry=false — the hot paths
+        # below only ever test this for None, so the default adds zero
+        # host syncs and no per-iteration allocation beyond the branch.
+        # First, so that the session sees this constructor's own work;
+        # ``obs`` is a session the caller already opened spans on
+        from ..obs import maybe_session
+        if obs is None:
+            obs = maybe_session(config)
+        self._obs = obs
         self.train_set = train_set.construct(config)
         self.objective = objective
         self.num_class = config.num_model_per_iteration
@@ -388,6 +397,8 @@ class GBDTModel:
             self.efb_dev = None
             self.efb_maps = None
 
+        if obs is not None:
+            _sp = obs.span("booster.to_device", what="binned")
         # grower-facing bin metadata (== the user-facing arrays unless the
         # feature axis is padded for feature-parallel sharding)
         self._nb_grow = self.num_bin_dev
@@ -447,6 +458,8 @@ class GBDTModel:
             self.binned_dev = SparseBinned(
                 self.binned_dev, jnp.asarray(ds.binned_sparse.default_bin),
                 ds.binned_sparse.stride, self.num_features)
+        if obs is not None:
+            obs.end_to_device(_sp, self.binned_dev)
 
         # split_batch resolution (config.py): 0 = auto -> strict leaf-wise
         # below 64 leaves, K-way super-steps above (the
@@ -602,6 +615,10 @@ class GBDTModel:
                     "false.")
 
         mg_kwargs = None   # set on the masked-learner path (integrity shadow)
+        if obs is not None:
+            from ..grower import grower_memo_counts
+            _sp = obs.span("grower.make")
+            memo0 = grower_memo_counts()
         if dist == "data":
             from ..parallel.data_parallel import make_dp_grower
             self.grower = make_dp_grower(
@@ -690,10 +707,21 @@ class GBDTModel:
                 cegb=self._cegb_state,
                 padded_leaves=self._leaf_pad)
             self.grower = make_grower(**mg_kwargs)
+        if obs is not None:
+            # what the process-wide memo of jitted growers answered
+            # (grower.py _SHARED_GROWERS): hit = this booster runs a
+            # program an earlier one traced
+            memo = {r: n - memo0[r]
+                    for r, n in grower_memo_counts().items() if n > memo0[r]}
+            for result, n in memo.items():
+                obs.metrics.counter("grower.memo", result=result).inc(n)
+            obs.end_setup(_sp, memo="+".join(sorted(memo)) or "none")
 
         if config.linear_tree and config.boosting not in ("gbdt", "gbrt"):
             raise ValueError("linear_tree requires boosting=gbdt")
 
+        if obs is not None:
+            _sp = obs.span("booster.to_device", what="row_state")
         if self.objective is not None:
             self.objective.init(ds.metadata, self.num_data)
 
@@ -704,6 +732,10 @@ class GBDTModel:
             init += s.reshape(self.num_data, -1)
         self.score = jnp.asarray(init)
         self._init_applied = ds.metadata.init_score is not None
+        if obs is not None:
+            obs.end_to_device(_sp, (self.score, [
+                getattr(self.objective, a, None)
+                for a in ("label", "weight")]))
 
         # validation sets: (dataset, device binned, score)
         self.valid_sets: List[Tuple[Dataset, jax.Array, jax.Array]] = []
@@ -747,13 +779,9 @@ class GBDTModel:
                     "pure recompute.  Use the masked learner")
             self._integrity = IntegrityChecker(config, shadow, independent)
 
-        # telemetry (obs/): None when telemetry=false — the hot paths
-        # below only ever test this for None, so the default adds zero
-        # host syncs and no per-iteration allocation beyond the branch
-        from ..obs import maybe_session
-        self._obs = maybe_session(config)
         self._flops = None
         if self._obs is not None:
+            self._obs.adopt_construct_seconds(self.train_set)
             ledger = getattr(self.grower, "comm", None)
             if ledger is not None:
                 self._obs.attach_comm_sites(ledger)
@@ -1236,6 +1264,9 @@ class GBDTModel:
         valid.construct(self.config)
         nv = valid.num_data
         pad = 0
+        obs = self._obs
+        if obs is not None:
+            _sp = obs.span("booster.to_device", what="valid")
         if valid.binned_sparse is not None:
             binned = valid.binned_sparse.to_device()
         else:
@@ -1275,6 +1306,8 @@ class GBDTModel:
                 init[:nv, k] += (self.tree_weights[ti]
                                  * self.models[ti].predict(raw))
         score = jnp.asarray(init)
+        if obs is not None:
+            obs.end_to_device(_sp, (binned, score))
         # replay existing device trees (continued training)
         for ti, dt in enumerate(self.device_trees):
             mi = n_host_only + ti
@@ -1565,20 +1598,22 @@ class GBDTModel:
             def one_iter(carry, xs):
                 score, dead, cuse, ml = carry
                 fmask, it = xs
-                g, h = obj.get_gradients(score[:, 0])
+                with jax.named_scope("lgbtpu.grad"):
+                    g, h = obj.get_gradients(score[:, 0])
                 if fin_freq > 0 and fin_policy == "clamp":
                     # clamp is sync-free, so it applies every iteration
                     g = jnp.nan_to_num(g, nan=0.0, posinf=_FINITE_CLAMP,
                                        neginf=-_FINITE_CLAMP)
                     h = jnp.nan_to_num(h, nan=0.0, posinf=_FINITE_CLAMP,
                                        neginf=0.0)
-                if use_goss:
-                    w = self._goss_vals(g, h, it)
-                elif use_bag:
-                    w = self._bagging_w(it)
-                else:
-                    w = jnp.ones_like(g)
-                vals = jnp.stack([g * w, h * w, w], axis=1)
+                with jax.named_scope("lgbtpu.sample"):
+                    if use_goss:
+                        w = self._goss_vals(g, h, it)
+                    elif use_bag:
+                        w = self._bagging_w(it)
+                    else:
+                        w = jnp.ones_like(g)
+                    vals = jnp.stack([g * w, h * w, w], axis=1)
                 kw = {"is_cat": ic} if ic is not None else {}
                 if self._extra_trees or self._bynode_masked \
                         or self._quant is not None:
@@ -1644,14 +1679,15 @@ class GBDTModel:
                     # stump; a NaN-induced natural stump must NOT end
                     # training
                     dead = dead | ((arrays.num_leaves <= 1) & ~bad)
-                delta = jnp.where(ok > 0.0,
-                                  jnp.take(lv, arrays.leaf_of_row), 0.0)
                 from ..obs.flops import (note_traced,
                                          score_update_flops_bytes)
                 note_traced("score",
                             *score_update_flops_bytes(score.shape[0]),
                             phase="score", cadence="iter")
-                score = score.at[:, 0].add(delta)
+                with jax.named_scope("lgbtpu.score"):
+                    delta = jnp.where(ok > 0.0,
+                                      jnp.take(lv, arrays.leaf_of_row), 0.0)
+                    score = score.at[:, 0].add(delta)
                 if fin_freq > 0 and fin_policy == "skip_iter":
                     # a tripped check heals the score carry too: a NaN
                     # that slipped in at an UNCHECKED iteration (freq>1)
@@ -1708,8 +1744,9 @@ class GBDTModel:
 
         obs = self._obs
         if obs is not None:
-            _sp = obs.tracer.span("train_chunk", n_iters=k,
-                                  iteration=start_iter)
+            obs.activate()
+            _sp = obs.span("train_chunk", mirror=False, n_iters=k,
+                           iteration=start_iter)
             if obs.profiler is not None:
                 # the chunk is ONE device program: the capture window
                 # opens if any requested iteration falls inside it
@@ -2045,19 +2082,21 @@ class GBDTModel:
                 score, vsc, esb, esi, esh, stop, dead, cuse, ml = carry
                 fmask, it, eit = xs
                 blocked = dead | stop
-                g, h = obj.get_gradients(score[:, 0])
+                with jax.named_scope("lgbtpu.grad"):
+                    g, h = obj.get_gradients(score[:, 0])
                 if fin_freq > 0 and fin_policy == "clamp":
                     g = jnp.nan_to_num(g, nan=0.0, posinf=_FINITE_CLAMP,
                                        neginf=-_FINITE_CLAMP)
                     h = jnp.nan_to_num(h, nan=0.0, posinf=_FINITE_CLAMP,
                                        neginf=0.0)
-                if use_goss:
-                    w = goss_vals(g, h, it, seed=samp_seed)
-                elif use_bag:
-                    w = bagging_w(it, seed=samp_seed)
-                else:
-                    w = jnp.ones_like(g)
-                vals = jnp.stack([g * w, h * w, w], axis=1)
+                with jax.named_scope("lgbtpu.sample"):
+                    if use_goss:
+                        w = goss_vals(g, h, it, seed=samp_seed)
+                    elif use_bag:
+                        w = bagging_w(it, seed=samp_seed)
+                    else:
+                        w = jnp.ones_like(g)
+                    vals = jnp.stack([g * w, h * w, w], axis=1)
                 kw = {"is_cat": ic} if ic is not None else {}
                 if rng_iter_kw:
                     kw["rng_iter"] = it
@@ -2095,12 +2134,13 @@ class GBDTModel:
                     dead = dead | (arrays.num_leaves <= 1) | bad
                 else:
                     dead = dead | ((arrays.num_leaves <= 1) & ~bad)
-                delta = jnp.where(ok > 0.0,
-                                  jnp.take(lv, arrays.leaf_of_row), 0.0)
                 note_traced("score",
                             *score_update_flops_bytes(score.shape[0]),
                             phase="score", cadence="iter")
-                score = score.at[:, 0].add(delta)
+                with jax.named_scope("lgbtpu.score"):
+                    delta = jnp.where(ok > 0.0,
+                                      jnp.take(lv, arrays.leaf_of_row), 0.0)
+                    score = score.at[:, 0].add(delta)
                 if fin_freq > 0 and fin_policy == "skip_iter":
                     score = jnp.where(bad, jnp.nan_to_num(
                         score, nan=0.0, posinf=_FINITE_CLAMP,
@@ -2117,8 +2157,9 @@ class GBDTModel:
                         arrays.left_child, arrays.right_child, na_bin,
                         arrays.is_cat_node, arrays.cat_rank, efb_maps,
                         steps=steps)
-                    vd = jnp.where(ok > 0.0, jnp.take(lv, leaf), 0.0)
-                    new_vsc.append(vsc[vi2].at[:, 0].add(vd))
+                    with jax.named_scope("lgbtpu.score"):
+                        vd = jnp.where(ok > 0.0, jnp.take(lv, leaf), 0.0)
+                        new_vsc.append(vsc[vi2].at[:, 0].add(vd))
                 vsc = tuple(new_vsc)
                 # early-stop vote (callback.early_stopping traced form,
                 # min_delta == 0): update-then-check exactly like the
@@ -2133,10 +2174,11 @@ class GBDTModel:
                     note_traced("fused_eval",
                                 *eval_flops_bytes(n_rows, n_entries),
                                 phase="eval", cadence="iter")
-                    ev = jnp.stack([
-                        fn_m(vsc[vi2][:, 0], valid_ops[vi2][1],
-                             valid_ops[vi2][2])
-                        for (vi2, fn_m) in metric_idx])
+                    with jax.named_scope("lgbtpu.eval"):
+                        ev = jnp.stack([
+                            fn_m(vsc[vi2][:, 0], valid_ops[vi2][1],
+                                 valid_ops[vi2][2])
+                            for (vi2, fn_m) in metric_idx])
                     fin2 = jnp.isfinite(ev)
                     cmp2 = jnp.where(es_hib, ev > esb, ev < esb)
                     improved = fin2 & (~esh | cmp2) & ~blocked
@@ -2342,9 +2384,9 @@ class GBDTModel:
         obs = self._obs
         _sp = None
         if obs is not None:
-            _sp = obs.tracer.span("train_superepoch", n_iters=k,
-                                  iteration=start_iter,
-                                  n_evals=n_entries)
+            obs.activate()
+            _sp = obs.span("train_superepoch", mirror=False, n_iters=k,
+                           iteration=start_iter, n_evals=n_entries)
             if obs.profiler is not None:
                 for it in range(start_iter, start_iter + k):
                     obs.profiler.on_iter_begin(it)
@@ -2563,7 +2605,8 @@ class GBDTModel:
             self._elastic.check_peers()
         cfg = self.config
         obs = self._obs
-        t_iter0 = obs.iter_begin(self.iter_) if obs is not None else 0.0
+        if obs is not None:
+            obs.iter_begin(self.iter_)
         bbox = self._bbox
         if bbox is not None:
             import time as _time
@@ -2574,6 +2617,8 @@ class GBDTModel:
             # BoostFromAverage (gbdt.cpp:346): add init to train+valid
             # scorers before gradient computation; the saved tree gets the
             # bias via AddBias AFTER UpdateScore (gbdt.cpp:416-418)
+            if obs is not None:
+                _sp = obs.phase("init_score", self.iter_)
             for k in range(self.num_class):
                 init_scores[k] = self._boost_from_score(k)
             self._init_scores = list(init_scores)
@@ -2582,6 +2627,8 @@ class GBDTModel:
                 self.score = self.score + bias
                 for vi, (vds, vb, vs) in enumerate(self.valid_sets):
                     self.valid_sets[vi] = (vds, vb, vs + bias)
+            if obs is not None:
+                obs.end_phase(_sp, self.score)
         # gradients (GBDT::Boosting, gbdt.cpp:172)
         gscore = self._score_for_gradients()
         if self._bias_in_every_tree:
@@ -2601,7 +2648,9 @@ class GBDTModel:
             g_all = g_all.reshape(self.num_data, self.num_class)
             h_all = h_all.reshape(self.num_data, self.num_class)
         if obs is not None:
-            obs.phase_metric("grad", _sp.end((g_all, h_all)))
+            obs.end_phase(_sp, (g_all, h_all))
+            # row sampling and the accumuland stack, up to the grower
+            _sp = obs.phase("sample", self.iter_)
 
         it_global = self.iter_ + self._iter_rng_offset
         # fault injection: gradient poisoning at iteration k (the
@@ -2639,6 +2688,8 @@ class GBDTModel:
         iter_state = {"leaf_of_rows": [], "leaf_values": [], "trees": [],
                       "train_deltas": [], "valid_deltas": []}
         for k in range(self.num_class):
+            if obs is not None and k:
+                _sp = obs.phase("sample", self.iter_)
             g, h = g_all[:, k], h_all[:, k]
             if self._goss:
                 w = self._goss_vals(g, h)
@@ -2719,10 +2770,11 @@ class GBDTModel:
                 return a
 
             if obs is not None:
+                obs.end_phase(_sp, vals_g)
                 _sp = obs.phase("grow", self.iter_)
             arrays = _grow()
             if obs is not None:
-                obs.phase_metric("grow", _sp.end(arrays.num_leaves))
+                obs.end_phase(_sp, arrays.num_leaves)
                 _sp = obs.phase("fetch", self.iter_)
             # ONE batched host transfer of the tree-sized fields; the [N]
             # leaf_of_row stays on device (only pulled when renew/linear
@@ -2754,7 +2806,10 @@ class GBDTModel:
                 host = host_small._replace(leaf_of_row=arrays.leaf_of_row)
             if obs is not None:
                 # device_get blocks by itself; no fence needed
-                obs.phase_metric("fetch", _sp.end())
+                obs.end_phase(_sp)
+                # the host's tree out of the fetched arrays (no device
+                # work: no fence)
+                _sp = obs.phase("tree_host", self.iter_)
             nl = int(host.num_leaves)
             # perf observability: grower loop steps per tree (== splits
             # for strict leaf-wise; the super-step count for split_batch)
@@ -2847,6 +2902,7 @@ class GBDTModel:
             iter_trees.append(ht)
 
             if obs is not None:
+                obs.end_phase(_sp)
                 _sp = obs.phase("score", self.iter_)
             linear = cfg.linear_tree and nl > 1
             if linear:
@@ -2878,7 +2934,7 @@ class GBDTModel:
                         it_global)
                 self.score = self.score.at[:, k].add(delta)
             if obs is not None:
-                obs.phase_metric("score", _sp.end(self.score))
+                obs.end_phase(_sp, self.score)
                 # score-update site note (obs/flops.py) — host-side
                 # arithmetic only, gated so the telemetry-off path
                 # stays exactly one is-None branch
@@ -2887,6 +2943,9 @@ class GBDTModel:
                 note_traced("score",
                             *score_update_flops_bytes(self.num_data),
                             phase="score", cadence="iter")
+                # the device copy of the tree and its walk over every
+                # valid set
+                _sp = obs.phase("valid_score", self.iter_)
             iter_state["train_deltas"].append(delta)
 
             steps = round_up_pow2(max(ht.max_depth(), 1))
@@ -2920,6 +2979,9 @@ class GBDTModel:
                 self.valid_sets[vi] = (vds, vbinned,
                                        vscore.at[:, k].add(vd))
             iter_state["valid_deltas"].append(vdeltas)
+            if obs is not None:
+                obs.end_phase(_sp, [vs for _, _, vs in self.valid_sets]
+                              or None)
 
         if heal_score:
             # a tripped skip_iter check heals the score carry too: a NaN
@@ -2936,7 +2998,7 @@ class GBDTModel:
         if obs is not None:
             # all of this iteration's trees (num_class of them) count
             # toward its step/comm accounting
-            obs.iter_end(self.iter_ - 1, t_iter0,
+            obs.iter_end(self.iter_ - 1,
                          sum(self.step_counts[-self.num_class:]))
         if bbox is not None:
             # one host-side record per iteration (no device syncs: all
@@ -3002,14 +3064,16 @@ class GBDTModel:
 
 
 def create_boosting(config: Config, train_set: Dataset,
-                    objective, hist_reduce=None) -> GBDTModel:
-    """Boosting factory (boosting.cpp:35-68 CreateBoosting analog)."""
+                    objective, hist_reduce=None, obs=None) -> GBDTModel:
+    """Boosting factory (boosting.cpp:35-68 CreateBoosting analog).
+    ``obs``: the telemetry session the caller opened for this booster
+    (None: the model builds its own when ``telemetry`` is on)."""
     if config.boosting in ("gbdt", "gbrt"):
-        return GBDTModel(config, train_set, objective, hist_reduce)
+        return GBDTModel(config, train_set, objective, hist_reduce, obs)
     if config.boosting == "dart":
         from .dart import DARTModel
-        return DARTModel(config, train_set, objective, hist_reduce)
+        return DARTModel(config, train_set, objective, hist_reduce, obs)
     if config.boosting in ("rf", "random_forest"):
         from .rf import RFModel
-        return RFModel(config, train_set, objective, hist_reduce)
+        return RFModel(config, train_set, objective, hist_reduce, obs)
     raise ValueError(f"Unknown boosting type: {config.boosting}")

@@ -19,11 +19,12 @@ class RFModel(GBDTModel):
     _bias_in_every_tree = True
     average_output = True
 
-    def __init__(self, config, train_set, objective, hist_reduce=None):
+    def __init__(self, config, train_set, objective, hist_reduce=None,
+                 obs=None):
         if config.bagging_freq <= 0 or not (0.0 < config.bagging_fraction < 1.0):
             raise ValueError("rf requires bagging (bagging_freq>0, "
                              "0<bagging_fraction<1)")
-        super().__init__(config, train_set, objective, hist_reduce)
+        super().__init__(config, train_set, objective, hist_reduce, obs)
         self._const_score = None
 
     def _score_for_gradients(self):

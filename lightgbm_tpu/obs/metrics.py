@@ -133,13 +133,15 @@ class MetricsRegistry:
                             f"{type(inst).__name__}, not {cls.__name__}")
         return inst
 
-    def counter(self, name: str, **labels: Any) -> Counter:
+    # the instrument's name is positional-only: ``name`` is also a label
+    # (``jax.traces{name=grower}``)
+    def counter(self, name: str, /, **labels: Any) -> Counter:
         return self._get(Counter, name, labels)
 
-    def gauge(self, name: str, **labels: Any) -> Gauge:
+    def gauge(self, name: str, /, **labels: Any) -> Gauge:
         return self._get(Gauge, name, labels)
 
-    def histogram(self, name: str,
+    def histogram(self, name: str, /,
                   buckets: Optional[Tuple[float, ...]] = None,
                   **labels: Any) -> Histogram:
         kw = {"buckets": tuple(buckets)} if buckets else {}

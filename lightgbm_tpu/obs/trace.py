@@ -12,14 +12,27 @@ through here.
 Event model: spans are Chrome-trace "complete" events (``ph": "X"``)
 with microsecond ``ts``/``dur`` on the monotonic clock, written one
 JSON object per line (JSONL) so a crash loses at most the line in
-flight.  ``jsonl_to_chrome`` wraps the same records into the
+flight.  Every span carries an ``id`` (unique in the process) and the
+``parent`` id of the span that was open on its thread when it began, so
+a layer's self time is its duration minus its children's; a tracer's
+``ctx`` (the booster's identifier) rides on every record.
+``jsonl_to_chrome`` wraps the same records into the
 ``{"traceEvents": [...]}`` envelope Perfetto / chrome://tracing load
 directly — the round trip is loss-free because the JSONL records ARE
 trace events.
+
+One clock with the device: a span opened with ``mirror=True`` holds a
+``jax.profiler.TraceAnnotation`` of its name for as long as it is open,
+so any profiler session running meanwhile shows it on the host plane
+beside the device's operations.  Only spans that PARTITION a job are
+mirrored: a trace reader names an idle gap by the host event that
+overlaps it most, and an annotation enclosing a whole iteration would
+take every gap.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -64,6 +77,9 @@ def fence(x: Any = None) -> Any:
     return x
 
 
+_SPAN_IDS = itertools.count(1)     # next() is atomic under the GIL
+
+
 class Span:
     """One open span; closes via context-manager exit or ``end()``.
 
@@ -71,16 +87,31 @@ class Span:
     — a span that timed asynchronous device
     work must wait on a value derived from that work, or the time leaks
     into whoever blocks next.  ``end()`` with no result (and plain
-    ``with``-exit) records wall time without touching the device."""
+    ``with``-exit) records wall time without touching the device.
 
-    __slots__ = ("tracer", "name", "args", "t0", "_done")
+    ``parent`` is the id of the span that was open on this thread (and
+    this tracer) when this one began.  ``mirror`` enters a
+    ``jax.profiler.TraceAnnotation`` of the same name until ``end()``."""
 
-    def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
+    __slots__ = ("tracer", "name", "args", "t0", "id", "parent",
+                 "_open", "_ann", "_done")
+
+    def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any],
+                 mirror: bool = False):
         self.tracer = tracer
         self.name = name
         self.args = args
-        self.t0 = tracer.now()
+        self.id = next(_SPAN_IDS)
+        self._open = tracer._open_spans()
+        self.parent = self._open[-1].id if self._open else None
+        self._open.append(self)
         self._done = False
+        self._ann = None
+        if mirror:
+            import jax.profiler
+            self._ann = jax.profiler.TraceAnnotation(name)
+            self._ann.__enter__()
+        self.t0 = tracer.now()
 
     def __enter__(self) -> "Span":
         return self
@@ -94,7 +125,15 @@ class Span:
         if result is not None:
             fence(result)
         dur = self.tracer.now() - self.t0
-        self.tracer._emit(self.name, self.t0, dur, self.args)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        try:
+            # with it go the spans above it, abandoned by an exception
+            del self._open[self._open.index(self):]
+        except ValueError:
+            pass
+        self.tracer._emit(self.name, self.t0, dur, self.args,
+                          span_id=self.id, parent=self.parent)
         return dur
 
     def __exit__(self, *exc) -> bool:
@@ -105,16 +144,21 @@ class Span:
 class Tracer:
     """Nested-span tracer with an optional JSONL sink.
 
-    Spans nest naturally (the Chrome trace model infers nesting from
-    containment of [ts, ts+dur) per tid), so no explicit stack is kept;
-    ``span()`` is re-entrant and thread-safe.  Events are retained
-    in-memory (for programmatic export) AND streamed to the sink the
-    moment each span closes.
+    Each thread keeps the list of its open spans, from which a new span
+    takes its ``parent``; ``span()`` is re-entrant and thread-safe.
+    Events are retained in-memory (for programmatic export) AND streamed
+    to the sink the moment each span closes.  ``ctx`` is written into
+    every record (the training session puts the booster's identifier
+    there, so the spans of one booster are told from another's in a
+    shared sink).
     """
 
     def __init__(self, sink_path: Optional[str] = None,
-                 pid: Optional[int] = None):
+                 pid: Optional[int] = None,
+                 ctx: Optional[Dict[str, Any]] = None):
         self.events: List[Dict[str, Any]] = []
+        self.ctx = dict(ctx or {})
+        self._local = threading.local()
         self._lock = threading.Lock()
         self._sink = None
         self._sink_path = sink_path
@@ -136,18 +180,31 @@ class Tracer:
         clock Python exposes)."""
         return time.perf_counter()
 
-    def span(self, name: str, **args: Any) -> Span:
-        return Span(self, name, args)
+    def _open_spans(self) -> List[Span]:
+        try:
+            return self._local.open
+        except AttributeError:
+            self._local.open = []
+            return self._local.open
+
+    def span(self, name: str, mirror: bool = False, **args: Any) -> Span:
+        return Span(self, name, args, mirror)
 
     def instant(self, name: str, **args: Any) -> None:
         """Zero-duration marker event (``ph: "i"``)."""
         self._emit(name, self.now(), 0.0, args, ph="i")
 
     def _emit(self, name: str, t0: float, dur: float,
-              args: Dict[str, Any], ph: str = "X") -> None:
+              args: Dict[str, Any], ph: str = "X",
+              span_id: Optional[int] = None,
+              parent: Optional[int] = None) -> None:
         ev = {"name": name, "ph": ph, "ts": round(t0 * 1e6, 3),
               "dur": round(dur * 1e6, 3), "pid": self.pid,
               "tid": threading.get_ident() & 0xFFFF}
+        if span_id is not None:
+            ev["id"] = span_id
+            ev["parent"] = parent
+        ev.update(self.ctx)
         if args:
             ev["args"] = args
         with self._lock:
@@ -172,12 +229,6 @@ class Tracer:
             if self._sink is not None:
                 self._sink.close()
                 self._sink = None
-
-    def export_chrome(self, path: str) -> None:
-        """Write the in-memory events as a Chrome/Perfetto trace file."""
-        with self._lock:
-            events = list(self.events)
-        _write_chrome(path, events)
 
 
 def timed_fenced(fn, iters: int = 10, tracer: Optional[Tracer] = None,
@@ -215,16 +266,12 @@ def read_jsonl(path: str) -> List[Dict[str, Any]]:
     return out
 
 
-def _write_chrome(path: str, events: List[Dict[str, Any]]) -> None:
-    with open(path, "w") as f:
-        f.write(json.dumps({"traceEvents": events,
-                            "displayTimeUnit": "ms"}))
-
-
 def jsonl_to_chrome(src: str, dst: str) -> int:
     """Convert a JSONL event sink into a Chrome-trace JSON file that
     Perfetto (ui.perfetto.dev) and chrome://tracing load directly;
     returns the event count."""
     events = read_jsonl(src)
-    _write_chrome(dst, events)
+    with open(dst, "w") as f:
+        f.write(json.dumps({"traceEvents": events,
+                            "displayTimeUnit": "ms"}))
     return len(events)

@@ -124,8 +124,12 @@ def compute_histogram(binned: jax.Array, vals: jax.Array, *, num_bins: int,
                                      num_slots=num_slots)
 
 
+# device-phase names (metadata only): the pass is ``lgbtpu.hist``, and in
+# it the making of the one-hot operand and the contraction, which are
+# separate operations on the chip, carry a scope each
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block_rows", "num_slots"))
+@jax.named_scope("lgbtpu.hist")
 def _compute_histogram_matmul(binned: jax.Array, vals: jax.Array, *,
                               num_bins: int, block_rows: int = 0,
                               slot: Optional[jax.Array] = None,
@@ -210,8 +214,9 @@ def _compute_histogram_matmul(binned: jax.Array, vals: jax.Array, *,
             # exact zeros (no slot reaches them), sliced off after the
             # scan, so they cost MXU cycles, never numerics
             vals_blk = jnp.pad(vals_blk, ((0, 0), (0, c_pad - c)))
-        onehot = (bins_blk.astype(jnp.int32)[:, :, None] == iota) \
-            .astype(op_dt).reshape(block_rows, f * bp)
+        with jax.named_scope("lgbtpu.hist.onehot"):
+            onehot = (bins_blk.astype(jnp.int32)[:, :, None] == iota) \
+                .astype(op_dt).reshape(block_rows, f * bp)
         # [C, block] x [block, F*Bp] -> [C, F*Bp]: the narrow C=3 axis maps
         # to output SUBLANES (padded 3->8) instead of lanes (3->128), a
         # measured ~2.2x win over the transposed orientation.
@@ -221,12 +226,13 @@ def _compute_histogram_matmul(binned: jax.Array, vals: jax.Array, *,
         # rows of 1 + 2^-12 sum to the row count).  The 0/1 operand is
         # exact either way; HIGHEST carries all 24 bits of the accumuland
         # into the f32 accumulator.  Integer operands are exact as is.
-        h = lax.dot_general(
-            vals_blk, onehot,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            precision=None if integer else lax.Precision.HIGHEST,
-            preferred_element_type=acc_dt)
-        return acc + h, None
+        with jax.named_scope("lgbtpu.hist.contract"):
+            h = lax.dot_general(
+                vals_blk, onehot,
+                dimension_numbers=(((0,), (0,)), ((), ())),
+                precision=None if integer else lax.Precision.HIGHEST,
+                preferred_element_type=acc_dt)
+            return acc + h, None
 
     acc0 = jnp.zeros((c_pad, f * bp), dtype=acc_dt)
     acc, _ = lax.scan(body, acc0, xs)

@@ -340,6 +340,7 @@ def _monotone_adjust(gains, lefts, total, mono, out_lo, out_hi, dir_axis,
     return jnp.where(was_valid & ok & (gains > kEpsilon), gains, kMinScore)
 
 
+@jax.named_scope("lgbtpu.split")
 def find_best_split(hist: jax.Array, total: jax.Array, num_bin: jax.Array,
                     na_bin: jax.Array, feature_mask: jax.Array,
                     params: SplitParams, parent_output: jax.Array = None,

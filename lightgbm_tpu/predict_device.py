@@ -25,6 +25,7 @@ from .utils.shapes import round_up_pow2  # noqa: F401  (shared policy;
 
 
 @functools.partial(jax.jit, static_argnames=("steps",))
+@jax.named_scope("lgbtpu.walk")
 def traverse_tree_binned(binned, split_feature, threshold_bin, default_left,
                          left_child, right_child, na_bin, is_cat_node,
                          cat_rank, efb_maps=None, *, steps: int):
@@ -78,7 +79,8 @@ def add_tree_score(score, binned, split_feature, threshold_bin, default_left,
                                 default_left, left_child, right_child,
                                 na_bin, is_cat_node, cat_rank, efb_maps,
                                 steps=steps)
-    return score + weight * jnp.take(leaf_value, leaf)
+    with jax.named_scope("lgbtpu.score"):
+        return score + weight * jnp.take(leaf_value, leaf)
 
 
 # (round_up_pow2 moved to utils/shapes.py — the ONE bucketing policy

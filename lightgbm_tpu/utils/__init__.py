@@ -1,2 +1,1 @@
 from .log import Log, register_log_callback
-from .timer import FunctionTimer, global_timer

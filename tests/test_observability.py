@@ -9,8 +9,13 @@ Covers the ISSUE 3 acceptance surface:
 - JSONL traces round-trip through the Perfetto exporter;
 - comm-bytes counters match the PR 1 per-shard hist-bytes math;
 - metrics aggregation is deterministic and agrees dp == serial;
-- satellites: verbosity -> log level mapping, timer atexit gating,
-  profiler-window param validation, log_telemetry callback.
+- satellites: verbosity -> log level mapping, profiler-window param
+  validation, log_telemetry callback.
+
+And ISSUE 26's: one span API on the profiler's clock — every phase of a
+``lgb.cv`` job spanned with ``id`` / ``parent``, mirrored into a profiler
+session, observed into the registry; device phases named by
+``jax.named_scope`` without changing the lowered program.
 """
 
 import json
@@ -30,16 +35,6 @@ from lightgbm_tpu.obs.trace import (Tracer, fence, jsonl_to_chrome,
 from lightgbm_tpu.utils.log import Log
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _quiet_global_timer():
-    """ObsSession flips the process-global timer on (the FunctionTimer
-    feed); restore the off default so later test modules' scopes don't
-    arm the exit summary."""
-    yield
-    from lightgbm_tpu.utils.timer import global_timer
-    global_timer.enabled = False
 
 
 def _small_data(n=1200, f=8, seed=3):
@@ -135,11 +130,15 @@ class TestTelemetryOff:
         assert calls[0] == 3, \
             f"expected 1 device_get per iteration, saw {calls[0]} over 3"
 
-    def test_telemetry_on_only_adds_fences(self, monkeypatch):
-        """With telemetry=true the extra syncs are exactly the three
-        phase fences (grad/grow/score; fetch rides the existing
-        device_get) — pinning the span structure."""
+    @pytest.mark.parametrize("with_valid", [False, True])
+    def test_telemetry_on_only_adds_fences(self, monkeypatch, with_valid):
+        """With telemetry=true the extra syncs of an iteration are
+        exactly the fences of the phases that queue device work — grad,
+        sample, grow, score and, with a valid set, valid_score; fetch
+        rides the existing device_get, tree_host is host work — pinning
+        the span structure, each fence by its span's name."""
         import jax
+        from lightgbm_tpu.obs.trace import Span
         x, y = _small_data()
         base = {"objective": "binary", "num_leaves": 7,
                 "min_data_in_leaf": 5, "verbosity": 0, "fused_chunk": 0,
@@ -147,18 +146,30 @@ class TestTelemetryOff:
         ds = lgb.Dataset(x, label=y, params=base)
         ds.construct()
         bst = lgb.Booster(params=base, train_set=ds)
+        if with_valid:
+            bst.add_valid(lgb.Dataset(x[:300], label=y[:300],
+                                      reference=ds), "valid")
         bst.update()
 
-        calls = [0]
-        real = jax.device_get
+        calls, fenced = [0], []
+        real, real_end = jax.device_get, Span.end
 
         def counting(*a, **kw):
             calls[0] += 1
             return real(*a, **kw)
 
+        def naming(self, result=None):
+            if result is not None:
+                fenced.append(self.name)
+            return real_end(self, result)
+
         monkeypatch.setattr(jax, "device_get", counting)
+        monkeypatch.setattr(Span, "end", naming)
         bst.update()
-        assert calls[0] == 4               # 1 fetch + 3 phase fences
+        want = ["lgbtpu.grad", "lgbtpu.sample", "lgbtpu.grow",
+                "lgbtpu.score"] + ["lgbtpu.valid_score"] * with_valid
+        assert fenced == want
+        assert calls[0] == 1 + len(want)   # 1 fetch + the phase fences
 
 
 # -- traces ----------------------------------------------------------------
@@ -215,7 +226,10 @@ class TestTrace:
                      n_iter=2)
         bst.telemetry_finish()
         names = {e["name"] for e in read_jsonl(sink)}
-        assert {"grad", "grow", "fetch", "score"} <= names
+        assert {"lgbtpu." + p for p in (
+            "booster.init", "booster.to_device", "grower.make", "iter",
+            "init_score", "grad", "sample", "grow", "fetch", "tree_host",
+            "score", "valid_score")} <= names
 
 
 # -- metrics ---------------------------------------------------------------
@@ -261,9 +275,12 @@ class TestMetrics:
         snap = bst.telemetry_snapshot()
         assert snap["train.iterations"]["value"] == 3.0
         assert snap["train.steps_per_tree"]["count"] == 3
-        for phase in ("grad", "grow", "fetch", "score"):
+        for phase in ("grad", "sample", "grow", "fetch", "tree_host",
+                      "score", "valid_score"):
             key = f"train.phase_seconds{{phase={phase}}}"
             assert snap[key]["count"] == 3
+        # boost-from-average runs in the first iteration alone
+        assert snap["train.phase_seconds{phase=init_score}"]["count"] == 1
 
     def test_fused_chunk_counts_iterations(self):
         x, y = _small_data(2400)
@@ -396,28 +413,6 @@ class TestVerbosityMapping:
             Log.level = old
 
 
-class TestTimerGating:
-    def test_atexit_not_armed_by_import_or_disabled_use(self):
-        import atexit
-
-        from lightgbm_tpu.utils.timer import Timer
-
-        t = Timer()
-        t.stop("x", t.start("x"))          # disabled: must not arm
-        assert not t._atexit_armed
-        t.enabled = True
-        t.stop("x", t.start("x"))
-        assert t._atexit_armed
-        atexit.unregister(t.print_summary)  # keep the test run silent
-
-    def test_print_summary_silent_without_stats(self, capsys):
-        from lightgbm_tpu.utils.timer import Timer
-        t = Timer()
-        t.enabled = True
-        t.print_summary()
-        assert capsys.readouterr().out == ""
-
-
 class TestSession:
     def test_maybe_session_off_by_default(self):
         assert maybe_session(Config({})) is None
@@ -488,3 +483,359 @@ class TestLogTelemetryCallback:
                   callbacks=[lgb.log_telemetry(period=1,
                                                collect=collected)])
         assert collected == {}
+
+
+# -- ISSUE 26: one span API, on the profiler's clock ------------------------
+
+CV_PARAMS = {"objective": "binary", "num_leaves": 7, "max_bin": 31,
+             "min_data_in_leaf": 5, "verbosity": 0, "telemetry": True,
+             "fused_chunk": 0, "tpu_learner": "masked", "metric": "auc"}
+SETUP_SPANS = {"cv.fold_setup", "dataset.subset", "booster.init",
+               "booster.to_device", "grower.make"}
+ITER_PHASES = ["init_score", "grad", "sample", "grow", "fetch", "tree_host",
+               "score", "valid_score"]
+
+
+def _cv(tmp_path=None, **extra):
+    """A two-fold, two-round ``lgb.cv`` with telemetry on; the folds'
+    boosters (their sessions hold the spans and the registry)."""
+    x, y = _small_data()
+    params = dict(CV_PARAMS, **extra)
+    if tmp_path is not None:
+        params["telemetry_trace_file"] = str(tmp_path / "cv.jsonl")
+    out = lgb.cv(params, lgb.Dataset(x, label=y, params=params),
+                 num_boost_round=2, nfold=2, stratified=False,
+                 return_cvbooster=True)
+    return out["cvbooster"].boosters
+
+
+def _spans(bst):
+    return [e for e in bst._model._obs.tracer.events if e["ph"] == "X"]
+
+
+class TestSpanRecord:
+    def test_id_parent_and_ctx(self):
+        tr = Tracer(pid=0, ctx={"booster": 9})
+        with tr.span("outer") as outer:
+            with tr.span("inner") as inner:
+                pass
+            with tr.span("second") as second:
+                pass
+        assert outer.parent is None
+        assert inner.parent == outer.id and second.parent == outer.id
+        assert len({outer.id, inner.id, second.id}) == 3
+        by_name = {e["name"]: e for e in tr.events}
+        assert by_name["inner"]["parent"] == by_name["outer"]["id"]
+        assert all(e["booster"] == 9 for e in tr.events)
+        # an instant is no span: no id, no parent
+        tr.instant("marker")
+        assert "id" not in tr.events[-1]
+
+    def test_self_time_is_duration_less_children(self):
+        import time
+        tr = Tracer(pid=0)
+        with tr.span("layer"):
+            time.sleep(0.002)
+            with tr.span("below"):
+                time.sleep(0.01)
+        layer, below = (next(e for e in tr.events if e["name"] == n)
+                        for n in ("layer", "below"))
+        own = layer["dur"] - sum(e["dur"] for e in tr.events
+                                 if e.get("parent") == layer["id"])
+        assert 0 < own < below["dur"]
+
+    def test_a_span_left_open_by_an_exception_is_dropped(self):
+        tr = Tracer(pid=0)
+        outer = tr.span("outer")
+        tr.span("abandoned")            # never closed
+        outer.end()
+        assert tr.span("next").parent is None
+
+    def test_spans_of_threads_do_not_adopt_each_other(self):
+        import threading
+        tr = Tracer(pid=0)
+        got = []
+        with tr.span("main"):
+            t = threading.Thread(
+                target=lambda: got.append(tr.span("other").parent))
+            t.start()
+            t.join()
+        assert got == [None]
+
+    def test_name_is_a_label_too(self):
+        """``jax.traces{name=grower}``: the instrument's name is
+        positional-only, so a label may be called ``name``."""
+        r = MetricsRegistry()
+        r.counter("jax.traces", name="grower").inc()
+        assert r.snapshot()["jax.traces{name=grower}"]["value"] == 1.0
+
+
+class TestJobSpans:
+    def test_cv_job_yields_every_span_in_one_tree_a_booster(self, tmp_path):
+        boosters = _cv(tmp_path)
+        ids_seen = set()
+        for fold, bst in enumerate(boosters):
+            spans = _spans(bst)
+            names = {e["name"][len("lgbtpu."):] for e in spans}
+            assert all(e["name"].startswith("lgbtpu.") for e in spans)
+            assert SETUP_SPANS | set(ITER_PHASES) | {"iter", "eval"} <= names
+            by_id = {e["id"]: e for e in spans}
+            assert len(by_id) == len(spans) and not ids_seen & set(by_id)
+            ids_seen |= set(by_id)
+            sid = bst._model._obs.id
+            assert all(e["booster"] == sid for e in spans)
+            # one tree under the booster: every parent is a span of the
+            # same booster; at the top only the fold's set-up, the
+            # iterations and the evaluations between them
+            tops = {e["name"][len("lgbtpu."):] for e in spans
+                    if e["parent"] is None}
+            assert tops == {"cv.fold_setup", "iter", "eval"}
+            assert all(e["parent"] in by_id for e in spans
+                       if e["parent"] is not None)
+
+            def parent_name(e):
+                return by_id[e["parent"]]["name"][len("lgbtpu."):]
+            for e in spans:
+                name = e["name"][len("lgbtpu."):]
+                if name in ITER_PHASES:
+                    assert parent_name(e) == "iter"
+                elif name in ("dataset.subset", "booster.init"):
+                    assert parent_name(e) == "cv.fold_setup"
+                elif name == "grower.make":
+                    assert parent_name(e) == "booster.init"
+                elif name == "booster.to_device":
+                    assert parent_name(e) == {
+                        "binned": "booster.init", "row_state": "booster.init",
+                        "valid": "cv.fold_setup"}[e["args"]["what"]]
+            setup = next(e for e in spans
+                         if e["name"] == "lgbtpu.cv.fold_setup")
+            assert setup["args"] == {"fold": fold}
+            subsets = [e for e in spans
+                       if e["name"] == "lgbtpu.dataset.subset"]
+            assert sorted(e["args"]["rows"] for e in subsets) == [600, 600]
+            # the gathered rows: uint8 bins and the float64 raw values
+            assert all(e["args"]["bytes"] == 600 * 8 * (1 + 8)
+                       for e in subsets)
+            evals = [e for e in spans if e["name"] == "lgbtpu.eval"]
+            assert [e["args"]["rows"] for e in evals] == [600, 600]
+        # the shared sink holds both boosters' spans, told apart by id
+        events = read_jsonl(str(tmp_path / "cv.jsonl"))
+        assert {e["booster"] for e in events if e["ph"] == "X"} \
+            == {b._model._obs.id for b in boosters}
+
+    def test_registry_sees_what_the_spans_saw(self):
+        # a grower no earlier test built: the memo's first answer is miss
+        boosters = _cv(lambda_l2=0.0626)
+        for fold, bst in enumerate(boosters):
+            m, snap = bst._model, bst.telemetry_snapshot()
+            spans = _spans(bst)
+            placed = [m.binned_dev, m.score, m.objective.label] \
+                + [a for _, vb, vs in m.valid_sets for a in (vb, vs)]
+            assert snap["xfer.h2d_bytes"]["value"] \
+                == sum(int(a.nbytes) for a in placed)
+            assert sum(e["args"]["bytes"] for e in spans
+                       if e["name"] == "lgbtpu.booster.to_device") \
+                == snap["xfer.h2d_bytes"]["value"]
+            # the memo of jitted growers: the second fold's booster runs
+            # the program the first one traced
+            result = "hit" if fold else "miss"
+            assert snap[f"grower.memo{{result={result}}}"]["value"] == 1.0
+            assert ("jax.traces{name=grower}" in snap) == (fold == 0)
+            for stage, n in (("fold_setup", 1), ("subset", 2),
+                             ("booster_init", 1), ("to_device", 3),
+                             ("grower_make", 1)):
+                h = snap[f"train.setup_seconds{{stage={stage}}}"]
+                assert h["count"] == n
+            assert snap["train.eval_seconds"]["count"] == 2
+            # Dataset.construct's own stages, from the root of the
+            # fold's reference chain
+            root = bst.train_set.get_ref_chain()[-1]
+            assert set(root.construct_seconds) == {"to_numpy", "fit_bins",
+                                                   "bin_data"}
+            for stage, seconds in root.construct_seconds.items():
+                h = snap[f"data.construct_seconds{{stage={stage}}}"]
+                assert h["count"] == 1 and h["sum"] == seconds
+
+    def test_phases_partition_the_iteration(self):
+        for bst in _cv():
+            snap = bst.telemetry_snapshot()
+            phases = sum(rec["sum"] for key, rec in snap.items()
+                         if key.startswith("train.phase_seconds{"))
+            whole = snap["train.iter_seconds"]["sum"]
+            assert 0 < phases <= whole
+            # what no phase owns is the loop's own few lines
+            assert (whole - phases) / whole < 0.10
+            for e in _spans(bst):
+                if e["name"] == "lgbtpu.iter":
+                    kids = sum(k["dur"] for k in _spans(bst)
+                               if k["parent"] == e["id"])
+                    assert kids <= e["dur"]
+
+    def test_a_span_inside_a_phase_stays_out_of_the_registry(self):
+        obs = ObsSession()
+        obs.iter_begin(0)
+        grow = obs.phase("grow", 0)
+        inner = obs.phase("hist_pass", 0)        # nested: tracer only
+        obs.end_phase(inner)
+        obs.end_phase(grow)
+        obs.iter_end(0)
+        snap = obs.snapshot()
+        assert "train.phase_seconds{phase=grow}" in snap
+        assert "train.phase_seconds{phase=hist_pass}" not in snap
+        names = [e["name"] for e in obs.tracer.events]
+        assert names == ["lgbtpu.hist_pass", "lgbtpu.grow", "lgbtpu.iter"]
+
+    def test_a_fold_setup_lands_in_the_boosters_own_session(self):
+        """``cv.fold_setup`` opens before the Booster exists: the fold's
+        session is built first and handed down, not replaced."""
+        for bst in _cv():
+            obs = bst._model._obs
+            setup = [e for e in obs.tracer.events
+                     if e["name"] == "lgbtpu.cv.fold_setup"]
+            assert len(setup) == 1
+            assert "train.setup_seconds{stage=fold_setup}" in obs.snapshot()
+
+    def test_traces_go_to_the_booster_that_runs(self):
+        """Five sessions are built before the first iteration runs; a
+        trace is counted in the session of the booster whose iteration
+        traced, not in the one built last."""
+        a, b = ObsSession(), ObsSession()
+        from lightgbm_tpu.utils.compile_cache import trace_event
+        a.iter_begin(0)
+        trace_event("probe_program")
+        a.iter_end(0)
+        assert a.snapshot()["jax.traces{name=probe_program}"]["value"] == 1
+        assert "jax.traces{name=probe_program}" not in b.snapshot()
+
+
+class TestProfilerClock:
+    def test_host_plane_holds_the_partition_and_no_enclosing_span(self):
+        """Under a profiler session (as benchmarks/run.py::Trace opens
+        it) the program's spans are host events beside jax's; the span
+        that encloses a whole iteration is not among them, or it would
+        name every idle gap."""
+        import jax
+        from jax._src.lib import _profiler
+        x, y = _small_data()
+        ds = lgb.Dataset(x, label=y, params=CV_PARAMS).construct()
+        jax.devices()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        session = _profiler.ProfilerSession(opts)
+        try:
+            lgb.cv(CV_PARAMS, ds, num_boost_round=2, nfold=2,
+                   stratified=False)
+        finally:
+            data = session.stop_and_get_profile_data()
+        host = [ev.name for plane in data.planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events
+                if ev.name.startswith("lgbtpu.")]
+        assert host.count("lgbtpu.cv.fold_setup") == 2
+        assert host.count("lgbtpu.grow") == 4
+        assert host.count("lgbtpu.eval") == 4
+        assert "lgbtpu.iter" not in host
+        assert set(host) == {"lgbtpu." + n for n in
+                             SETUP_SPANS | set(ITER_PHASES) | {"eval"}}
+
+    def test_no_annotation_without_telemetry(self, monkeypatch):
+        import jax.profiler
+
+        def boom(*a, **kw):
+            raise AssertionError("telemetry=false made a TraceAnnotation")
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+        x, y = _small_data()
+        params = dict(CV_PARAMS, telemetry=False)
+        lgb.cv(params, lgb.Dataset(x, label=y, params=params),
+               num_boost_round=1, nfold=2, stratified=False)
+
+
+class TestDeviceScopes:
+    """``jax.named_scope`` names the device's phases and changes nothing
+    else: with the scopes patched to nothing the same program lowers."""
+
+    @staticmethod
+    def _grower_text(split_batch, debug):
+        import jax
+        import jax.numpy as jnp
+        from lightgbm_tpu.grower import make_grower
+        from lightgbm_tpu.ops.split import SplitParams
+        n, f = 512, 6
+        fn = make_grower(num_leaves=7, num_bins=32, split_batch=split_batch,
+                         params=SplitParams(min_data_in_leaf=5), jit=False)
+        args = (jnp.zeros((n, f), jnp.uint8), jnp.zeros((n, 3), jnp.float32),
+                jnp.ones(f, bool), jnp.full(f, 32, jnp.int32),
+                jnp.full(f, -1, jnp.int32))
+        return jax.jit(fn).lower(*args).as_text(debug_info=debug)
+
+    @staticmethod
+    def _hist_text(debug):
+        import jax
+        import jax.numpy as jnp
+        from lightgbm_tpu.ops.histogram import compute_histogram
+        fn = jax.jit(lambda b, v, s: compute_histogram(
+            b, v, num_bins=32, slot=s, num_slots=4))
+        return fn.lower(jnp.zeros((512, 6), jnp.uint8),
+                        jnp.zeros((512, 3), jnp.float32),
+                        jnp.zeros(512, jnp.int32)).as_text(debug_info=debug)
+
+    @pytest.mark.parametrize("split_batch", [1, 4])
+    def test_grower_carries_every_scope(self, split_batch):
+        text = self._grower_text(split_batch, True)
+        for scope in ("lgbtpu.grow", "lgbtpu.partition", "lgbtpu.hist/",
+                      "lgbtpu.hist.onehot", "lgbtpu.hist.contract",
+                      "lgbtpu.hist.state", "lgbtpu.split"):
+            assert scope in text, scope
+
+    def test_histogram_pass_carries_its_scopes(self):
+        text = self._hist_text(True)
+        assert "lgbtpu.hist.onehot" in text
+        assert "lgbtpu.hist.contract" in text
+
+    def test_walk_score_and_eval_carry_scopes(self):
+        import jax
+        import jax.numpy as jnp
+        from lightgbm_tpu.metrics import build_traced_eval
+        from lightgbm_tpu.predict_device import add_tree_score
+        z = jnp.zeros(3, jnp.int32)
+        text = add_tree_score.lower(
+            jnp.zeros(64), jnp.zeros((64, 4), jnp.uint8), z, z,
+            jnp.zeros(3, bool), z, z, jnp.full(4, -1, jnp.int32),
+            jnp.zeros(3, bool), jnp.zeros((3, 32), jnp.int32),
+            jnp.zeros(4), jnp.float32(1.0), steps=2) \
+            .as_text(debug_info=True)
+        assert "lgbtpu.walk" in text and "lgbtpu.score" in text
+        teval = build_traced_eval(((0, "valid", "auc", True),),
+                                  Config({"objective": "binary"}))
+        text = teval.lower((jnp.zeros(64),), ((jnp.zeros(64), jnp.ones(64)),)) \
+            .as_text(debug_info=True)
+        assert "lgbtpu.eval" in text
+
+    def test_scopes_change_nothing_but_names(self, monkeypatch):
+        """The lowered text without locations (where a scope's name
+        lives) is the same with the scopes and with ``jax.named_scope``
+        patched to nothing."""
+        import contextlib
+
+        import jax
+
+        def texts(debug):
+            return [self._grower_text(1, debug), self._grower_text(4, debug),
+                    self._hist_text(debug)]
+        with_scopes = texts(False)
+        assert all("lgbtpu." not in t for t in with_scopes)
+
+        @contextlib.contextmanager
+        def no_scope(name):
+            yield
+        monkeypatch.setattr(jax, "named_scope", no_scope)
+        jax.clear_caches()
+        try:
+            without, named = texts(False), texts(True)
+        finally:
+            jax.clear_caches()      # nothing traced here outlives the test
+        # (a decorator applied at import keeps its scope; every ``with``
+        # block and the growers' own decorators are patched away)
+        assert all("lgbtpu.partition" not in t and "lgbtpu.grow" not in t
+                   and "lgbtpu.hist.onehot" not in t for t in named)
+        assert without == with_scopes

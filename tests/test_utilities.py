@@ -1,4 +1,4 @@
-"""Aux subsystem tests: logging, timers, dump_model, refit, pred early stop
+"""Aux subsystem tests: logging, dump_model, refit, pred early stop
 (test_utilities.py / SURVEY.md §5 analog)."""
 
 import json
@@ -7,8 +7,7 @@ import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.utils import FunctionTimer, Log, global_timer, \
-    register_log_callback
+from lightgbm_tpu.utils import Log, register_log_callback
 
 
 class TestChooseParamValue:
@@ -65,13 +64,6 @@ class TestLog:
     def test_fatal_raises(self):
         with pytest.raises(RuntimeError):
             Log.fatal("boom")
-
-
-class TestTimer:
-    def test_scopes_accumulate(self):
-        with FunctionTimer("unit_test_scope"):
-            pass
-        assert global_timer.counts["unit_test_scope"] >= 1
 
 
 class TestDumpModel:

@@ -4,7 +4,8 @@ Attribute every millisecond of a steady-state iteration to a named
 phase.  Since the obs subsystem this
 script is a THIN consumer: it enables ``telemetry=true`` on the booster
 and reads the per-phase spans the training loop itself emits
-(grad / grow / fetch / score, models/gbdt.py) — the same spans a
+(grad / sample / grow / fetch / tree_host / score / valid_score,
+models/gbdt.py) — the same spans a
 production run records — plus a couple of raw-latency probes timed with
 ``obs.trace.timed_fenced``.
 
@@ -79,8 +80,9 @@ def main():
     # attribution — no replicated pipeline, no hand-rolled fences
     reps = 8
     obs = m._obs
-    skip = {k: len(obs.tracer.durations(k))
-            for k in ("grad", "grow", "fetch", "score")}
+    phases = ("grad", "sample", "grow", "fetch", "tree_host", "score",
+              "valid_score")
+    skip = {k: len(obs.tracer.durations("lgbtpu." + k)) for k in phases}
     t0 = time.perf_counter()
     for _ in range(reps):
         bst.update()
@@ -90,8 +92,8 @@ def main():
     print(f"\nper-phase (over {reps} reps), n={n} leaves={num_leaves}:",
           file=sys.stderr)
     phase_sum = 0.0
-    for k in ("grad", "grow", "fetch", "score"):
-        v = obs.tracer.durations(k)[skip[k]:]
+    for k in phases:
+        v = obs.tracer.durations("lgbtpu." + k)[skip[k]:]
         if not v:
             continue
         phase_sum += min(v)
