@@ -25,7 +25,10 @@ persistent-cache hits and misses, and the device's peak bytes in use):
 - ``numerics``  the histogram contraction at 28 features x 64 padded bins
   against float64 NumPy: a probe that is exact in f32 but not in bf16 must
   be reproduced *exactly* (dense and k-hot), random data must meet the f32
-  summation bound, the int8 path must equal an int64 reference.
+  summation bound, the int8 path must equal an int64 reference.  The same
+  probe and bound at the benchmark cell's width (2,000 features, 16 slots,
+  32,768 rows), and the count of contractions traced by implementation
+  (on the chip the f32 passes take the VMEM kernel, the int8 one the scan).
 - ``facts``     three bring-up observations: blocking 4-byte fetch latency,
   ``block_until_ready`` against ``obs.trace.fence``, and whether
   ``telemetry_profile_iters`` leaves a non-empty xplane file.
@@ -49,6 +52,7 @@ import time
 import numpy as np
 
 N_TRAIN, N_VALID, N_FEAT = 1_000_000, 100_000, 28
+N_WIDE, F_WIDE = 32_768, 2_000     # the benchmark cell's width, few rows
 MAX_BIN = 63
 ROUNDS_255, ROUNDS_31, ROUNDS_4CHIP = 32, 25, 4
 REQUEST_ROWS = (1, 7, 64, 1000, 4096)
@@ -315,6 +319,24 @@ def _bincount_hist(bins: np.ndarray, vals: np.ndarray) -> np.ndarray:
     return out
 
 
+def _bincount_hist_slotted(bins: np.ndarray, vals: np.ndarray,
+                           slot: np.ndarray, slots: int) -> np.ndarray:
+    """float64 reference of the slotted contraction: hist[f, b, c*slots + s]
+    sums vals[n, c] over rows with bins[n, f] == b and slot[n] == s; a row
+    whose slot is negative adds nothing."""
+    live = slot >= 0
+    bins, vals, slot = bins[live].astype(np.int64), vals[live], slot[live]
+    out = np.zeros((bins.shape[1], MAX_BIN, vals.shape[1], slots),
+                   np.float64)
+    for f in range(bins.shape[1]):
+        cell = bins[:, f] * slots + slot
+        for c in range(vals.shape[1]):
+            out[f, :, c, :] = np.bincount(
+                cell, weights=vals[:, c].astype(np.float64),
+                minlength=MAX_BIN * slots).reshape(MAX_BIN, slots)
+    return out.reshape(bins.shape[1], MAX_BIN, vals.shape[1] * slots)
+
+
 def stage_numerics():
     import jax.numpy as jnp
     from lightgbm_tpu import sparse_data
@@ -393,6 +415,44 @@ def stage_numerics():
     info["random_max_err_over_mass"] = float((err / mass).max())
     info["random_max_err_over_bound"] = float((err / bound).max())
 
+    # (c) the width of the benchmark's cell: 2,000 features, 16 slots (the
+    # batched grower's contraction), rows without a slot among them.  On
+    # the chip this is the kernel that builds the one-hot in VMEM
+    # (ops/hist_kernel.py) at the tiles it takes there: 16 feature tiles,
+    # the last one ragged.  First (a)'s probe: at ~30 rows a (bin, slot)
+    # every partial sum of 1 + 2^-12 is exact in f32 in any order and in
+    # any split of the accumuland, so the sums are exact, and a split that
+    # drops its low pieces returns the row count.  Then seeded accumulands
+    # under (b)'s bound, with two more roundings for the adds that join
+    # the three pieces.
+    n_w, f_w, slots = N_WIDE, F_WIDE, 16
+    rng = np.random.RandomState(13)
+    bins_w = rng.randint(0, MAX_BIN, size=(n_w, f_w)).astype(np.uint8)
+    slot_w = rng.randint(-1, slots, size=n_w).astype(np.int32)
+    bins_w_dev, slot_w_dev = jnp.asarray(bins_w), jnp.asarray(slot_w)
+    vals_w = np.stack([rng.randn(n_w), rng.rand(n_w) * 0.25,
+                       np.ones(n_w)], axis=1).astype(np.float32)
+
+    def wide(vals):
+        got = np.asarray(compute_histogram(
+            bins_w_dev, jnp.asarray(vals), num_bins=MAX_BIN,
+            slot=slot_w_dev, num_slots=slots))
+        ref = _bincount_hist_slotted(bins_w, vals, slot_w, slots)
+        assert got.dtype == np.float32 and got.shape == ref.shape
+        return got.astype(np.float64), ref
+
+    got, ref = wide(vals_a[:n_w])
+    assert np.array_equal(got, ref), \
+        ("wide f32 histogram lost operand bits", float(np.abs(got - ref).max()))
+    info["wide_probe_exact"] = True
+    got, ref = wide(vals_w)
+    mass = _bincount_hist_slotted(bins_w, np.abs(vals_w), slot_w, slots)
+    count = np.tile(ref[:, :, 2 * slots:], (1, 1, 3))
+    bound = np.maximum((count + 1.0) * 2.0 ** -24 * mass, 1e-300)
+    err = np.abs(got - ref)
+    assert (err <= bound).all(), float((err / bound).max())
+    info["wide_random_max_err_over_bound"] = float((err / bound).max())
+
     # int8: the shipped quantizer's output through the integer
     # contraction equals an int64 bincount of the same int8 values
     spec = QuantSpec(bits=8)
@@ -405,6 +465,11 @@ def stage_numerics():
                           _bincount_hist(bins_b, q_host).astype(np.int64)), \
         "int8 histogram is not exact"
     info["int8_exact"] = True
+    # which contraction each of the passes above traced: on the chip the
+    # f32 ones the kernel, the int8 one the scan
+    from lightgbm_tpu.obs.flops import traced_impls
+    info["contraction_traces"] = {impl: n for (_, impl), n
+                                  in traced_impls().items()}
     return bins_dev, vals_dev, info
 
 

@@ -38,8 +38,9 @@ run, not because the constants are exact):
 
 HBM-byte convention: bytes that MUST cross HBM for the op — operand
 reads + result writes, assuming perfect fusion of generated
-intermediates (the XLA behavior ``ops/histogram.py`` measured: the
-one-hot never materializes).
+intermediates.  For the histogram that holds of the TPU's kernel
+(``ops/hist_kernel.py``: the one-hot lives in VMEM), not of the scan,
+whose one-hot XLA writes to HBM and reads back (``ops/histogram.py``).
 """
 
 from __future__ import annotations
@@ -87,7 +88,8 @@ def hist_flops_bytes(n_rows: int, n_cols: int, num_bins: int,
     multi-slot expansion is active (``slotted``: num_slots > 1, the
     kernel passes it explicitly; defaults to ``channels > 3``) +
     histogram write (f32 and int32 are both 4-byte lanes); the one-hot
-    is generated in-registers (measured fused, ops/histogram.py).
+    is not counted (the kernel builds it in VMEM; the scan's, which does
+    cross HBM, is the formulation's overhead, not the algorithm's bytes).
 
     Accounting convention for the strict hist_overlap path: its 1-slot
     mask is the in-graph ENCODING of the masked pass it is
@@ -287,6 +289,10 @@ def train_hist_flops_per_iter(n_rows: int, n_feat: int, num_bins: int,
 
 _TRACED_LOCK = threading.Lock()
 _TRACED: Dict[str, FlopSite] = {}
+# traces by (site, implementation), and the ``jax.monitoring`` event that
+# carries each to the running session's registry
+_IMPLS: Dict[Tuple[str, str], int] = {}
+IMPL_EVENT_PREFIX = "/lgbtpu/impl/"
 
 # ambient member-axis multiplier (fleet/trainer.py): while a fleet
 # program traces, every site note fires ONCE (vmap traces the body once)
@@ -315,26 +321,44 @@ def member_axis(n: int):
 
 
 def note_traced(site: str, flops: int, hbm_bytes: int,
-                phase: str = "", cadence: str = "step") -> None:
+                phase: str = "", cadence: str = "step",
+                impl: str = "") -> None:
     """Record a site's static accounting from TRACED shapes.  Called
     inside jitted function bodies, so it fires once per fresh trace and
     overwrites idempotently on retrace — the latest traced shapes win
     (the process-wide view; per-model attribution goes through the
     driver's FlopLedger, which never depends on jit-cache state).
     Under :func:`member_axis` the note is scaled by the fleet's member
-    count — vmap traces the body once but runs it N-wide."""
+    count — vmap traces the body once but runs it N-wide.
+
+    ``impl`` names the implementation traced at a site that has more than
+    one (the histogram contraction: ``vmem`` | ``scan``).  Each trace is
+    counted, process-wide (:func:`traced_impls`) and, through
+    ``jax.monitoring``, as ``<site>.contraction_traces{impl=}`` in the
+    registry of the session that runs (``compile_cache.watch_compiles``)."""
     scale = _member_scale()
     with _TRACED_LOCK:
         _TRACED[site] = FlopSite(site=site, phase=phase,
                                  flops=int(flops) * scale,
                                  hbm_bytes=int(hbm_bytes) * scale,
                                  cadence=cadence)
+        if impl:
+            _IMPLS[(site, impl)] = _IMPLS.get((site, impl), 0) + 1
+    if impl:
+        from jax import monitoring
+        monitoring.record_event(f"{IMPL_EVENT_PREFIX}{site}/{impl}")
 
 
 def traced_sites() -> Dict[str, FlopSite]:
     """Process-wide snapshot of the trace-time site notes."""
     with _TRACED_LOCK:
         return dict(_TRACED)
+
+
+def traced_impls() -> Dict[Tuple[str, str], int]:
+    """Process-wide count of traces by (site, implementation)."""
+    with _TRACED_LOCK:
+        return dict(_IMPLS)
 
 
 # ---------------------------------------------------------------------------

@@ -35,6 +35,13 @@ cross-platform model determinism for measured throughput.  The tuned
 block_rows only re-partitions the scan, but f32 accumulation order
 follows the partition, so it is applied the same way: only under
 ``hist_tune=on``, and recorded in bench extras for provenance.
+
+``block_rows`` is the SCAN's row block.  Since PR 27 a TPU contracts
+float32 accumulands in the kernel of ``ops/hist_kernel.py``, which takes
+its tiles from the shapes and ignores ``block_rows``: there the sweep's
+block_rows candidates time the same program, and only K is tuned.  The
+block still means what it says on the CPU and for the integer
+accumulands of ``quant_train``.
 """
 
 from __future__ import annotations

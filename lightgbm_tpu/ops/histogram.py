@@ -10,10 +10,36 @@ computed as a **one-hot contraction on the MXU**:
 
     hist[f*B + b, c] = sum_n (binned[n, f] == b) * vals[n, c]
 
-i.e. a single ``[F*B, n] @ [n, C]`` matmul per row-block, accumulated over
-blocks with ``lax.scan``.  The one-hot operand is generated on the fly
-(iota-compare) and fused by XLA into the matmul operand load, so HBM traffic
-stays at the binned-matrix + vals bytes.  Channels C = (grad, hess, count).
+Two implementations of that contraction, one a regime; the call's backend,
+dtypes and shapes choose (:func:`vmem_plan`), never a parameter:
+
+- ``vmem`` (``ops/hist_kernel.py``): on a TPU, for float32 accumulands.  One
+  Pallas kernel builds each 0/1 tile in VMEM and contracts it there, in
+  three bfloat16 pieces of the accumuland that sum to it exactly; the
+  one-hot never exists in HBM.
+- ``scan`` (below): everywhere else (the CPU, with its byte-identity pins on
+  block partitions; integer accumulands).  A ``[C, block] @ [block, F*B]``
+  matmul per row block, accumulated over blocks with ``lax.scan``; the
+  one-hot of a block is an array of its own.
+
+The records, since this docstring once said otherwise.  On the TPU XLA does
+**not** fuse the iota-compare into the matmul's operand load: it writes each
+block's one-hot to HBM and reads it back.  At 2,000 features x 64 bins the
+64 MB budget below clips the block to 128 rows, and a block cost 20.1 us
+to write (16.4 MB at the chip's 819 GB/s) and 38.3 us to contract: a pass
+over 320,000 rows x 16 slots 155.2 ms on the v5e (ledger, PR 26).  No XLA
+formulation tried keeps it out of HBM (a batched 3-D contraction, bfloat16
+pieces, rows on lanes: each compiles to a ``pred[block, F*Bp]`` fusion
+output; TPU compiler in the sandbox, PR 27).  An earlier Pallas kernel read
+8.2 against the scan's 4.7 ms a pass at 1M x 28 x 64 bins and was removed
+(before PR 22, in an earlier round; neither its source nor its layout is on
+record); the scan then ran at the backend's default precision, and PR 22,
+which stated ``HIGHEST``, read it at 5.38 ms there.  PR 27's kernel against
+the scan, one v5e, milliseconds a pass (builder's chip run, PR 27): 320,000
+x 2,000 at 16 slots 70.5 against 156.3, with no slot 40.7 against 144.3; 1M
+x 28 with no slot 2.74 against 6.05, at 16 slots 4.14 against 6.41.  The
+kernel is the faster at both shapes, so the rule has no condition on width.
+Channels C = (grad, hess, count).
 
 All features share a uniform padded bin axis ``B`` (= dataset max_bin) so
 shapes are static; per-feature valid-bin masking happens in the split scan.
@@ -43,8 +69,12 @@ HIST_ONEHOT_BUDGET = 64 * 1024 * 1024
 
 def hist_block_rows(num_features: int, padded_bins: int,
                     itemsize: int = 4, channels: int = 3) -> int:
-    """Row-block size bounded by the one-hot intermediate's byte
-    budget.  ``itemsize`` is the accumuland (vals) element width — the
+    """Row-block size of the SCAN, bounded by the one-hot intermediate's
+    byte budget.  The budget governs whoever takes the scan: the CPU, and
+    on a TPU the integer accumulands of ``quant_train`` (and a shape whose
+    accumulator the kernel cannot hold, ``hist_kernel.tile_plan``); the kernel's
+    one-hot tile lives in VMEM and has tiles of its own.
+    ``itemsize`` is the accumuland (vals) element width — the
     one-hot operand is generated at the SAME width so the dot's operand
     dtypes match, so int8-packed passes (quant_train, ops/quantize.py)
     get proportionally larger blocks than the f32 default.
@@ -110,23 +140,67 @@ def compute_histogram(binned: jax.Array, vals: jax.Array, *, num_bins: int,
     the multi-leaf batched grower never materializes the [N, C*K]
     operand in HBM (at 10M rows x K=8 that buffer alone would be ~1 GB).
 
-    Backend: the XLA one-hot-matmul scan below on every platform.  A
-    hand-written Pallas kernel was built and measured SLOWER on TPU v5e
-    (8.2 vs 4.7 ms/pass at 1M x 28 x 64 bins: XLA fuses the one-hot
-    generation into the dot's operand load better than the explicit
-    kernel, and the matmul already sits at the M-axis sublane
-    ceiling), so it was removed rather than shipped as dead
-    code; the batched multi-leaf contraction (grower.py split_batch) is
-    the path past that ceiling.
+    ``block_rows`` is the scan's row block; the kernel takes its tiles
+    from the shapes and ignores it.
     """
+    plan = vmem_plan(binned, vals, num_bins=num_bins,
+                     num_slots=num_slots if slot is not None else 1)
+    if plan is not None:
+        return _compute_histogram_vmem(binned, vals, num_bins=num_bins,
+                                       plan=plan, slot=slot,
+                                       num_slots=num_slots)
     return _compute_histogram_matmul(binned, vals, num_bins=num_bins,
                                      block_rows=block_rows, slot=slot,
                                      num_slots=num_slots)
 
 
+def vmem_plan(binned: jax.Array, vals: jax.Array, *, num_bins: int,
+              num_slots: int = 1):
+    """The kernel's tiles where the kernel runs, else None (the scan): a
+    TPU behind the call, float32 accumulands, integer bins, and an
+    accumulator that ``hist_kernel.tile_plan`` can hold in VMEM.  Sparse rows
+    never come here (``sparse_data.histogram``)."""
+    if jax.default_backend() != "tpu" or vals.dtype != jnp.float32 \
+            or not jnp.issubdtype(binned.dtype, jnp.integer):
+        return None
+    from .hist_kernel import tile_plan
+    return tile_plan(*binned.shape, num_bins, vals.shape[1] * num_slots)
+
+
+def _note_pass(binned, vals, num_bins: int, channels: int, slotted: bool,
+               impl: str) -> None:
+    """Static FLOP/byte accounting from the TRACED shapes (obs/flops.py; a
+    Python side effect, so it fires once per fresh trace and costs nothing
+    at runtime), and the count of contraction sites by implementation,
+    ``hist.contraction_traces{impl=}``.  The "hist" site carries the USEFUL
+    channels only."""
+    from ..obs.flops import hist_flops_bytes, note_traced
+    n, f = binned.shape
+    note_traced("hist", *hist_flops_bytes(
+        n, f, num_bins, channels=channels,
+        binned_itemsize=getattr(binned.dtype, "itemsize", 1),
+        vals_itemsize=getattr(vals.dtype, "itemsize", 4),
+        slotted=slotted), phase="grow", impl=impl)
+
+
+@functools.partial(jax.jit, static_argnames=("num_bins", "plan", "num_slots"))
+@jax.named_scope("lgbtpu.hist")
+def _compute_histogram_vmem(binned: jax.Array, vals: jax.Array, *,
+                            num_bins: int, plan,
+                            slot: Optional[jax.Array] = None,
+                            num_slots: int = 1) -> jax.Array:
+    from .hist_kernel import hist_vmem
+    k = num_slots if slot is not None else 1
+    _note_pass(binned, vals, num_bins, vals.shape[1] * k,
+               slot is not None and num_slots > 1, impl="vmem")
+    return hist_vmem(binned, vals, num_bins=num_bins, plan=plan, slot=slot,
+                     num_slots=num_slots)
+
+
 # device-phase names (metadata only): the pass is ``lgbtpu.hist``, and in
-# it the making of the one-hot operand and the contraction, which are
-# separate operations on the chip, carry a scope each
+# it the making of the one-hot operand and the contraction, which in the
+# scan are separate operations on the chip, carry a scope each (the kernel
+# is one operation, ``lgbtpu.hist.contract``)
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block_rows", "num_slots"))
 @jax.named_scope("lgbtpu.hist")
@@ -150,20 +224,11 @@ def _compute_histogram_matmul(binned: jax.Array, vals: jax.Array, *,
     op_dt = vals.dtype if integer else jnp.float32
     acc_dt = jnp.int32 if integer else jnp.float32
 
-    # static FLOP/byte accounting from the TRACED shapes (obs/flops.py;
-    # a Python side effect, so it fires once per fresh trace and costs
-    # nothing at runtime — the comm.py trick applied to compute).  The
-    # "hist" site carries the USEFUL channels only; the lane-pad MACs
-    # go to the MFU-excluded "hist_pad" site (phase="pad")
-    from ..obs.flops import (hist_flops_bytes, hist_pad_flops_bytes,
-                             note_traced)
-    note_traced("hist", *hist_flops_bytes(
-        n, f, num_bins, channels=c,
-        binned_itemsize=getattr(binned.dtype, "itemsize", 1),
-        vals_itemsize=getattr(vals.dtype, "itemsize", 4),
-        slotted=slot is not None and num_slots > 1),
-        phase="grow")
+    # the lane-pad MACs go to the MFU-excluded "hist_pad" site (phase="pad")
+    _note_pass(binned, vals, num_bins, c, slot is not None and num_slots > 1,
+               impl="scan")
     if c_pad > c:
+        from ..obs.flops import hist_pad_flops_bytes, note_traced
         note_traced("hist_pad", *hist_pad_flops_bytes(n, f, num_bins,
                                                       channels=c),
                     phase="pad")

@@ -220,8 +220,10 @@ def watch_compiles(metrics, tracer=None) -> bool:
     """Feed XLA compile / compilation-cache events into an obs
     MetricsRegistry (+ optional Tracer instants): compile durations as
     a ``jax.compile_seconds`` histogram, cache hits/misses and other
-    compile-adjacent counters as ``jax.events{event=...}``, and the
-    library's own trace events as ``jax.traces{name=...}``.
+    compile-adjacent counters as ``jax.events{event=...}``, the
+    library's own trace events as ``jax.traces{name=...}``, and the traces
+    of a site by implementation (``obs.flops.note_traced(impl=)``) as
+    ``hist.contraction_traces{impl=...}``.
 
     Uses ``jax.monitoring``'s public listener hooks; listeners are
     process-global and cannot be unregistered, so the registered
@@ -232,6 +234,7 @@ def watch_compiles(metrics, tracer=None) -> bool:
         from jax import monitoring
     except Exception:
         return False
+    from ..obs.flops import IMPL_EVENT_PREFIX
 
     def _on_duration(event: str, duration: float, **kw) -> None:
         if "compil" not in event:
@@ -245,6 +248,10 @@ def watch_compiles(metrics, tracer=None) -> bool:
         if event.startswith(_TRACE_PREFIX):
             metrics.counter("jax.traces",
                             name=event[len(_TRACE_PREFIX):]).inc()
+            return
+        if event.startswith(IMPL_EVENT_PREFIX):
+            site, impl = event[len(IMPL_EVENT_PREFIX):].split("/")
+            metrics.counter(f"{site}.contraction_traces", impl=impl).inc()
             return
         if "compil" not in event and "cache" not in event:
             return
