@@ -187,7 +187,7 @@ _PARAMS: Dict[str, tuple] = {
     "mesh_shape": (list, None, []),          # one axis, e.g. [8]
     "mesh_axis_names": (list, None, []),     # one axis, e.g. ["data"]
     # tree_learner=data histogram reduction: true = reduce-scatter the
-    # feature-chunked histograms so each shard carries only [L, F/n, B, 3]
+    # feature-chunked histograms so each shard carries only [L, 3, F/n, B]
     # of GLOBAL histograms (the reference's ReduceScatter owner shape,
     # data_parallel_tree_learner.cpp:174-186); false = legacy full psum
     # (every shard holds all global histograms) — A/B escape hatch

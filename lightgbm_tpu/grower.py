@@ -76,6 +76,53 @@ def grower_memo_counts() -> dict:
         return dict(_MEMO_COUNTS)
 
 
+# XLA's memory analysis of the executable a jitted grower runs, by grower
+# and argument shapes; the grower is held with its answer, so that its id
+# stays its own.  Bounded like the memo of growers.
+_GROWER_MEMORY: "OrderedDict[tuple, tuple]" = OrderedDict()
+
+
+def compiled_grower_temp_bytes(grow, args, kwargs) -> Optional[int]:
+    """Bytes of temporaries in the executable that the jitted grower ``grow``
+    runs on these arguments, from XLA's own memory analysis: what the
+    compiler laid out, padding included, which no count of logical shapes
+    gives (at 2,000 features x 255 bins one padded copy was 15.6 GB of a
+    state of 1.7).  None where ``grow`` is no jitted function or the backend
+    has no analysis.
+
+    Lowering reuses the trace of the call that ran; compiling reads the
+    persistent cache where that call wrote there, and compiles a second
+    time where it did not.  So the answer is remembered process-wide, a
+    booster asks once, and only telemetry asks at all (``grower.temp_bytes``,
+    docs/Observability.md)."""
+    if not hasattr(grow, "lower"):
+        return None
+    leaves = jax.tree_util.tree_leaves((args, kwargs))
+    key = (id(grow),) + tuple((tuple(a.shape), str(a.dtype))
+                              for a in leaves if hasattr(a, "shape"))
+    with _SHARED_GROWERS_LOCK:
+        if key in _GROWER_MEMORY:
+            return _GROWER_MEMORY[key][1]
+    try:
+        out = int(grow.lower(*args, **kwargs).compile()
+                  .memory_analysis().temp_size_in_bytes)
+    except Exception:           # no analysis on this backend: not a fault
+        out = None
+    with _SHARED_GROWERS_LOCK:
+        _GROWER_MEMORY[key] = (grow, out)
+        while len(_GROWER_MEMORY) > _SHARED_GROWERS_MAX:
+            _GROWER_MEMORY.popitem(last=False)
+    return out
+
+
+def slot_histograms(h: jax.Array, nslots: int) -> jax.Array:
+    """A step's contraction ``[3·nslots, F, B]`` (channel ``c·nslots +
+    slot``, ops/histogram.py) as one histogram a slot, ``[nslots, 3, F,
+    B]``: what the state holds a leaf (``_GrowState.hist``) and the split
+    scan takes.  Only major axes move."""
+    return h.reshape((3, nslots) + h.shape[1:]).swapaxes(0, 1)
+
+
 class _Unkeyable(Exception):
     pass
 
@@ -135,7 +182,7 @@ class TreeArrays(NamedTuple):
 
 class _GrowState(NamedTuple):
     leaf_of_row: jax.Array
-    hist: jax.Array              # [L, F, B, 3]
+    hist: jax.Array              # [L, 3, F, B], channel-major
     # per-leaf allowed output range (monotone 'basic' method; ±inf w/o)
     olo: jax.Array               # [L] f32
     ohi: jax.Array               # [L] f32
@@ -216,7 +263,9 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
 
     vals: [N, 3] f32 = (grad, hess, in-bag weight); out-of-bag rows zeroed.
 
-    Parallelism hooks (SURVEY.md §2.6 strategies map onto one program):
+    Parallelism hooks (SURVEY.md §2.6 strategies map onto one program);
+    every histogram a hook takes or returns is channel-major,
+    ``[C, F|G, B|Bg]`` (``_hist`` below):
     - hist_reduce: reduce local histograms across the mesh row axis
       (data-parallel psum; identity for serial).  The hook may SHRINK the
       feature axis: the owner-shard data-parallel learner reduce-scatters
@@ -304,10 +353,12 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
       one.  Each step picks the top-K leaves by cached best gain, applies
       all K splits in one row-partition pass, and builds all K smaller
       children's histograms in ONE one-hot contraction with C=3K channels.
-      The histogram matmul is sublane-bound at M=3 (3 of
-      8 sublanes, ~4.6 TFLOP/s ceiling), so batching K leaves raises the
-      ceiling ~K× while amortizing the one-hot generation — per-split cost
-      drops toward 1/K.  Trees differ slightly from strict leaf-wise
+      A pass costs what its one-hot costs whatever the channels carry:
+      the TPU's kernel streams 3·16·⌈C/16⌉ accumuland rows through every
+      weight tile of the one-hot (ops/hist_kernel.py; its MXU floor,
+      PERF.md §6), so one pass for K leaves costs little more than one
+      pass for one and the per-split cost drops toward 1/K.  Trees differ
+      slightly from strict leaf-wise
       growth (between LightGBM's leaf-wise and XGBoost's depth-wise);
       K=1 keeps exact reference semantics and is the default.  Widths
       are snapped into ``utils/shapes.SPLIT_BATCH_SET`` (and fitted
@@ -354,14 +405,23 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         from .efb import expand_group_hist
 
         def _expand(gh, total):
-            return expand_group_hist(gh, total, efb.group_of_feat,
-                                     efb.col_idx, efb.fix0)
+            # the EFB expansion keeps the [G, Bg, C] layout it shares with
+            # the partitioned learner; it sees a turned view
+            return jnp.moveaxis(expand_group_hist(
+                jnp.moveaxis(gh, 0, -1), total, efb.group_of_feat,
+                efb.col_idx, efb.fix0), -1, 0)
     else:
         def _expand(gh, total):
             return gh
 
     def _hist(binned_view, vals, slot=None, nslots=1, scales=None):
-        """Reduced histogram; with ``slot`` a per-slot multi-histogram
+        """Reduced histogram, channel-major ``[C, F|G, B|Bg]`` (C = 3, or
+        3·nslots with channel ``c * nslots + slot``): the layout of the
+        grower's state, of every hook and of the split scan.  The 3
+        channels are never an array's minor axis here: the TPU's compiler
+        pads such an axis to 128 lanes where it tiles it, and at 2,000
+        features x 255 bins one padded copy was 15.6 GB (PERF.md §6, PR
+        30).  With ``slot`` a per-slot multi-histogram
         (split_batch) whose vals ⊗ onehot(slot) expansion happens inside
         the scan (ops/histogram.py), never as an [N, 3*K] HBM buffer.
         Sparse-binned data takes the O(nnz) segment-sum formulation
@@ -370,12 +430,13 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         a second argument (voting's gain-statistic vote needs real
         values; the reduce itself stays int32)."""
         if isinstance(binned_view, _spd.SparseBinned):
-            h = _spd.histogram(binned_view, vals, num_bins=Bh, slot=slot,
-                               num_slots=nslots)
+            h = jnp.moveaxis(_spd.histogram(
+                binned_view, vals, num_bins=Bh, slot=slot,
+                num_slots=nslots), -1, 0)
         else:
             h = compute_histogram(binned_view, vals, num_bins=Bh,
                                   block_rows=block_rows, slot=slot,
-                                  num_slots=nslots)
+                                  num_slots=nslots, channel_major=True)
         return reduce_fn(h, scales) if use_quant else reduce_fn(h)
 
     def _quant_prepare(n, vals, feature_mask, rng_iter, n_leaves,
@@ -405,7 +466,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                               seed=quant_seed)
 
         def scan_expand(h, t):
-            return _expand(dequantize_hist(h, scales), t)
+            return _expand(dequantize_hist(h, scales, axis=0), t)
         return vals, scales, scan_expand
 
     def _make_child_hist(n: int, scales=None):
@@ -597,8 +658,8 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         before the scan-space view, and the root aggregates come from
         exact int32 sums dequantized by the shared scales."""
         expand = _expand if expand is None else expand
-        hist0 = _hist(binned_view, vals, scales=scales)  # [F|G, B|Bg, 3]
-        # root aggregates from vals directly, NOT from hist0[0]: a filtering
+        hist0 = _hist(binned_view, vals, scales=scales)  # [3, F|G, B|Bg]
+        # root aggregates from vals directly, NOT from feature 0's: a filtering
         # hist_reduce (voting's top-k zeroing) may have dropped feature 0's
         # histogram, and this is also one less reduction of a big tensor
         if scales is not None:
@@ -608,7 +669,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             if sum_reduce is not None:
                 ti = sum_reduce(vals.astype(jnp.int32).sum(axis=0))
             elif hist_reduce is not None:
-                ti = hist0[0].sum(axis=0)
+                ti = hist0[:, 0].sum(axis=1)
             else:
                 ti = vals.astype(jnp.int32).sum(axis=0)
             total0 = dequantize_hist(ti, scales)
@@ -618,7 +679,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             # caller-supplied reduce hook without a sum_reduce: derive the
             # totals from the reduced histogram so cross-shard hooks keep
             # seeing globally-reduced root aggregates
-            total0 = hist0[0].sum(axis=0)
+            total0 = hist0[:, 0].sum(axis=1)
         else:
             total0 = vals.sum(axis=0)
         root_out = leaf_output(total0[0], total0[1], params)
@@ -661,7 +722,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                                          params, root_out, is_cat, **kw))
         return hist0, total0, root_out, res0, et_key, bn_key
 
-    def _init_state(n, nleaf, nnode, fv, nf, hist0, total0, root_out,
+    def _init_state(n, nleaf, nnode, nf, hist0, total0, root_out,
                     res0, cuse0=None) -> _GrowState:
         """Fresh grow state with ``nleaf`` leaf slots / ``nnode`` node
         slots (== L/L-1 strict; +K scratch slots batched)."""
@@ -670,8 +731,12 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             leaf_of_row=jnp.zeros(n, jnp.int32),
             # quantized training carries the histogram state as exact
             # int32 (dtype follows the root pass); subtraction and the
-            # reduce collectives stay integer, dequantized only at scan
-            hist=jnp.zeros((nleaf, fv, Bh, 3),
+            # reduce collectives stay integer, dequantized only at scan.
+            # The carry follows the REDUCED histogram's feature axis, not
+            # the binned view's: an owner-shard hist_reduce leaves each
+            # shard with only its chunk of the global histograms
+            # ([L, 3, F/n, B])
+            hist=jnp.zeros((nleaf,) + hist0.shape,
                            hist0.dtype).at[0].set(hist0),
             olo=jnp.full(nleaf, neg_inf),
             ohi=jnp.full(nleaf, jnp.inf),
@@ -748,11 +813,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         hist0, total0, root_out, res0, et_key, bn_key = _root_eval(
             binned_view, vals, feature_mask, num_bin, na_bin, is_cat,
             rng_iter, cuse0, expand=scan_expand, scales=scales)
-        # the carry follows the REDUCED histogram's feature axis, not the
-        # binned view's: an owner-shard hist_reduce leaves each shard with
-        # only its chunk of the global histograms ([L, F/n, B, 3])
-        st = _init_state(n, L, L - 1, hist0.shape[0],
-                         feature_mask.shape[0], hist0, total0,
+        st = _init_state(n, L, L - 1, feature_mask.shape[0], hist0, total0,
                          root_out, res0, cuse0)
 
         def split_step(st: _GrowState) -> _GrowState:
@@ -1006,10 +1067,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         hist0, total0, root_out, res0, et_key, bn_key = _root_eval(
             binned_view, vals, feature_mask, num_bin, na_bin, is_cat,
             rng_iter, cuse0, expand=scan_expand, scales=scales)
-        # carry feature axis = the REDUCED histogram's (owner-shard chunk
-        # under the scatter-reducing dp learner; the view width otherwise)
-        fh = hist0.shape[0]
-        st = _init_state(n, LP, NP, fh, feature_mask.shape[0], hist0,
+        st = _init_state(n, LP, NP, feature_mask.shape[0], hist0,
                          total0, root_out, res0, cuse0)
 
         neg_inf = jnp.float32(-jnp.inf)
@@ -1092,10 +1150,9 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                         .at[targets].set(jnp.arange(nC, dtype=jnp.int32))
                     tslot = tslot_of_leaf[leaf_of_row]       # [N]
                 hist_c = _hist(binned_view, vals, tslot, nC,
-                               scales=scales)                # [Fh, Bh, 3nC]
+                               scales=scales)                # [3nC, Fh, Bh]
                 with jax.named_scope("lgbtpu.hist.state"):
-                    hist_c = hist_c.reshape(fh, Bh, 3, nC) \
-                        .transpose(3, 0, 1, 2)               # [nC, Fh, Bh, 3]
+                    hist_c = slot_histograms(hist_c, nC)     # [nC, 3, Fh, Bh]
                     if use_subtraction:
                         hist_small = hist_c
                         hist_large = st.hist[leaf_sel] - hist_small

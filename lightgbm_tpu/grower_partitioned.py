@@ -233,7 +233,11 @@ class PartitionedGrower:
         if quant is not None:
             self._quantize = jax.jit(functools.partial(
                 _quantize_vals, spec=quant))
-        self._find = jax.jit(functools.partial(find_best_split, params=params))
+        # this learner keeps its histograms [F, B, 3], as compute_histogram
+        # hands them over; the scan takes them channel-major
+        self._find = jax.jit(
+            lambda hist, *args, **kw: find_best_split(
+                jnp.moveaxis(hist, -1, 0), *args, params=params, **kw))
         # HistogramPool analog (feature_histogram.hpp:1095,
         # histogram_pool_size): cap the number of device-resident per-leaf
         # histograms; evicted leaves are reconstructed on demand (the

@@ -580,9 +580,10 @@ class GBDTModel:
         # remainder closed) the voting/feature growers too; only the
         # host-orchestrated partitioned learner keeps exact shapes
         self._leaf_pad = None
+        self._grower_memory_noted = False
         if self._trace_buckets and learner == "masked":
             lp = bucket_leaves(config.num_leaves)
-            # inflation cap: the grower carries a [L, F, B, 3] histogram
+            # inflation cap: the grower carries a [L, 3, F, B] histogram
             # per leaf slot, so padding a tiny budget to the 64 floor
             # (e.g. num_leaves=4 -> 16x) could blow HBM on wide data;
             # past 4x the trace consolidation isn't worth the state.
@@ -1168,6 +1169,31 @@ class GBDTModel:
             from ..dataset import fingerprint_arrays
             self._global_fp = fingerprint_arrays(glab, gw)
         return gscore, self._global_fp
+
+    def _note_grower_memory(self, obs, args, kwargs) -> None:
+        """Once a booster, where one jitted masked grower on one device
+        grows its trees: the bytes of temporaries XLA laid out for the
+        executable the iteration just ran (``grower.temp_bytes``) and the
+        logical bytes of its per-leaf histogram state
+        (``grower.hist_state_bytes``: leaf slots x 3 x columns x bins),
+        one observation each, so a reader of several boosters takes
+        ``sum / count``."""
+        self._grower_memory_noted = True
+        if self._dist is not None:
+            return
+        from ..grower import compiled_grower_temp_bytes
+        temp = compiled_grower_temp_bytes(self.grower, args, kwargs)
+        if temp is None:
+            return
+        obs.metrics.histogram("grower.temp_bytes").observe(temp)
+        # the batched grower keeps K scratch slots past the leaf budget
+        k = min(self._split_batch, self.config.num_leaves - 1)
+        slots = (self._leaf_pad or self.config.num_leaves) + (k if k > 1 else 0)
+        cols = self.binned_dev.num_features if self._sparse \
+            else self.binned_dev.shape[1]
+        bins = int(self.efb_dev.group_bins) if self._use_efb else self.max_bin
+        obs.metrics.histogram("grower.hist_state_bytes").observe(
+            slots * 3 * int(cols) * bins * 4)
 
     def _prep_vals(self, vals: jax.Array) -> jax.Array:
         """Pad + row-shard the per-row (grad, hess, weight) stack for the
@@ -2775,6 +2801,10 @@ class GBDTModel:
             arrays = _grow()
             if obs is not None:
                 obs.end_phase(_sp, arrays.num_leaves)
+                if not self._grower_memory_noted:
+                    self._note_grower_memory(
+                        obs, (self.binned_dev, vals_g, fmask_g,
+                              self._nb_grow, self._na_grow), gkw)
                 _sp = obs.phase("fetch", self.iter_)
             # ONE batched host transfer of the tree-sized fields; the [N]
             # leaf_of_row stays on device (only pulled when renew/linear

@@ -293,6 +293,7 @@ _TRACED: Dict[str, FlopSite] = {}
 # carries each to the running session's registry
 _IMPLS: Dict[Tuple[str, str], int] = {}
 IMPL_EVENT_PREFIX = "/lgbtpu/impl/"
+PLAN_EVENT_PREFIX = "/lgbtpu/plan/"
 
 # ambient member-axis multiplier (fleet/trainer.py): while a fleet
 # program traces, every site note fires ONCE (vmap traces the body once)
@@ -347,6 +348,17 @@ def note_traced(site: str, flops: int, hbm_bytes: int,
     if impl:
         from jax import monitoring
         monitoring.record_event(f"{IMPL_EVENT_PREFIX}{site}/{impl}")
+
+
+def note_kernel_plan(site: str, **tiles: int) -> None:
+    """Count a trace of ``site``'s kernel by the tiles its plan chose, as
+    ``<site>.kernel_plans{<tile>=...}`` in the registry of the session that
+    runs (through ``jax.monitoring``, like the count by implementation):
+    ``hist.kernel_plans{fpart=32,parts=4}`` says the contraction's
+    accumulator was held in four parts of 32 features."""
+    from jax import monitoring
+    monitoring.record_event(PLAN_EVENT_PREFIX + site + "/" + ",".join(
+        f"{k}={int(v)}" for k, v in sorted(tiles.items())))
 
 
 def traced_sites() -> Dict[str, FlopSite]:

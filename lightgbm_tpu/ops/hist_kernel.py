@@ -72,10 +72,12 @@ def tile_plan(n: int, f: int, num_bins: int, channels: int,
     cp = -(-int(channels) // 16) * 16
     ft = min(f, FEATURE_TILE)
     per_feature = 3 * cp * bp * 4
-    fpart = ft
-    if ft * per_feature > ACC_BYTES:
-        fpart = min(ft, max(8, ACC_BYTES // per_feature // 8 * 8))
-    parts = -(-ft // fpart)
+    # a block whose accumulator does not fit is contracted in parts, as few
+    # as fit and all alike: at 256 bins x 16 slots 40 features fit, and four
+    # parts of 32 contract the block's 128 where four of 40 contract 160
+    most = max(8, ACC_BYTES // per_feature // 8 * 8)
+    parts = -(-ft // most)
+    fpart = ft if parts == 1 else -(-ft // (8 * parts)) * 8
     fsub = max(1, min(fpart, ONEHOT_ROWS // bp))
     # a narrow block takes more rows, so that a grid step's fixed cost is
     # spread over as much one-hot (PERF.md §6, PR 27: 1M x 28, 3.1 → 2.7 ms)
@@ -145,9 +147,11 @@ def _kernel(bins_ref, vals_ref, slot_ref, out_ref, acc_ref, bt_ref, *,
 
 def hist_vmem(binned: jax.Array, vals: jax.Array, *, num_bins: int,
               plan: Plan, slot: Optional[jax.Array] = None,
-              num_slots: int = 1, interpret: bool = False) -> jax.Array:
-    """``compute_histogram``'s result, ``[F, num_bins, C]`` float32, for
-    dense integer ``binned [N, F]`` and float32 ``vals [N, cv]``, by the
+              num_slots: int = 1, interpret: bool = False,
+              channel_major: bool = False) -> jax.Array:
+    """``compute_histogram``'s result, ``[F, num_bins, C]`` float32
+    (``[C, F, num_bins]``, as the kernel writes it, with ``channel_major``),
+    for dense integer ``binned [N, F]`` and float32 ``vals [N, cv]``, by the
     tiles of :func:`tile_plan`.  ``interpret`` runs the kernel in Pallas's
     interpreter (the CPU's tests)."""
     p = plan
@@ -190,4 +194,4 @@ def hist_vmem(binned: jax.Array, vals: jax.Array, *, num_bins: int,
     # past the block's own ft (parts*fpart > ft) and past f are dropped
     out = out.reshape(p.cp, nf, p.parts * p.fpart, p.bp)[:c, :, :p.ft]
     out = out.reshape(c, nf * p.ft, p.bp)[:, :f, :num_bins]
-    return out.transpose(1, 2, 0)
+    return out if channel_major else out.transpose(1, 2, 0)

@@ -107,20 +107,21 @@ def hist_block_rows(num_features: int, padded_bins: int,
 
 
 def pad_feature_axis(h: jax.Array, total: int) -> jax.Array:
-    """Zero-pad the leading (feature/group) axis of a histogram to
-    ``total`` rows.  The owner-shard reduce-scatter
+    """Zero-pad the feature/group axis of a channel-major histogram
+    ``[C, F, B]`` to ``total`` rows.  The owner-shard reduce-scatter
     (parallel/data_parallel.py) needs the histogram's chunk axis to
     divide evenly over the mesh; zero rows reduce to zero and are never
     scanned (their scan slots carry a False feature mask)."""
-    pad = total - h.shape[0]
+    pad = total - h.shape[1]
     if pad <= 0:
         return h
-    return jnp.pad(h, ((0, pad),) + ((0, 0),) * (h.ndim - 1))
+    return jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
 
 
 def compute_histogram(binned: jax.Array, vals: jax.Array, *, num_bins: int,
                       block_rows: int = 0, slot: Optional[jax.Array] = None,
-                      num_slots: int = 1) -> jax.Array:
+                      num_slots: int = 1,
+                      channel_major: bool = False) -> jax.Array:
     """hist[f, b, c] = sum over rows n of (binned[n,f]==b) * vals[n,c].
 
     binned: [N, F] integer bins (uint8/uint16/int32)
@@ -132,7 +133,13 @@ def compute_histogram(binned: jax.Array, vals: jax.Array, *, num_bins: int,
             the returned histogram is int32 and cross-shard reductions
             of it are bitwise order-independent.
     returns [F, num_bins, C] float32 (int32 for integer vals) — with
-    ``slot`` set, C becomes ``C * num_slots``.
+    ``slot`` set, C becomes ``C * num_slots`` (channel ``c * num_slots +
+    slot``).  ``channel_major`` returns ``[C, F, num_bins]`` instead, as
+    both implementations accumulate it: the masked grower keeps its
+    histograms so (grower.py), because an array whose minor axis is the
+    3 channels is padded to 128 lanes wherever the TPU's compiler tiles
+    it (42.8x; at 2,000 features x 255 bins one such copy was 15.6 GB,
+    PERF.md §6, PR 30).
 
     slot/num_slots: per-row slot id in [0, num_slots) or negative for
     "no slot" (row contributes nothing).  The per-slot one-hot expansion
@@ -148,10 +155,12 @@ def compute_histogram(binned: jax.Array, vals: jax.Array, *, num_bins: int,
     if plan is not None:
         return _compute_histogram_vmem(binned, vals, num_bins=num_bins,
                                        plan=plan, slot=slot,
-                                       num_slots=num_slots)
+                                       num_slots=num_slots,
+                                       channel_major=channel_major)
     return _compute_histogram_matmul(binned, vals, num_bins=num_bins,
                                      block_rows=block_rows, slot=slot,
-                                     num_slots=num_slots)
+                                     num_slots=num_slots,
+                                     channel_major=channel_major)
 
 
 def vmem_plan(binned: jax.Array, vals: jax.Array, *, num_bins: int,
@@ -183,18 +192,22 @@ def _note_pass(binned, vals, num_bins: int, channels: int, slotted: bool,
         slotted=slotted), phase="grow", impl=impl)
 
 
-@functools.partial(jax.jit, static_argnames=("num_bins", "plan", "num_slots"))
+@functools.partial(jax.jit, static_argnames=("num_bins", "plan", "num_slots",
+                                             "channel_major"))
 @jax.named_scope("lgbtpu.hist")
 def _compute_histogram_vmem(binned: jax.Array, vals: jax.Array, *,
                             num_bins: int, plan,
                             slot: Optional[jax.Array] = None,
-                            num_slots: int = 1) -> jax.Array:
+                            num_slots: int = 1,
+                            channel_major: bool = False) -> jax.Array:
     from .hist_kernel import hist_vmem
     k = num_slots if slot is not None else 1
     _note_pass(binned, vals, num_bins, vals.shape[1] * k,
                slot is not None and num_slots > 1, impl="vmem")
+    from ..obs.flops import note_kernel_plan
+    note_kernel_plan("hist", parts=plan.parts, fpart=plan.fpart)
     return hist_vmem(binned, vals, num_bins=num_bins, plan=plan, slot=slot,
-                     num_slots=num_slots)
+                     num_slots=num_slots, channel_major=channel_major)
 
 
 # device-phase names (metadata only): the pass is ``lgbtpu.hist``, and in
@@ -202,12 +215,14 @@ def _compute_histogram_vmem(binned: jax.Array, vals: jax.Array, *,
 # scan are separate operations on the chip, carry a scope each (the kernel
 # is one operation, ``lgbtpu.hist.contract``)
 @functools.partial(jax.jit,
-                   static_argnames=("num_bins", "block_rows", "num_slots"))
+                   static_argnames=("num_bins", "block_rows", "num_slots",
+                                    "channel_major"))
 @jax.named_scope("lgbtpu.hist")
 def _compute_histogram_matmul(binned: jax.Array, vals: jax.Array, *,
                               num_bins: int, block_rows: int = 0,
                               slot: Optional[jax.Array] = None,
-                              num_slots: int = 1) -> jax.Array:
+                              num_slots: int = 1,
+                              channel_major: bool = False) -> jax.Array:
     n, f = binned.shape
     c = vals.shape[1] * (num_slots if slot is not None else 1)
     # wide multi-leaf contractions (split_batch K ∈ {32, 64} → C = 3K
@@ -301,7 +316,8 @@ def _compute_histogram_matmul(binned: jax.Array, vals: jax.Array, *,
 
     acc0 = jnp.zeros((c_pad, f * bp), dtype=acc_dt)
     acc, _ = lax.scan(body, acc0, xs)
-    return acc[:c].reshape(c, f, bp).transpose(1, 2, 0)[:, :num_bins, :]
+    out = acc[:c].reshape(c, f, bp)[:, :, :num_bins]       # [C, F, B]
+    return out if channel_major else out.transpose(1, 2, 0)
 
 
 def masked_histogram(binned: jax.Array, vals: jax.Array, leaf_of_row: jax.Array,

@@ -26,11 +26,14 @@ kEpsilon = 1e-15
 kMinScore = -jnp.inf
 
 
-def dequantize_hist(hist: jax.Array, scales: jax.Array) -> jax.Array:
+def dequantize_hist(hist: jax.Array, scales: jax.Array,
+                    axis: int = -1) -> jax.Array:
     """Quantized-training dequantization AT SPLIT-SCAN TIME: an exact
-    int32 histogram (or [3] leaf-total vector) whose trailing axis is
-    the (grad, hess, count) channel block becomes the real-valued f32
-    tensor the gain/leaf-value math below consumes.
+    int32 histogram (or [3] leaf-total vector) whose ``axis`` is the
+    (grad, hess, count) channel block (the trailing one of a ``[F, B, 3]``
+    histogram and of the totals, the leading one of the masked grower's
+    channel-major ``[3, F, B]``) becomes the real-valued f32 tensor the
+    gain/leaf-value math below consumes.
 
     The int32 accumulation (ops/histogram.py integer path) is exact, so
     this one widening multiply is the ONLY place quantization noise
@@ -38,18 +41,20 @@ def dequantize_hist(hist: jax.Array, scales: jax.Array) -> jax.Array:
     are deterministic integers times the iteration's shared scale, and
     split selection is bit-reproducible across serial and every
     sharded learner (the f32 path only guarantees that per compiled
-    program).  ``scales`` [3] broadcasts over a 3-channel trailing axis
-    and tiles over the split_batch 3K channel blocks.
+    program).  ``scales`` [3] broadcasts over a 3-channel axis and tiles
+    over the split_batch 3K channel blocks.
 
     A trace-time flop/byte note (obs/flops.py "dequant") is recorded by
     the grower at its call sites, not here — this helper also runs on
     tiny [3] totals where a per-call note would misattribute shapes.
     """
-    c = hist.shape[-1]
+    axis = axis % hist.ndim
+    c = hist.shape[axis]
     s = scales
     if c != s.shape[-1]:            # split_batch: 3K channels tile [3]
         s = jnp.tile(s, c // s.shape[-1])
-    return hist.astype(jnp.float32) * s
+    return hist.astype(jnp.float32) \
+        * s.reshape((c,) + (1,) * (hist.ndim - 1 - axis))
 
 
 class SplitParams(NamedTuple):
@@ -179,32 +184,32 @@ def leaf_gain(sum_g, sum_h, p: SplitParams, parent_output=None, count=None):
 
 def _numerical_candidates(hist, total, num_bin, na_bin, feature_mask,
                           params: SplitParams, parent_out, rand_bin=None):
-    """Gain tensor [2, F, B] over (missing-direction, feature, threshold).
+    """Gain tensor [2, F, B] over (missing-direction, feature, threshold)
+    and the left sums [2, 3, F, B] behind it, from a channel-major
+    histogram [3, F, B].
 
     rand_bin: [F] int32 or None — extra_trees mode (extremely randomized
     trees, feature_histogram.hpp:116): each feature is only allowed to
     split at its one pre-drawn random threshold bin.
     """
-    f, b, _ = hist.shape
-    cum = jnp.cumsum(hist, axis=1)                      # [F, B, 3] inclusive
+    _, f, b = hist.shape
+    cum = jnp.cumsum(hist, axis=2)                      # [3, F, B] inclusive
     bins = jnp.arange(b, dtype=jnp.int32)
 
     has_na = (na_bin >= 0)
-    na_vals = jnp.where(has_na[:, None],
-                        jnp.take_along_axis(
-                            hist, jnp.maximum(na_bin, 0)[:, None, None]
-                            .repeat(3, axis=2), axis=1)[:, 0, :],
-                        0.0)                            # [F, 3]
+    na_idx = jnp.broadcast_to(jnp.maximum(na_bin, 0)[None, :, None],
+                              (3, f, 1))
+    na_vals = jnp.where(has_na[None, :, None],
+                        jnp.take_along_axis(hist, na_idx, axis=2),
+                        0.0)                            # [3, F, 1]
 
     # dir 0: missing -> right. left(b) = cum[b]  (na bin == last, never left)
     # dir 1: missing -> left.  left(b) = cum[b] + hist[na]
-    left0 = cum
-    left1 = cum + na_vals[:, None, :]
-    lefts = jnp.stack([left0, left1], axis=0)           # [2, F, B, 3]
-    rights = total[None, None, None, :] - lefts
+    lefts = jnp.stack([cum, cum + na_vals], axis=0)     # [2, 3, F, B]
+    rights = total[None, :, None, None] - lefts
 
-    gl, hl, cl = lefts[..., 0], lefts[..., 1], lefts[..., 2]
-    gr, hr, cr = rights[..., 0], rights[..., 1], rights[..., 2]
+    gl, hl, cl = lefts[:, 0], lefts[:, 1], lefts[:, 2]
+    gr, hr, cr = rights[:, 0], rights[:, 1], rights[:, 2]
 
     gain_l = leaf_gain(gl, hl, params, parent_out, cl)
     gain_r = leaf_gain(gr, hr, params, parent_out, cr)
@@ -239,13 +244,13 @@ def _categorical_candidates(hist, total, num_bin, cat_mask,
     feature_histogram.hpp:278): one-vs-rest when few categories, else a
     two-direction scan over bins sorted by grad/hess ratio.
 
-    Returns (gains [3, F, B], lefts [3, F, B, 3], orders [3, F, B]):
-    scan modes = (one-vs-rest, ratio-ascending, ratio-descending); ``orders``
-    maps scan position -> bin id.
+    Returns (gains [3, F, B], lefts [3, 3, F, B], orders [3, F, B]):
+    scan modes = (one-vs-rest, ratio-ascending, ratio-descending), then the
+    channel for ``lefts``; ``orders`` maps scan position -> bin id.
     """
-    f, b, _ = hist.shape
+    _, f, b = hist.shape
     pcat = params._replace(lambda_l2=params.lambda_l2 + params.cat_l2)
-    g, h, c = hist[..., 0], hist[..., 1], hist[..., 2]
+    g, h, c = hist[0], hist[1], hist[2]
     used = c >= max(0.5, float(params.min_data_per_group) - 0.5)
     n_used = used.sum(axis=1)                            # [F]
     positions = jnp.arange(b, dtype=jnp.int32)
@@ -260,15 +265,16 @@ def _categorical_candidates(hist, total, num_bin, cat_mask,
     order_ovr = jnp.broadcast_to(positions[None, :], (f, b)).astype(jnp.int32)
     orders = jnp.stack([order_ovr, order_asc, order_desc])         # [3, F, B]
 
-    hist3 = jnp.broadcast_to(hist[None], (3, f, b, 3))
-    sorted_hist = jnp.take_along_axis(hist3, orders[..., None], axis=2)
-    cum = jnp.cumsum(sorted_hist, axis=2)                # [3, F, B, 3]
+    hist3 = jnp.broadcast_to(hist[None], (3, 3, f, b))
+    sorted_hist = jnp.take_along_axis(
+        hist3, jnp.broadcast_to(orders[:, None], (3, 3, f, b)), axis=3)
+    cum = jnp.cumsum(sorted_hist, axis=3)                # [3, 3, F, B]
     # mode 0 = one-vs-rest: left = single bin at this position
     lefts = cum.at[0].set(sorted_hist[0])
-    rights = total[None, None, None, :] - lefts
+    rights = total[None, :, None, None] - lefts
 
-    gl, hl, cl = lefts[..., 0], lefts[..., 1], lefts[..., 2]
-    gr, hr, cr = rights[..., 0], rights[..., 1], rights[..., 2]
+    gl, hl, cl = lefts[:, 0], lefts[:, 1], lefts[:, 2]
+    gr, hr, cr = rights[:, 0], rights[:, 1], rights[:, 2]
     gain_l = leaf_gain(gl, hl, pcat, parent_out, cl)
     gain_r = leaf_gain(gr, hr, pcat, parent_out, cr)
     gain_shift = leaf_gain(total[0], total[1], pcat, parent_out, total[2])
@@ -311,11 +317,11 @@ def _monotone_adjust(gains, lefts, total, mono, out_lo, out_hi, dir_axis,
     bound tensors — the allowed range of each CHILD as a function of the
     candidate threshold, so a split is only constrained by opposite
     leaves whose region actually overlaps that child's region."""
-    rights = total[None, None, None, :] - lefts
-    out_l = leaf_output(lefts[..., 0], lefts[..., 1], params, parent_out,
-                        lefts[..., 2])
-    out_r = leaf_output(rights[..., 0], rights[..., 1], params, parent_out,
-                        rights[..., 2])
+    rights = total[None, :, None, None] - lefts         # [2, 3, F, B]
+    out_l = leaf_output(lefts[:, 0], lefts[:, 1], params, parent_out,
+                        lefts[:, 2])
+    out_r = leaf_output(rights[:, 0], rights[:, 1], params, parent_out,
+                        rights[:, 2])
     if mono_bounds is not None:
         lo_l, hi_l, lo_r, hi_r = (b[None] for b in mono_bounds)  # [1,F,B]
         cl_l = jnp.clip(out_l, lo_l, hi_l)
@@ -325,8 +331,8 @@ def _monotone_adjust(gains, lefts, total, mono, out_lo, out_hi, dir_axis,
         cl_r = jnp.clip(out_r, out_lo, out_hi)
 
     def gain_given(sums, out):
-        tg = threshold_l1(sums[..., 0], params.lambda_l1)
-        return -(2.0 * tg * out + (sums[..., 1] + params.lambda_l2) * out * out)
+        tg = threshold_l1(sums[:, 0], params.lambda_l1)
+        return -(2.0 * tg * out + (sums[:, 1] + params.lambda_l2) * out * out)
 
     mono_f = mono[None, :, None]                       # broadcast over dirs/bins
     was_valid = gains > kMinScore
@@ -338,6 +344,15 @@ def _monotone_adjust(gains, lefts, total, mono, out_lo, out_hi, dir_axis,
     ok = jnp.where(mono_f > 0, cl_l <= cl_r,
                    jnp.where(mono_f < 0, cl_l >= cl_r, True))
     return jnp.where(was_valid & ok & (gains > kEpsilon), gains, kMinScore)
+
+
+def _take_sums(lefts, d, f, b):
+    """``lefts[d, :, f, b]`` of ``[D, 3, F, B]`` as three gathers of one
+    scalar each.  One gather of the 3-channel slice makes the TPU's
+    compiler lay the whole operand out with the channels minor, padded
+    3 -> 128: at 16 slots x 2,000 x 63 bins a copy of 4.2 GB, 1.23 s a job
+    (``copy.97``, PERF.md §5 at PR 28), and of 15.6 GB at 255 bins."""
+    return jnp.stack([lefts[d, c, f, b] for c in range(lefts.shape[1])])
 
 
 @jax.named_scope("lgbtpu.split")
@@ -352,7 +367,10 @@ def find_best_split(hist: jax.Array, total: jax.Array, num_bin: jax.Array,
                     mono_bounds=None) -> SplitResult:
     """Best split for one leaf across numerical and categorical features.
 
-    hist:         [F, B, 3] f32 — per-feature histograms (g, h, count)
+    hist:         [3, F, B] f32 — per-feature histograms, channel-major:
+                  (g, h, count) lead, bins are the minor axis.  No array
+                  of the scan has the 3 channels minor: the TPU's compiler
+                  pads such an axis to 128 lanes wherever it tiles it
     total:        [3] parent aggregates
     num_bin:      [F] int32 valid bin count per feature
     na_bin:       [F] int32 NaN-bin index or -1
@@ -361,7 +379,7 @@ def find_best_split(hist: jax.Array, total: jax.Array, num_bin: jax.Array,
     mono:         [F] int32 — monotone constraints -1/0/+1 (None = none)
     out_lo/out_hi: scalar allowed output range of this leaf (monotone)
     """
-    f, b, _ = hist.shape
+    _, f, b = hist.shape
     # static FLOP/byte note from the traced shapes (obs/flops.py): one
     # candidate leaf's scan — fires at trace time only; under the
     # grower's vmap the recorded unit is the per-leaf scan
@@ -421,7 +439,7 @@ def find_best_split(hist: jax.Array, total: jax.Array, num_bin: jax.Array,
         rem = nbest % (f * b)
         best_f = (rem // b).astype(jnp.int32)
         best_b = (rem % b).astype(jnp.int32)
-        left_sum = nlefts[best_dir, best_f, best_b]
+        left_sum = _take_sums(nlefts, best_dir, best_f, best_b)
         return (nbest_gain, best_f, best_b, best_dir == 1, left_sum,
                 jnp.bool_(False), iota_rank)
 
@@ -433,7 +451,7 @@ def find_best_split(hist: jax.Array, total: jax.Array, num_bin: jax.Array,
             rem = cbest % (f * b)
             best_f = (rem // b).astype(jnp.int32)
             pos = (rem % b).astype(jnp.int32)
-            left_sum = clefts[mode, best_f, pos]
+            left_sum = _take_sums(clefts, mode, best_f, pos)
             order = corders[mode, best_f]                 # [B] pos -> bin
             rank = jnp.argsort(order).astype(jnp.int32)   # bin -> pos
             # one-vs-rest: single bin at `pos` goes left -> rank 0 only

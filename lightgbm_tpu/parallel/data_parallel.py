@@ -9,7 +9,7 @@ TPU-native redesign of the reference DataParallelTreeLearner
   layout: the feature-group axis is padded to ``n_shards`` equal chunks
   and reduce-scattered, so each shard ends up holding only ITS chunk of
   the GLOBAL histograms — the grower's per-shard histogram carry is
-  ``[L, G/n_shards, B, 3]`` and per-chip histogram state stops scaling
+  ``[L, 3, G/n_shards, B]`` and per-chip histogram state stops scaling
   with the global feature width (the owner-shard memory shape the
   reference gets from ReduceScatter; arXiv:1611.01276's communication
   pattern for distributed tree induction);
@@ -24,7 +24,7 @@ TPU-native redesign of the reference DataParallelTreeLearner
 - row partition stays local (no row data ever moves, like the reference).
 
 ``owner_shard=False`` restores the previous design — ONE full-tensor
-``lax.psum`` of ``[F, B, 3]`` with the split decision recomputed
+``lax.psum`` of ``[3, F, B]`` with the split decision recomputed
 replicated on every shard — kept for A/B benchmarking
 (tools/bench_hist.py --sharded) and as a config escape hatch
 (``dp_owner_shard=false``).
@@ -103,7 +103,7 @@ def owner_hist_reduce(axis: str, n_shards: int, chunk: int,
                       ledger: CommLedger = None):
     """The ReduceScatter hook: pad the histogram's feature-group axis to
     ``n_shards * chunk`` rows and ``psum_scatter`` it, leaving each shard
-    with its owned ``[chunk, B, C]`` slice of the GLOBAL histograms
+    with its owned ``[C, chunk, B]`` slice of the GLOBAL histograms
     (data_parallel_tree_learner.cpp:185's communication shape; XLA
     lowers this to a true reduce-scatter over ICI, moving 1/n_shards of
     the bytes a full psum replicates to every chip).  ``ledger`` records
@@ -118,8 +118,8 @@ def owner_hist_reduce(axis: str, n_shards: int, chunk: int,
         h = pad_feature_axis(h, total)
         if ledger is not None:
             return ledger.psum_scatter(h, axis, site="dp.hist_reduce",
-                                       scatter_dimension=0, tiled=True)
-        return lax.psum_scatter(h, axis, scatter_dimension=0, tiled=True)
+                                       scatter_dimension=1, tiled=True)
+        return lax.psum_scatter(h, axis, scatter_dimension=1, tiled=True)
 
     return hist_reduce
 
@@ -236,13 +236,16 @@ def _make_dp_owner_grower(mesh: Mesh, *, num_leaves, num_bins, params,
 
         if efb is not None:
             # per-shard EFB expansion: owned-groups histogram
-            # [chunk, Bg, C] -> scan feature space [fmax, B, C], with the
+            # [C, chunk, Bg] -> scan feature space [C, fmax, B], with the
             # FixHistogram default-bin reconstruction (dataset.cpp:1292)
-            # done from the leaf totals on owned features only
+            # done from the leaf totals on owned features only; like the
+            # serial expansion (efb.expand_group_hist) it works on a
+            # turned [chunk, Bg, C] view
             bg = int(efb.group_bins)
             g_of = efb.group_of_feat
 
             def hist_expand(gh, total):
+                gh = jnp.moveaxis(gh, 0, -1)
                 idx = lax.axis_index(axis)
                 gfid = sf_dev[idx]
                 safe = jnp.maximum(gfid, 0)
@@ -258,12 +261,12 @@ def _make_dp_owner_grower(mesh: Mesh, *, num_leaves, num_bins, params,
                 rest = fh[:, 1:, :].sum(axis=1)
                 bin0 = jnp.where((jnp.take(efb.fix0, safe) & ok)[:, None],
                                  total[None, :] - rest, fh[:, 0, :])
-                return fh.at[:, 0, :].set(bin0)
+                return jnp.moveaxis(fh.at[:, 0, :].set(bin0), -1, 0)
         else:
             # unbundled: group == feature, owned features are the
             # contiguous chunk — the scan view just trims reduce padding
             def hist_expand(h, total):
-                return lax.slice_in_dim(h, 0, fmax, axis=0)
+                return lax.slice_in_dim(h, 0, fmax, axis=1)
 
         def mono_view(m):
             gfid = _gfid()

@@ -54,7 +54,7 @@ class OwnerShardPlan(NamedTuple):
 
     chunk:      histogram rows owned per shard, ``ceil(G / n_shards)``
                 (G = EFB group count, or F without bundling) — the dp
-                grower's per-shard histogram carry is [L, chunk, B, 3]
+                grower's per-shard histogram carry is [L, 3, chunk, B]
     fmax:       split-scan width per shard = max features owned by any
                 shard (> chunk only when EFB bundles several features
                 into one owned group)

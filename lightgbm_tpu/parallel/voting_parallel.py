@@ -53,7 +53,7 @@ _SHARED_LOCK = threading.Lock()
 
 def _local_feature_gains(h: jax.Array, params: SplitParams,
                          n_shards: int) -> jax.Array:
-    """Per-feature best LOCAL split gain from a local histogram [F, B, 3]
+    """Per-feature best LOCAL split gain from a local histogram [3, F, B]
     — the vote statistic.  Matches the reference's local search setup:
     L1/L2-regularized gains with the per-rank constraint rescale
     ``min_data_in_leaf /= num_machines`` / ``min_sum_hessian_in_leaf /=
@@ -64,12 +64,12 @@ def _local_feature_gains(h: jax.Array, params: SplitParams,
     mh = float(params.min_sum_hessian_in_leaf) / n_shards
     l1, l2 = float(params.lambda_l1), float(params.lambda_l2)
     eps = 1e-10
-    cum = jnp.cumsum(h, axis=1)
-    total = cum[:, -1:, :]
-    gl, hl = cum[..., 0], cum[..., 1]
-    gr = total[..., 0] - cum[..., 0]
-    hr = total[..., 1] - cum[..., 1]
-    cl, cr = cum[..., 2], total[..., 2] - cum[..., 2]
+    cum = jnp.cumsum(h, axis=2)
+    total = cum[:, :, -1:]
+    gl, hl = cum[0], cum[1]
+    gr = total[0] - cum[0]
+    hr = total[1] - cum[1]
+    cl, cr = cum[2], total[2] - cum[2]
 
     def tl1(g):
         if l1 <= 0.0:
@@ -121,12 +121,12 @@ def _build(mesh: Mesh, *, num_leaves, num_bins, params, top_k, max_depth,
     ledger = CommLedger(n_shards)     # static comm-bytes sites (obs/comm)
 
     def vote_reduce(h, scales=None):
-        f = h.shape[0]
+        f = h.shape[1]
         k = min(top_k, f)
         # quantized training: the vote statistic needs real values;
         # the LOCAL dequantization is scan-shaped work, the reduced
         # tensor stays exact int32
-        h_stat = h if scales is None else dequantize_hist(h, scales)
+        h_stat = h if scales is None else dequantize_hist(h, scales, axis=0)
         gains = _local_feature_gains(h_stat, params, n_shards)
         _, local_top = lax.top_k(gains, k)              # [k]
         onehot = jnp.zeros(f, jnp.float32).at[local_top].add(1.0)
@@ -139,11 +139,11 @@ def _build(mesh: Mesh, *, num_leaves, num_bins, params, top_k, max_depth,
         k2 = min(2 * k, f)
         _, selected = lax.top_k(score, k2)
         sel_mask = jnp.zeros(f, bool).at[selected].set(True)
-        # the ledger records the full zero-masked [F, B, 3] payload —
+        # the ledger records the full zero-masked [3, F, B] payload —
         # the tensor XLA actually reduces; the reference's
         # CopyLocalHistogram would ship only the voted k2/F slice.
         # jnp.where (not *) keeps the int32 dtype under quant
-        return ledger.psum(jnp.where(sel_mask[:, None, None], h,
+        return ledger.psum(jnp.where(sel_mask[None, :, None], h,
                                      jnp.zeros((), h.dtype)), axis,
                            site="voting.hist")
 

@@ -223,7 +223,9 @@ def watch_compiles(metrics, tracer=None) -> bool:
     compile-adjacent counters as ``jax.events{event=...}``, the
     library's own trace events as ``jax.traces{name=...}``, and the traces
     of a site by implementation (``obs.flops.note_traced(impl=)``) as
-    ``hist.contraction_traces{impl=...}``.
+    ``hist.contraction_traces{impl=...}``, with the tiles the kernel's plan
+    chose (``obs.flops.note_kernel_plan``) as ``hist.kernel_plans{fpart=,
+    parts=}``.
 
     Uses ``jax.monitoring``'s public listener hooks; listeners are
     process-global and cannot be unregistered, so the registered
@@ -234,7 +236,7 @@ def watch_compiles(metrics, tracer=None) -> bool:
         from jax import monitoring
     except Exception:
         return False
-    from ..obs.flops import IMPL_EVENT_PREFIX
+    from ..obs.flops import IMPL_EVENT_PREFIX, PLAN_EVENT_PREFIX
 
     def _on_duration(event: str, duration: float, **kw) -> None:
         if "compil" not in event:
@@ -252,6 +254,11 @@ def watch_compiles(metrics, tracer=None) -> bool:
         if event.startswith(IMPL_EVENT_PREFIX):
             site, impl = event[len(IMPL_EVENT_PREFIX):].split("/")
             metrics.counter(f"{site}.contraction_traces", impl=impl).inc()
+            return
+        if event.startswith(PLAN_EVENT_PREFIX):
+            site, tiles = event[len(PLAN_EVENT_PREFIX):].split("/")
+            metrics.counter(f"{site}.kernel_plans", **dict(
+                t.split("=") for t in tiles.split(","))).inc()
             return
         if "compil" not in event and "cache" not in event:
             return

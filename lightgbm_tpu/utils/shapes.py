@@ -47,7 +47,7 @@ from __future__ import annotations
 
 # floor of the leaf-budget bucket: the common LightGBM budgets 31..63
 # (default 31) collapse onto one L=64 trace; 127 -> 128, 255 -> 256.
-# Below the floor the padded state costs (hist [L, F, B, 3] carry) stay
+# Below the floor the padded state costs (hist [L, 3, F, B] carry) stay
 # small in absolute terms while the trace family shrinks drastically.
 LEAF_BUCKET_FLOOR = 64
 

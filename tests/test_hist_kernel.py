@@ -192,6 +192,8 @@ REAL = {
     "cell_root": (320_000, 2_000, 63, jnp.uint8, 0),
     "narrow_k1": (1_000_000, 28, 63, jnp.uint8, 0),
     "groups_uint16_in_parts": (400_000, 300, 700, jnp.uint16, 8),
+    # epsilon-b255.cv5's pass: 256 bins x 16 slots, a block in four parts
+    "cell_b255_k16_in_parts": (320_000, 2_000, 255, jnp.uint8, 16),
 }
 
 
@@ -214,3 +216,37 @@ def test_kernel_compiles_for_the_v5e(name, one_chip, no_compile_cache):
     # the binned matrix is read as placed: no second copy in another layout
     binned_bytes = n * f * jnp.dtype(dtype).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < binned_bytes // 2
+
+
+def test_grower_at_2000_features_255_bins_255_leaves_fits_the_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole batched grower of ``epsilon-b255.cv5`` (a 320,000-row fold,
+    2,000 features, 255 bins, 255 leaves in a budget of 256, 16 slots, the
+    kernel for its contraction) through the TPU's compiler: arguments and
+    temporaries fit the chip's 15.75 GiB, by XLA's own memory analysis.  The
+    parent's grower did not compile there: one copy of ``f32[32,2,2000,255,3]``
+    padded its minor 3 to 128 lanes, 15.6 GB (PERF.md section 6, PR 30).  With
+    the histograms channel-major the temporaries are a few times the
+    per-leaf state.  About a minute: one whole-program compile, the only
+    guard against a layout that pads coming back."""
+    from lightgbm_tpu.grower import make_grower
+    from lightgbm_tpu.ops.split import SplitParams
+    n, f, bins, leaves, k = 320_000, 2_000, 255, 255, 16
+    # the rule asks the backend: on the chip it answers "tpu"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    grow = make_grower(num_leaves=leaves, num_bins=bins, split_batch=k,
+                       padded_leaves=256, hist_overlap=True, jit=False,
+                       params=SplitParams(min_data_in_leaf=1,
+                                          min_sum_hessian_in_leaf=100.0))
+
+    def placed(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(grow).lower(
+        placed((n, f), jnp.uint8), placed((n, 3), jnp.float32),
+        placed((f,), jnp.bool_), placed((f,), jnp.int32),
+        placed((f,), jnp.int32), max_leaves=placed((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * 2 ** 30
+    state = (256 + k) * 3 * f * bins * 4
+    assert state < m.temp_size_in_bytes < 4 * state

@@ -55,7 +55,9 @@ class TestSplit:
     def _mk(self, binned, g, h, B):
         N, F = binned.shape
         vals = np.stack([g, h, np.ones(N, np.float32)], axis=1)
-        hist = compute_histogram(jnp.array(binned), jnp.array(vals), num_bins=B)
+        # the scan takes its histogram channel-major, [3, F, B]
+        hist = compute_histogram(jnp.array(binned), jnp.array(vals), num_bins=B,
+                                 channel_major=True)
         total = jnp.asarray(vals.sum(axis=0), dtype=jnp.float32)
         return hist, total
 

@@ -135,25 +135,26 @@ class TestOwnerShard:
 
     def test_reduce_scatter_owned_hist_shape(self, mesh8):
         # the per-shard histogram state after the reduce is the owned
-        # [ceil(F/8), B, 3] chunk of the GLOBAL histogram — the shape
-        # assertion behind the [L, F/n_shards, B, 3] grower carry
+        # [3, ceil(F/8), B] chunk of the GLOBAL histogram (channel-major,
+        # as the grower holds it) — the shape assertion behind the
+        # [L, 3, F/n_shards, B] grower carry
         from jax.sharding import PartitionSpec as P
         F, B = 11, 16
         plan = owner_shard_plan(np.arange(F), 8)
         assert plan.chunk == 2
         red = owner_hist_reduce("data", 8, plan.chunk)
         rng = np.random.RandomState(0)
-        local = rng.rand(8, F, B, 3).astype(np.float32)  # per-shard hists
+        local = rng.rand(8, 3, F, B).astype(np.float32)  # per-shard hists
 
         fn = jax.jit(jax.shard_map(
             lambda h: red(h[0]), mesh=mesh8,
             in_specs=(P("data", None, None, None),),
-            out_specs=P("data", None, None), check_vma=False))
+            out_specs=P(None, "data", None), check_vma=False))
         out = np.asarray(fn(local))
         # global stacked output = 8 shards x chunk rows of GLOBAL sums
-        assert out.shape == (8 * plan.chunk, B, 3)
-        ref = np.zeros((8 * plan.chunk, B, 3), np.float32)
-        ref[:F] = local.sum(axis=0)
+        assert out.shape == (3, 8 * plan.chunk, B)
+        ref = np.zeros((3, 8 * plan.chunk, B), np.float32)
+        ref[:, :F] = local.sum(axis=0)
         np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
 
     @pytest.mark.parametrize("split_batch", [1, 8])
