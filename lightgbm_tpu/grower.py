@@ -123,6 +123,45 @@ def slot_histograms(h: jax.Array, nslots: int) -> jax.Array:
     return h.reshape((3, nslots) + h.shape[1:]).swapaxes(0, 1)
 
 
+# lanes of a TPU vector register: a matrix narrower than this has all its
+# columns in every tile of its row-major form
+LANES = 128
+
+
+def column_reader(binned) -> Optional[Callable]:
+    """``columns(col_k) -> K arrays [N]`` for ``[K]`` traced column ids of
+    the dense binned matrix: K dynamic slices, no gather.  Call it once a
+    tree, outside the grow loop.  ``None`` for a ``SparseBinned`` matrix,
+    which has no columns to slice.
+
+    Of a matrix of 128 columns or more a column slice reads the one lane
+    tile that holds it, N x 128 bytes (2.7 ms a step of 16 at 320,000 x
+    2,000).  A narrower one has every column in every tile, whichever way
+    the TPU's compiler lays it out (28 columns padded to 128 lanes, or
+    column-major with 32 columns to a tile), so each slice reads all of it,
+    16 times a step (7.6 ms at 8.4M x 28): that one is turned once a tree
+    into one run of N bytes a column, end to end, N x F bytes in all, and
+    a column is a contiguous slice that the selects read in place (PERF.md
+    §6, PR 31).  Turning a wide one too is faster still (0.2 ms a step and
+    2.7 ms a tree at 320,000 x 2,000) but holds a second copy of the matrix
+    while a tree grows.  The runs are addressed in int32, so a narrow matrix
+    of 2**31 cells or more is sliced as a wide one is."""
+    if isinstance(binned, _spd.SparseBinned):
+        return None
+    n, f = binned.shape
+    turned = f < LANES and n * f < 2 ** 31
+    by_column = binned.T.reshape(-1) if turned else binned
+
+    def columns(col_k):
+        if turned:
+            return [lax.dynamic_slice_in_dim(by_column, col_k[k] * n, n)
+                    for k in range(col_k.shape[0])]
+        return [lax.dynamic_index_in_dim(by_column, col_k[k], axis=1,
+                                         keepdims=False)
+                for k in range(col_k.shape[0])]
+    return columns
+
+
 class _Unkeyable(Exception):
     pass
 
@@ -522,6 +561,97 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
 
         return child_hist
 
+    def _partition_rows(binned, columns, leaf_of_row, is_cat, na_bin_part,
+                        num_bin_part, leaf_k, new_leaf_k, feat_k, thr_k,
+                        dleft_k, icat_k, rank_k, tleft_k=None,
+                        tright_k=None):
+        """The row partition of one step, the strict grower's (one slot)
+        and the batched one's (K): a row of leaf ``leaf_k[k]`` stays if
+        the rank of its bin in column ``feat_k[k]`` is at most
+        ``thr_k[k]`` (its missing-value bin goes where ``dleft_k[k]``
+        says) and moves to ``new_leaf_k[k]`` otherwise.  Returns the new
+        ``leaf_of_row`` and, where ``tleft_k`` / ``tright_k`` name the
+        contraction slot of each side's rows (-1: none), the rows' ``tslot``.
+
+        What a row needs of its slot is K scalars, not a table: every
+        per-slot value reaches the rows by K compares and selects, fused
+        into one pass, where a look-up by the row's slot was an ``[N]``
+        gather that the TPU runs element by element (8-12 ns a row each,
+        ten of them a step: three fifths of HIGGS's device time, PERF.md
+        §6, PR 31).  The K split columns are K dynamic slices of the
+        dense matrix (``columns``, the tree's ``column_reader(binned)``).
+        The rule follows what the trace sees of its input, and
+        ``grower.partition_rule{rule=}`` says which it took:
+
+        - ``select``: dense matrix, ``is_cat is None``.  The split scan
+          then only ever returns the identity rank (ops/split.py
+          ``iota_rank``), so the rank of a bin is the bin: no ``[N]``
+          gather is left.
+        - ``select+rank``: a categorical feature is present; the one
+          look-up ``rank_k[slot, bin]`` stays.
+        - ``sparse``: a ``SparseBinned`` matrix has no columns to slice;
+          its rows' bins come from ``column_per_row`` as before (one
+          look-up of the absent value's bin), the rank's as above.
+
+        ``na_bin_part`` / ``num_bin_part`` are the global arrays under the
+        feature-parallel and owner-shard learners, indexed by the global
+        ``feat_k``."""
+        nslots = leaf_k.shape[0]
+        sparse = columns is None
+        hit = [leaf_of_row == leaf_k[k] for k in range(nslots)]
+
+        def of_slot(v, none):
+            """``v[k]`` for the rows of slot k, ``none`` for the rest; a
+            row is in at most one slot."""
+            out = none
+            for k in range(nslots):
+                out = jnp.where(hit[k], v[k], out)
+            return out
+
+        from .obs.flops import note_partition_rule
+        note_partition_rule(
+            "sparse" if sparse else
+            "select" if is_cat is None else "select+rank",
+            row_gathers=int(sparse and nslots > 1) + int(is_cat is not None))
+        if sparse:
+            raw = _spd.column(binned, feat_k[0]) if nslots == 1 else \
+                _spd.column_per_row(binned, of_slot(feat_k, jnp.int32(0)))
+        else:
+            col_k = feat_k if efb is None else efb.group_of_feat[feat_k]
+            cols = columns(col_k)
+            raw = of_slot(cols, jnp.zeros((), cols[0].dtype)) \
+                .astype(jnp.int32)
+        if efb is None:
+            fcol = raw
+        else:
+            # decode the feature's bins from its bundle column
+            # (SubFeatureIterator analog, feature_group.h)
+            off = of_slot(efb_off_dev[feat_k], jnp.int32(-1))
+            nbp = of_slot(num_bin_part[feat_k], jnp.int32(0))
+            in_range = (raw >= off) & (raw < off + nbp - 1)
+            fcol = jnp.where(off < 0, raw,
+                             jnp.where(in_range, raw - off + 1, 0))
+        nb = of_slot(na_bin_part[feat_k], jnp.int32(-1))
+        is_na = (nb >= 0) & (fcol == nb)
+        # decision rank unifies numerical (iota rank) and categorical
+        # (ratio-order rank) predicates
+        if is_cat is None:
+            rv = fcol
+        else:
+            is_na = is_na & ~of_slot(icat_k, jnp.bool_(False))
+            rv = rank_k[of_slot(jnp.arange(nslots, dtype=jnp.int32),
+                                jnp.int32(0)), fcol]
+        go_left = jnp.where(is_na, of_slot(dleft_k, jnp.bool_(False)),
+                            rv <= of_slot(thr_k, jnp.int32(0)))
+        moved = functools.reduce(jnp.logical_or, hit) & ~go_left
+        new_leaf_of_row = jnp.where(
+            moved, of_slot(new_leaf_k, jnp.int32(0)), leaf_of_row)
+        if tleft_k is None:
+            return new_leaf_of_row, None
+        return new_leaf_of_row, jnp.where(
+            go_left, of_slot(tleft_k, jnp.int32(-1)),
+            of_slot(tright_k, jnp.int32(-1)))
+
     gscale = None if gain_scale is None else jnp.asarray(gain_scale,
                                                          jnp.float32)
     mono_dev = None if mono is None else jnp.asarray(mono, jnp.int32)
@@ -794,6 +924,8 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             limit = jnp.asarray(max_leaves, jnp.int32)
         n, _f_global = binned.shape
         binned_view = view_fn(binned)
+        with jax.named_scope("lgbtpu.partition"):
+            columns = column_reader(binned)
         scales = None
         scan_expand = _expand
         if use_quant:
@@ -843,32 +975,11 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 rc = jnp.where(fix_r, i, st.right_child).at[i].set(~new_leaf)
 
                 # --- partition rows (CUDADataPartition::Split analog) -----
-                # decision rank unifies numerical (iota rank) and
-                # categorical (ratio-order rank) predicates
                 with jax.named_scope("lgbtpu.partition"):
-                    if efb is None:
-                        if isinstance(binned, _spd.SparseBinned):
-                            fcol = _spd.column(binned, feat)
-                        else:
-                            fcol = jnp.take(binned, feat, axis=1) \
-                                .astype(jnp.int32)
-                    else:
-                        # decode the feature's bins from its bundle column
-                        # (SubFeatureIterator analog, feature_group.h)
-                        gcol = jnp.take(binned, efb.group_of_feat[feat],
-                                        axis=1).astype(jnp.int32)
-                        off = efb_off_dev[feat]
-                        in_range = (gcol >= off) \
-                            & (gcol < off + num_bin_part[feat] - 1)
-                        fcol = jnp.where(
-                            off < 0, gcol,
-                            jnp.where(in_range, gcol - off + 1, 0))
-                    nb = na_bin_part[feat]
-                    is_na = (nb >= 0) & (fcol == nb) & (~icat)
-                    go_left = jnp.where(is_na, dleft, rank_vec[fcol] <= thr)
-                    in_leaf = st.leaf_of_row == leaf
-                    leaf_of_row = jnp.where(in_leaf & (~go_left), new_leaf,
-                                            st.leaf_of_row)
+                    leaf_of_row, _ = _partition_rows(
+                        binned, columns, st.leaf_of_row, is_cat, na_bin_part,
+                        num_bin_part, leaf[None], new_leaf[None], feat[None],
+                        thr[None], dleft[None], icat[None], rank_vec[None])
 
                 # --- histograms: smaller child + subtraction --------------
                 smaller_left = lsum[2] <= rsum[2]
@@ -1048,6 +1159,8 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             limit = jnp.asarray(max_leaves, jnp.int32)
         n, _f_global = binned.shape
         binned_view = view_fn(binned)
+        with jax.named_scope("lgbtpu.partition"):
+            columns = column_reader(binned)
         scales = None
         scan_expand = _expand
         if use_quant:
@@ -1088,8 +1201,8 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 # one partition pass serves all K splits of the super-
                 # step (trace-time note; obs/flops.py)
                 from .obs.flops import note_traced, partition_flops_bytes
-                note_traced("partition", *partition_flops_bytes(n),
-                            phase="grow")
+                note_traced("partition",
+                            *partition_flops_bytes(n, slots=K), phase="grow")
                 leaf_sel = jnp.where(valid, leaves, L + kidx)
                 node_sel = jnp.where(valid, num_nodes + kidx,
                                      jnp.int32(L - 1) + kidx)
@@ -1106,49 +1219,23 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 parent_k = st.leaf_parent[leaf_sel]
 
                 # --- partition rows: ONE pass for all K splits ------------
+                # the contraction slot of a row comes out of the same pass:
+                # slot k takes the smaller child of split k (both children,
+                # k and K + k, where nothing is subtracted); the scratch
+                # leaves ``L + k`` of the invalid slots hold no row
+                smaller_left = lsum_k[:, 2] <= rsum_k[:, 2]      # [K]
+                if use_subtraction:
+                    tleft_k = jnp.where(smaller_left, kidx, -1)
+                    tright_k = jnp.where(smaller_left, -1, kidx)
+                else:
+                    tleft_k, tright_k = kidx, K + kidx
                 with jax.named_scope("lgbtpu.partition"):
-                    slot_of_leaf = jnp.full(LP, -1, jnp.int32) \
-                        .at[leaf_sel].set(kidx)
-                    slot = slot_of_leaf[st.leaf_of_row]          # [N]
-                    active = slot >= 0
-                    sl = jnp.maximum(slot, 0)
-                    feat_r = feat_k[sl]                          # [N]
-                    if efb is None:
-                        if isinstance(binned, _spd.SparseBinned):
-                            fcol = _spd.column_per_row(binned, feat_r)
-                        else:
-                            fcol = jnp.take_along_axis(
-                                binned, feat_r[:, None], axis=1)[:, 0] \
-                                .astype(jnp.int32)
-                    else:
-                        grp_r = efb.group_of_feat[feat_r]
-                        gcol = jnp.take_along_axis(
-                            binned, grp_r[:, None], axis=1)[:, 0] \
-                            .astype(jnp.int32)
-                        off = efb_off_dev[feat_r]
-                        in_range = (gcol >= off) \
-                            & (gcol < off + num_bin_part[feat_r] - 1)
-                        fcol = jnp.where(
-                            off < 0, gcol,
-                            jnp.where(in_range, gcol - off + 1, 0))
-                    nb_r = na_bin_part[feat_r]
-                    icat_r = icat_k[sl]
-                    is_na = (nb_r >= 0) & (fcol == nb_r) & (~icat_r)
-                    rv = rank_k[sl, fcol]
-                    go_left = jnp.where(is_na, dleft_k[sl], rv <= thr_k[sl])
-                    leaf_of_row = jnp.where(active & (~go_left),
-                                            new_leaf_sel[sl], st.leaf_of_row)
+                    leaf_of_row, tslot = _partition_rows(
+                        binned, columns, st.leaf_of_row, is_cat, na_bin_part,
+                        num_bin_part, leaf_sel, new_leaf_sel, feat_k, thr_k,
+                        dleft_k, icat_k, rank_k, tleft_k, tright_k)
 
                 # --- batched child histograms: one C=3K contraction -------
-                with jax.named_scope("lgbtpu.hist.state"):
-                    smaller_left = lsum_k[:, 2] <= rsum_k[:, 2]  # [K]
-                    small_id = jnp.where(smaller_left, leaf_sel,
-                                         new_leaf_sel)
-                    targets = small_id if use_subtraction \
-                        else jnp.concatenate([leaf_sel, new_leaf_sel])
-                    tslot_of_leaf = jnp.full(LP, -1, jnp.int32) \
-                        .at[targets].set(jnp.arange(nC, dtype=jnp.int32))
-                    tslot = tslot_of_leaf[leaf_of_row]       # [N]
                 hist_c = _hist(binned_view, vals, tslot, nC,
                                scales=scales)                # [3nC, Fh, Bh]
                 with jax.named_scope("lgbtpu.hist.state"):

@@ -160,19 +160,27 @@ def split_scan_flops_bytes(n_feat: int, num_bins: int,
             * int(n_feat) * int(num_bins) * int(n_leaves))
 
 
-# per-row ops of one partition pass: feature-column gather, NaN test,
-# rank gather, threshold compare, leaf-id select
-PARTITION_OPS_PER_ROW = 5
+# per-row ops of one partition pass, a slot: the slot's compare and the
+# selects that hand the row its slot's column, threshold, missing-value bin,
+# default direction, new leaf and the two target slots
+PARTITION_OPS_PER_ROW_SLOT = 8
+# per-row ops past the slots: the missing-value test (2), the threshold
+# compare, the direction, leaf and target-slot selects
+PARTITION_OPS_PER_ROW = 6
 
 
-def partition_flops_bytes(n_rows: int,
-                          binned_itemsize: int = 1) -> Tuple[int, int]:
-    """One row-partition pass (grower do_split / super_step): gather
-    the winning feature's column, compare, rewrite ``leaf_of_row``.
-    Bytes: column read + leaf_of_row read+write (int32)."""
-    n = int(n_rows)
-    return (PARTITION_OPS_PER_ROW * n,
-            n * int(binned_itemsize) + 2 * n * 4)
+def partition_flops_bytes(n_rows: int, binned_itemsize: int = 1,
+                          slots: int = 1) -> Tuple[int, int]:
+    """One row-partition pass (``grower._partition_rows``, a step of the
+    strict grower or a super-step of the batched one): ``slots`` compares
+    and selects a row hand it its slot's scalars, then one threshold
+    compare rewrites ``leaf_of_row`` and, for the batched grower, writes
+    the rows' contraction slot.  Bytes: the ``slots`` split columns read
+    (one dynamic slice each; no gather), ``leaf_of_row`` read and written,
+    ``tslot`` written where there is more than one slot (int32 each)."""
+    n, k = int(n_rows), int(slots)
+    return ((PARTITION_OPS_PER_ROW_SLOT * k + PARTITION_OPS_PER_ROW) * n,
+            k * n * int(binned_itemsize) + (2 + (k > 1)) * n * 4)
 
 
 # ops per quantized value: divide by scale, hash-uniform draw (~2 mixes
@@ -294,6 +302,7 @@ _TRACED: Dict[str, FlopSite] = {}
 _IMPLS: Dict[Tuple[str, str], int] = {}
 IMPL_EVENT_PREFIX = "/lgbtpu/impl/"
 PLAN_EVENT_PREFIX = "/lgbtpu/plan/"
+PARTITION_EVENT_PREFIX = "/lgbtpu/partition/"
 
 # ambient member-axis multiplier (fleet/trainer.py): while a fleet
 # program traces, every site note fires ONCE (vmap traces the body once)
@@ -359,6 +368,18 @@ def note_kernel_plan(site: str, **tiles: int) -> None:
     from jax import monitoring
     monitoring.record_event(PLAN_EVENT_PREFIX + site + "/" + ",".join(
         f"{k}={int(v)}" for k, v in sorted(tiles.items())))
+
+
+def note_partition_rule(rule: str, row_gathers: int) -> None:
+    """Count a trace of a grower's row partition by the rule it took
+    (``grower._partition_rows``: ``select``, ``select+rank`` or ``sparse``)
+    as ``grower.partition_rule{rule=}``, and add the ``[N]`` look-ups that
+    rule keeps a step to ``grower.partition_row_gathers``, in the registry
+    of the session that runs (through ``jax.monitoring``, like the kernel's
+    plan)."""
+    from jax import monitoring
+    monitoring.record_event(
+        f"{PARTITION_EVENT_PREFIX}{rule}/{int(row_gathers)}")
 
 
 def traced_sites() -> Dict[str, FlopSite]:
@@ -477,7 +498,7 @@ class FlopLedger:
         led.add("split_scan", "grow", f, b, "step")
         f, b = split_scan_flops_bytes(n_feat, num_bins, n_leaves=1)
         led.add("split_root", "grow", f * nc, b * nc, "iter")
-        f, b = partition_flops_bytes(n_rows, binned_itemsize)
+        f, b = partition_flops_bytes(n_rows, binned_itemsize, slots=k)
         led.add("partition", "grow", f, b, "step")
         f, b = score_update_flops_bytes(n_rows)
         led.add("score", "score", f * nc, b * nc, "iter")

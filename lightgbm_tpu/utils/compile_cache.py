@@ -225,7 +225,9 @@ def watch_compiles(metrics, tracer=None) -> bool:
     of a site by implementation (``obs.flops.note_traced(impl=)``) as
     ``hist.contraction_traces{impl=...}``, with the tiles the kernel's plan
     chose (``obs.flops.note_kernel_plan``) as ``hist.kernel_plans{fpart=,
-    parts=}``.
+    parts=}``, and the rule a grower's row partition took
+    (``obs.flops.note_partition_rule``) as ``grower.partition_rule{rule=}``
+    with the ``[N]`` look-ups it keeps in ``grower.partition_row_gathers``.
 
     Uses ``jax.monitoring``'s public listener hooks; listeners are
     process-global and cannot be unregistered, so the registered
@@ -236,7 +238,8 @@ def watch_compiles(metrics, tracer=None) -> bool:
         from jax import monitoring
     except Exception:
         return False
-    from ..obs.flops import IMPL_EVENT_PREFIX, PLAN_EVENT_PREFIX
+    from ..obs.flops import (IMPL_EVENT_PREFIX, PARTITION_EVENT_PREFIX,
+                             PLAN_EVENT_PREFIX)
 
     def _on_duration(event: str, duration: float, **kw) -> None:
         if "compil" not in event:
@@ -259,6 +262,11 @@ def watch_compiles(metrics, tracer=None) -> bool:
             site, tiles = event[len(PLAN_EVENT_PREFIX):].split("/")
             metrics.counter(f"{site}.kernel_plans", **dict(
                 t.split("=") for t in tiles.split(","))).inc()
+            return
+        if event.startswith(PARTITION_EVENT_PREFIX):
+            rule, gathers = event[len(PARTITION_EVENT_PREFIX):].split("/")
+            metrics.counter("grower.partition_rule", rule=rule).inc()
+            metrics.counter("grower.partition_row_gathers").inc(int(gathers))
             return
         if "compil" not in event and "cache" not in event:
             return

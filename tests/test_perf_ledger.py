@@ -101,9 +101,15 @@ class TestFlopFormulas:
         count = sum(2 for _ in range(n))   # gather + add per row
         assert flops == count
         assert hbm == n * 4 + 2 * n * 4
+        # one slot (the strict grower): the slot's compare and seven
+        # selects, six ops past the slots; its column, leaf_of_row in and out
         pf, pb = partition_flops_bytes(n, binned_itemsize=2)
-        assert pf == 5 * n
+        assert pf == (8 + 6) * n
         assert pb == n * 2 + 2 * n * 4
+        # 16 slots a step: 16 columns read, and the rows' slot written too
+        pf, pb = partition_flops_bytes(n, slots=16)
+        assert pf == (8 * 16 + 6) * n
+        assert pb == 16 * n + 3 * n * 4
 
     def test_train_hist_flops_per_iter_is_the_bench_formula(self):
         # the formula bench.py used to carry privately:

@@ -13,6 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from lightgbm_tpu import efb as efb_mod
+from lightgbm_tpu import sparse_data
 from lightgbm_tpu.grower import make_grower, slot_histograms
 from lightgbm_tpu.ops import hist_kernel
 from lightgbm_tpu.ops.histogram import compute_histogram
@@ -50,8 +52,12 @@ def grown(n, f, bins, leaves, k, seed):
     grow = make_grower(num_leaves=leaves, num_bins=bins, split_batch=k,
                        params=SplitParams(min_data_in_leaf=1,
                                           min_sum_hessian_in_leaf=1e-3))
-    t = grow(jnp.asarray(binned), jnp.asarray(vals), jnp.ones(f, bool),
-             jnp.full(f, bins, jnp.int32), jnp.full(f, -1, jnp.int32))
+    return digest(grow(
+        jnp.asarray(binned), jnp.asarray(vals), jnp.ones(f, bool),
+        jnp.full(f, bins, jnp.int32), jnp.full(f, -1, jnp.int32)))
+
+
+def digest(t):
     h = hashlib.sha256()
     for name in t._fields:
         h.update(np.ascontiguousarray(np.asarray(getattr(t, name))).tobytes())
@@ -201,3 +207,192 @@ def test_a_booster_notes_its_compiled_growers_memory_once():
     # reading the analysis lowers from the trace of the call that ran
     assert snaps[0].get("jax.traces{name=grower}", {"value": 0})["value"] <= 1
     assert "jax.traces{name=grower}" not in snaps[1]
+
+
+# --- the row partition of a step (PR 31) -----------------------------------
+# A row's slot, its split's column and its target slot reach it by K compares
+# and selects (``grower._partition_rows``), where they were ten ``[N]``
+# look-ups a step.  The four pins above hold unchanged; these cover what they
+# lack.  Leaves grown, steps and digest (every field of ``TreeArrays``,
+# ``leaf_of_row`` among them) as the grower of the parent commit (741bc37,
+# the look-ups) gave them for ``partition_case(name)``, taken before the
+# change; the accumulands are exact as above.
+PARTITION_PINNED = {
+    "nan_bins_k16": (63, 7, "d879421fd15d610e"),
+    "categorical_among_numeric_k16": (63, 7, "107178b5cf8aa53a"),
+    "nan_and_categorical_k16": (63, 7, "463870ce97aced6d"),
+    "efb_bundles_k8": (63, 10, "0b6cb4f7b2c3f710"),
+    "padded_leaves_k16": (40, 6, "0cdc729a1e2c1eec"),
+    "no_subtraction_k8": (63, 10, "fd3c6254d6a290ea"),
+    "sparse_binned_k8": (63, 10, "350ae2c47ec4c0ae"),
+    "strict_nan_categorical": (31, 30, "1cd8d151b6cd8b6a"),
+}
+
+
+def efb_case(n, bins):
+    """Six plain columns and two bundles of three mutually exclusive
+    8-bin features: the bundled matrix ``[n, 8]``, the accumulands and the
+    device's bundling state."""
+    rng = np.random.default_rng(23)
+    plain, vals = exact_inputs(n, 6, bins, 23)
+    owner = rng.integers(0, 4, (n, 2))          # 3: the row is in none
+    sub = rng.integers(1, 8, (n, 2))            # the owner's bin, 1..7
+    grouped = np.concatenate([plain, np.zeros((n, 2), np.uint8)], axis=1)
+    off = np.full(12, -1, np.int32)
+    for g in range(2):
+        for j in range(3):
+            off[6 + 3 * g + j] = 1 + 7 * j
+            grouped[:, 6 + g] += np.where(owner[:, g] == j,
+                                          7 * j + sub[:, g], 0) \
+                .astype(np.uint8)
+    num_bin = np.array([bins] * 6 + [8] * 6, np.int32)
+    info = efb_mod.EFBInfo(
+        groups=[[j] for j in range(6)] + [[6, 7, 8], [9, 10, 11]],
+        group_of_feat=np.concatenate(
+            [np.arange(6), np.repeat([6, 7], 3)]).astype(np.int32),
+        off_of_feat=off,
+        group_num_bin=np.array([bins] * 6 + [22, 22], np.int32))
+    return grouped, vals, num_bin, efb_mod.make_device_efb(info, num_bin,
+                                                           bins)
+
+
+def partition_case(name):
+    """Grower options, positional and keyword arguments of one case of
+    ``PARTITION_PINNED``: 3,000 rows, 12 features, 32 bins."""
+    p = SplitParams(min_data_in_leaf=1, min_sum_hessian_in_leaf=1e-3)
+    opts = dict(num_leaves=63, num_bins=32, split_batch=16, params=p)
+    n, f, bins = 3000, 12, 32
+    kw = {}
+    if name == "efb_bundles_k8":
+        grouped, vals, num_bin, opts["efb"] = efb_case(n, bins)
+        opts["split_batch"] = 8
+        return opts, (jnp.asarray(grouped), jnp.asarray(vals),
+                      jnp.ones(12, bool), jnp.asarray(num_bin),
+                      jnp.full(12, -1, jnp.int32)), kw
+    binned, vals = exact_inputs(n, f, bins, sum(map(ord, name)))
+    na = np.full(f, -1, np.int32)
+    if "nan" in name:
+        # the last bin of the first six columns is their missing-value bin;
+        # the label leans on two of them from either side, so that the
+        # missing rows go left at some nodes and right at others
+        na[:6] = bins - 1
+        miss = np.random.default_rng(5).random((n, 6)) < 0.2
+        binned[:, :6] = np.where(miss, bins - 1,
+                                 np.minimum(binned[:, :6], bins - 2))
+        g = vals[:, 0] + np.where(miss[:, 1], -0.25, 0.0) \
+            + np.where(miss[:, 2], 0.25, 0.0) \
+            + (binned[:, 1] > 12) * 0.125 - (binned[:, 2] > 20) * 0.125
+        vals = np.stack([g, vals[:, 1], vals[:, 2]], axis=1) \
+            .astype(np.float32)
+    if "categorical" in name:
+        is_cat = np.zeros(f, bool)
+        is_cat[[1, 4]] = True
+        code = np.array([3, -2, 5, 0, -4, 1, 2, -1])[binned[:, 1] % 8]
+        vals[:, 0] += (code / 4).astype(np.float32)
+        kw["is_cat"] = jnp.asarray(is_cat)
+        opts["params"] = p._replace(cat_smooth=1.0, cat_l2=1.0,
+                                    min_data_per_group=4)
+    if name == "padded_leaves_k16":
+        opts.update(num_leaves=40, padded_leaves=64)
+        kw["max_leaves"] = jnp.int32(40)
+    if name == "no_subtraction_k8":
+        opts.update(split_batch=8, subtract=False)
+    if name.startswith("strict"):
+        opts.update(split_batch=1, num_leaves=31)
+    b = jnp.asarray(binned)
+    if name == "sparse_binned_k8":
+        opts["split_batch"] = 8
+        binned = np.where(np.random.default_rng(4).random((n, f)) < 0.7, 0,
+                          binned).astype(np.uint8)
+        binned[:, 0] = exact_inputs(n, f, bins, sum(map(ord, name)))[0][:, 0]
+        rows, cols = np.nonzero(binned)
+        stored = np.bincount(rows, minlength=n)
+        flat = np.full((n, int(stored.max())), -1, np.int32)
+        flat[rows, np.concatenate([np.arange(c) for c in stored])] = \
+            cols * bins + binned[rows, cols]
+        b = sparse_data.SparseBinned(jnp.asarray(flat),
+                                     jnp.zeros(f, jnp.int32), bins, f)
+    return opts, (b, jnp.asarray(vals), jnp.ones(f, bool),
+                  jnp.full(f, bins, jnp.int32), jnp.asarray(na)), kw
+
+
+@pytest.mark.parametrize("name", list(PARTITION_PINNED))
+def test_partition_by_selects_grows_the_trees_the_look_ups_grew(name):
+    opts, args, kw = partition_case(name)
+    t = make_grower(**opts)(*args, **kw)
+    assert digest(t) == PARTITION_PINNED[name]
+    # the case holds what its name says
+    nodes = slice(0, int(t.num_leaves) - 1)
+    at_na = np.asarray(args[4])[np.asarray(t.split_feature)[nodes]] >= 0
+    cat = np.asarray(t.is_cat_node)[nodes]
+    if "nan" in name:
+        sent_left = np.asarray(t.default_left)[nodes][at_na & ~cat]
+        assert sent_left.any() and not sent_left.all()
+    assert cat.any() == ("categorical" in name)
+    if "efb" in name:
+        assert (np.asarray(t.split_feature)[nodes] >= 6).any()
+
+
+def row_gathers_of_the_step(opts, args, kw):
+    """``(operand shape, result shape)`` of every ``gather`` inside the
+    grower's loop whose result's leading dimension is the row count."""
+    n = args[0].shape[0]
+    grow = make_grower(jit=False, **opts)
+    shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args[:2]]
+    closed = jax.make_jaxpr(lambda b, v: grow(b, v, *args[2:], **kw))(*shapes)
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            step = inside or eqn.primitive.name == "while"
+            shape = tuple(eqn.outvars[0].aval.shape)
+            if step and eqn.primitive.name == "gather" and shape[:1] == (n,):
+                yield tuple(eqn.invars[0].aval.shape), shape
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub, step)
+    return list(walk(closed.jaxpr, False))
+
+
+def cell_shaped_grower(is_cat=None):
+    """The grower the cells run, at a CPU size: 255 leaves, 16 a step, 255
+    bins, a dense uint8 matrix."""
+    n, f, bins = 1000, 12, 255
+    opts = dict(num_leaves=255, num_bins=bins, split_batch=16,
+                params=SplitParams())
+    args = (jnp.zeros((n, f), jnp.uint8), jnp.zeros((n, 3), jnp.float32),
+            jnp.ones(f, bool), jnp.full(f, bins, jnp.int32),
+            jnp.full(f, -1, jnp.int32))
+    return opts, args, {} if is_cat is None else {"is_cat": is_cat}
+
+
+def test_the_batched_step_of_a_dense_numeric_input_holds_no_row_gather():
+    """The jaxpr of the batched grower for a dense input without a
+    categorical feature: no ``gather`` in the loop has an ``[N]`` result (the
+    parent's step held ten).  With a categorical feature exactly one is left,
+    the rank of the row's bin in its slot's ``[K, B]`` table."""
+    assert row_gathers_of_the_step(*cell_shaped_grower()) == []
+    assert row_gathers_of_the_step(*cell_shaped_grower(
+        jnp.asarray(np.arange(12) == 3))) == [((16, 255), (1000,))]
+
+
+@pytest.mark.parametrize("name,rule,gathers", [
+    ("dense_numeric", "select", 0),
+    ("categorical_among_numeric_k16", "select+rank", 1),
+    ("sparse_binned_k8", "sparse", 1),
+    ("strict_nan_categorical", "select+rank", 1),
+])
+def test_a_grower_counts_the_partition_rule_it_was_traced_with(
+        name, rule, gathers):
+    """``grower.partition_rule{rule=}`` and ``grower.partition_row_gathers``
+    beside ``jax.traces{name=grower}``, once a trace."""
+    from lightgbm_tpu.obs import ObsSession
+    opts, args, kw = cell_shaped_grower() if name == "dense_numeric" \
+        else partition_case(name)
+    session = ObsSession()
+    session.activate()
+    jax.eval_shape(lambda: make_grower(jit=False, **opts)(*args, **kw))
+    snap = session.snapshot()
+    assert snap["jax.traces{name=grower}"]["value"] == 1
+    assert {k: v["value"] for k, v in snap.items()
+            if k.startswith("grower.partition_rule")} \
+        == {"grower.partition_rule{rule=%s}" % rule: 1}
+    assert snap["grower.partition_row_gathers"]["value"] == gathers
