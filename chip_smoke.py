@@ -17,7 +17,7 @@ persistent-cache hits and misses, and the device's peak bytes in use):
   sizes and the held-out AUC, and checks tree 0's root split against a
   float64 NumPy gain scan over the same binned matrix.
 - ``train31``   31 leaves, ``split_batch=1``, 25 rounds, no valid set: the
-  strict grower inside the fused-chunk loop.
+  strict grower inside the super-epoch scan, its eval tail empty.
 - ``serve``     ``serve.Server`` at defaults answers 20 requests of 1..4096
   rows byte-equal to ``Booster.predict``; a second ``Server`` with
   ``serve_device_binning`` answers them through the fused program within
@@ -67,7 +67,7 @@ PARAMS_31 = dict(PARAMS_255, num_leaves=31, split_batch=1)
 
 
 def make_higgs_like(n: int, f: int, seed: int = 0):
-    """HIGGS-shaped seeded data (the generator bench.py uses)."""
+    """HIGGS-shaped seeded data."""
     rng = np.random.RandomState(seed)
     x = rng.randn(n, f).astype(np.float32)
     logit = (1.2 * x[:, 0] - 0.8 * x[:, 1] + 0.6 * x[:, 2] * x[:, 3]
@@ -175,15 +175,12 @@ def counter(snap: dict, key: str) -> int:
 
 
 def engaged_loop(bst) -> dict:
-    """Which of the three training loops ran, from the telemetry counters."""
+    """Which of the two training loops ran, from the telemetry counters."""
     snap = bst.telemetry_snapshot()
     out = {"superepochs": counter(snap, "train.superepochs"),
-           "fused_chunks": counter(snap, "train.fused_chunks"),
            "iterations": counter(snap, "train.iterations")}
     if out["superepochs"]:
         out["loop"] = "superepoch"
-    elif out["fused_chunks"]:
-        out["loop"] = "fused_chunk"
     else:
         out["loop"] = "per_iteration"
         out["fused_reasons"] = bst._model.fused_reasons()
@@ -234,7 +231,7 @@ def stage_train31(ds, xv, yv):
     bst = lgb.train(PARAMS_31, ds, num_boost_round=ROUNDS_31)
     loop = engaged_loop(bst)
     assert_main_path(bst._model, split_batch=1)
-    assert loop["loop"] == "fused_chunk", loop
+    assert loop["loop"] == "superepoch", loop
     leaves = [t.num_leaves for t in bst.trees]
     assert len(leaves) == ROUNDS_31 and min(leaves) > 15, leaves
     pred = bst.predict(xv)

@@ -217,15 +217,6 @@ class Booster:
         self._sync_trees()
         return stopped
 
-    def update_chunk(self, k: int) -> bool:
-        """Run ``k`` iterations fused in one device program (one host
-        round trip per chunk — see GBDTModel.train_chunk).  Caller must
-        have checked ``supports_fused()``; returns True if training hit a
-        no-split iteration."""
-        stopped = self._model.train_chunk(k)
-        self._sync_trees()
-        return stopped
-
     def update_superepoch(self, k: int, es_it0: int, eval_spec=(),
                           es_spec=None) -> dict:
         """Run ``k`` FULL iterations — growth, score updates, valid-set
@@ -237,16 +228,10 @@ class Booster:
         self._sync_trees()
         return out
 
-    def supports_fused(self) -> bool:
-        return (self._model is not None
-                and hasattr(self._model, "supports_fused")
-                and self._model.supports_fused()
-                and not self._model.valid_sets)
-
     def fused_reasons(self) -> List[str]:
-        """Why ``supports_fused()`` is False — specific blockers, empty
-        when fusion is eligible (GBDTModel.fused_reasons; bench
-        provenance and error messages)."""
+        """Why a run takes the per-iteration loop and not the scan —
+        specific blockers, empty when the model config can be scanned
+        (GBDTModel.fused_reasons)."""
         if self._model is None or not hasattr(self._model,
                                               "fused_reasons"):
             return ["no active training model"]
@@ -464,8 +449,8 @@ class Booster:
                 or "shrinkage_rate" in params:
             self._model.learning_rate = float(
                 self._model.config.learning_rate)
-        # the fused-chunk program bakes the learning rate (and sampling
-        # config) into its jitted closure — drop it so the next chunk
+        # a private scan program bakes the learning rate (and sampling
+        # config) into its jitted closure — drop it so the next epoch
         # re-traces with the new values
         self._model._fused_cache.clear()
         return self

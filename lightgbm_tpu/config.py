@@ -197,18 +197,19 @@ _PARAMS: Dict[str, tuple] = {
     # accelerators where per-split host round-trips dominate
     "tpu_learner": (str, "auto", []),  # auto | partitioned | masked
     "rows_per_block": (int, 0, []),          # 0 = auto-tune histogram row blocking
-    # iterations fused into one on-device program (lax.scan) when the
-    # objective/bagging config allows it — one blocking host fetch per
-    # chunk instead of several per iteration (every fetch is a sync).
-    # 0/1 disables fusion.
+    # the super-epoch's size under superepoch=0: iterations run as one
+    # on-device program (lax.scan) when the objective/sampling config
+    # allows it — one blocking host fetch per epoch instead of several
+    # per iteration (every fetch is a sync).  0/1: no scan unless
+    # superepoch > 0 names a size itself.
     "fused_chunk": (int, 25, []),
     # super-epoch trainer (docs/Fused-Training.md): lax.scan over k FULL
     # boosting iterations — grow + score update + traced metric eval
     # over the bucketed validation sets + an early-stop vote carried as
     # a traced flag — with exactly ONE host sync per epoch.  0 = auto
     # (engine picks k from fused_chunk / early_stopping_round when the
-    # config qualifies), >0 = explicit epoch size, -1 = disable (always
-    # per-iteration eval)
+    # config qualifies), >0 = explicit epoch size, -1 = the
+    # per-iteration loop throughout
     "superepoch": (int, 0, []),
     # ---- fleet training (lightgbm_tpu/fleet/, docs/Fleet.md) ----
     # number of fleet members when no explicit sweep is given: N seed
@@ -374,8 +375,8 @@ _PARAMS: Dict[str, tuple] = {
     # the mesh; host_loss always shrinks immediately
     "elastic_retries": (int, 1, []),
     # check grad/hess and new-tree leaf outputs for non-finite values
-    # every k iterations (one amortized scalar sync; fused-chunk
-    # compatible); 0 disables
+    # every k iterations (one amortized scalar sync; rides the
+    # scan's one fetch); 0 disables
     "finite_check_freq": (int, 0, []),
     # what to do when the finite check trips: raise | skip_iter (the
     # iteration contributes a zero stump) | clamp (nan_to_num gradients
@@ -389,7 +390,7 @@ _PARAMS: Dict[str, tuple] = {
     # riding the existing consolidated fetch every iteration.  0
     # disables the layer entirely (byte-identical to pre-integrity
     # behavior, zero extra host syncs).  Forces the per-iteration
-    # training path (fused_chunk/super-epoch fall back; see
+    # training path (the super-epoch falls back; see
     # GBDTModel.fused_reasons)
     "integrity_check_freq": (int, 0, []),
     # what a STICKY mismatch (fails the one re-check) does: raise

@@ -213,10 +213,6 @@ def _train_impl(params: Dict[str, Any], train_set: Dataset,
     if cfg.early_stopping_round and cfg.early_stopping_round > 0:
         cbs.append(callback_mod.early_stopping(
             cfg.early_stopping_round, cfg.first_metric_only, cfg.verbosity > 0))
-    if cfg.verbosity > 0 and cfg.metric_freq > 0 and \
-            not any(getattr(c, "order", 0) == 10 and not
-                    getattr(c, "before_iteration", False) for c in cbs):
-        pass  # explicit log_evaluation only (sklearn-compatible silence)
     cbs_before = [c for c in cbs if getattr(c, "before_iteration", False)]
     cbs_after = [c for c in cbs if not getattr(c, "before_iteration", False)]
     cbs_before.sort(key=lambda c: getattr(c, "order", 0))
@@ -225,38 +221,24 @@ def _train_impl(params: Dict[str, Any], train_set: Dataset,
     import time as _time
     t_start = _time.time()
 
-    # fused chunks: when no per-iteration host work is needed (no
-    # callbacks, eval, snapshots or custom fobj), run iterations in
-    # on-device chunks of ``fused_chunk`` — one host sync per chunk
-    # instead of ~5 per iteration (each fetch is a blocking sync).
-    # Any remainder falls through to the per-iter loop.
     start_round = resume_start
-    chunk_stopped = False
-    chunk = cfg.fused_chunk
-    if (chunk > 1 and fobj is None and not cbs
-            and not booster._valid_names
-            and not cfg.is_provide_training_metric
-            and train_eval_name is None
-            and cfg.snapshot_freq <= 0 and cfg.verbosity <= 1
-            and booster.supports_fused()):
-        while num_boost_round - start_round >= chunk and not chunk_stopped:
-            chunk_stopped = booster.update_chunk(chunk)
-            # current_iteration counts only THIS booster's iterations;
-            # a resumed run's global round index carries the offset
-            start_round = resume_start + booster.current_iteration
+    scan_stopped = False
 
     # super-epochs: the whole-run on-device path — k FULL iterations
-    # (growth + score + valid scoring + traced eval + early-stop vote)
-    # per device program, ONE host sync each, then the fetched eval
+    # (growth + score + valid scoring + traced eval + early-stop vote;
+    # without valid sets, growth + score alone) per device program, ONE
+    # host sync each instead of ~5 per iteration, then the fetched eval
     # block replayed through the REAL callbacks so record_evals /
-    # early_stopping / best_iteration are byte-identical per-iteration
-    se_plan = None if chunk_stopped else _superepoch_plan(
+    # early_stopping / best_iteration are byte-identical per-iteration.
+    # What the plan declines, and any remainder under 2 rounds, falls
+    # through to the per-iteration loop.
+    se_plan = _superepoch_plan(
         cfg, booster, fobj, feval, cbs_before, cbs_after,
         train_eval_name)
     if se_plan is not None:
         base_k, eval_spec, es_spec = se_plan
         from .utils.log import Log
-        while not chunk_stopped:
+        while not scan_stopped:
             k_eff = min(base_k, num_boost_round - start_round)
             if cfg.snapshot_freq > 0:
                 # clip to the snapshot boundary so periodic snapshots
@@ -316,7 +298,7 @@ def _train_impl(params: Dict[str, Any], train_set: Dataset,
                         booster._sync_trees()
                     break
             if es_raised or out["stump"]:
-                chunk_stopped = True
+                scan_stopped = True
             elif out["stop_row"] is not None:
                 # vote tripped but the replay did not raise (defensive
                 # mirror of the overshoot case): trust the host, clear
@@ -324,8 +306,10 @@ def _train_impl(params: Dict[str, Any], train_set: Dataset,
                 Log.warning("super-epoch early-stop vote tripped but "
                             "the host callbacks did not; resuming")
                 booster._model.clear_es_stop()
+            # current_iteration counts only THIS booster's iterations;
+            # a resumed run's global round index carries the offset
             start_round = resume_start + booster.current_iteration
-        if not chunk_stopped and start_round < num_boost_round \
+        if not scan_stopped and start_round < num_boost_round \
                 and eval_spec:
             # remainder rounds run per-iteration but keep the TRACED
             # metric values, so the whole run's record_evals stays
@@ -346,7 +330,7 @@ def _train_impl(params: Dict[str, Any], train_set: Dataset,
                         in booster._model.valid_sets):
             booster._traced_eval = True
 
-    for i in range(start_round, num_boost_round if not chunk_stopped else 0):
+    for i in range(start_round, num_boost_round if not scan_stopped else 0):
         env = CallbackEnv(model=booster, params=params, iteration=i,
                           begin_iteration=0, end_iteration=num_boost_round,
                           evaluation_result_list=None)
@@ -452,12 +436,9 @@ def _superepoch_plan(cfg, booster, fobj, feval, cbs_before, cbs_after,
     if any(not getattr(cb, "_replayable", False) for cb in cbs_after):
         return None
     model = getattr(booster, "_model", None)
-    if model is None or not hasattr(model, "train_superepoch"):
+    if model is None or not hasattr(model, "train_superepoch") \
+            or not model.supports_fused():
         return None
-    if not model._fusable_config() or model._faults_active():
-        return None
-    if getattr(model, "_integrity", None) is not None:
-        return None       # integrity layer: per-iteration path only
     import jax
     if str(cfg.fused_eval).lower() == "false" and model.valid_sets:
         return None

@@ -718,8 +718,7 @@ def ingest_dataset(source: str, params: Optional[Dict[str, Any]] = None,
     """Stream ``source`` (file or directory of chunks) into a
     ``Dataset``: chunked ingest -> merged sketches -> BinMappers ->
     streaming binned construction.  The full raw matrix never exists in
-    memory; peak RSS is bounded by one chunk (bench.py's ``ingest``
-    extras pin this).  With ``reference`` (a validation set binned
+    memory; peak RSS is bounded by one chunk.  With ``reference`` (a validation set binned
     against the training set) the reference's mappers are reused and no
     sketches are fitted."""
     from .config import Config

@@ -354,7 +354,7 @@ class GBDTModel:
                     self._use_efb = False
         # quantized training (ROADMAP item 3, docs/Quantized-Training.md):
         # one QuantSpec threads through every learner family below —
-        # masked (strict/batched/fused-chunk), partitioned, and all
+        # masked (strict/batched/scanned), partitioned, and all
         # three distributed growers
         self._quant = None
         if config.quant_train:
@@ -569,7 +569,7 @@ class GBDTModel:
         # top-k vote is per histogram call either way), sparse-binned
         # data keeps its own total-reduction order, and the
         # partitioned learner has no slot path.  Threaded through the
-        # serial, fused-chunk, data- and feature-parallel builders;
+        # serial, scanned, data- and feature-parallel builders;
         # the flop ledger accounts the 1-slot mask as the masked pass
         # it is byte-identical to (obs/flops.hist_flops_bytes).
         self._hist_overlap = (bool(getattr(config, "hist_overlap", True))
@@ -1197,8 +1197,17 @@ class GBDTModel:
 
     def _prep_vals(self, vals: jax.Array) -> jax.Array:
         """Pad + row-shard the per-row (grad, hess, weight) stack for the
-        row-sharded learners; identity otherwise.  Padded rows carry zero
-        weight so they never contribute to histograms."""
+        row-sharded learners, replicate it for the feature-sharded one;
+        identity otherwise.  Padded rows carry zero weight so they never
+        contribute to histograms."""
+        if self._dist == "feature":
+            # round 0's stack lies on one device, uncommitted; every later
+            # one follows the score onto the mesh (the grower's replicated
+            # leaf_of_row), and jit traces the grower anew for the second
+            # placement: give both the one the program runs on
+            from jax.sharding import NamedSharding, PartitionSpec
+            return jax.device_put(
+                vals, NamedSharding(self._mesh, PartitionSpec()))
         if self._dist not in ("data", "voting"):
             return vals
         if self._row_pad:
@@ -1365,7 +1374,7 @@ class GBDTModel:
         """In-graph bagging mask (gbdt.cpp:230-264 Bagging): the draw is
         keyed by the iteration's refresh epoch ``(it // freq) * freq`` so
         the mask is identical for ``bagging_freq`` consecutive iterations
-        and identical between the per-iteration and fused-chunk paths —
+        and identical between the per-iteration and scanned paths —
         ``it`` may be a traced scan index (the GOSS pattern).  Redrawing
         per iteration instead of caching costs one [N] uniform + compare,
         noise next to a histogram pass.  ``seed`` (optional, possibly a
@@ -1398,7 +1407,7 @@ class GBDTModel:
                    seed=None) -> jax.Array:
         """GOSS (goss.hpp:20-188): keep top_rate by |grad|, sample
         other_rate of the rest, amplify their weight.  ``it`` may be a
-        traced iteration index (fused-chunk path); defaults to the host
+        traced iteration index (the scan); defaults to the host
         counter so both paths draw identical per-iteration keys.
         ``seed`` (optional, possibly traced) overrides
         ``cfg.bagging_seed`` — the fleet trainer's per-member stream."""
@@ -1481,10 +1490,10 @@ class GBDTModel:
             for _ in range(int(start_iteration)):
                 self._feature_mask()
 
-    # -- fused multi-iteration path (one host sync per chunk) ---------------
+    # -- scanned multi-iteration path (one host sync per epoch) -------------
     def _fusable_config(self) -> bool:
-        """Whether this model/objective/sampling combination has fused-path
-        semantics (independent of whether fusion is enabled) — also gates
+        """Whether this model/objective/sampling combination has scan-path
+        semantics (independent of whether the scan is enabled) — also gates
         the f32 leaf-shrinkage in train_one_iter so toggling ``fused_chunk``
         never changes the trained model."""
         cfg = self.config
@@ -1502,12 +1511,12 @@ class GBDTModel:
     def supports_fused(self) -> bool:
         """True when whole iterations can run fused on device via
         ``lax.scan``: pure-JAX gradients -> grow -> leaf-gather score
-        update, with ONE host round trip per chunk instead of ~5 per
+        update, with ONE host round trip per epoch instead of ~5 per
         iteration.  Every blocking fetch drains the dispatch queue, so
         the per-iteration path idles the device ~5 times per iteration;
         the reference's cuda_exp learner syncs once per TREE
         (cuda_single_gpu_tree_learner.cpp:108-232) — this syncs once per
-        CHUNK of trees.
+        EPOCH of trees.
 
         Active fault injection (utils/faultinject.py) forces the
         per-iteration path: host-side injection sites cannot fire inside
@@ -1516,8 +1525,7 @@ class GBDTModel:
         identical models.  The integrity layer likewise forces the
         per-iteration path: its shadow compares and transient re-runs
         are host-driven."""
-        return (self.config.fused_chunk > 1 and self._fusable_config()
-                and not self._faults_active()
+        return (self._fusable_config() and not self._faults_active()
                 and self._integrity is None)
 
     @staticmethod
@@ -1529,10 +1537,10 @@ class GBDTModel:
         """Every reason ``supports_fused()`` is False, as specific
         human-readable blockers — empty when the fused path is
         eligible.  The ``reasons()`` companion of ``supports_fused()``:
-        consumed by the ``train_chunk`` errors (which must name the
+        consumed by the ``train_superepoch`` error (which must name the
         exact objective/sampling/config condition that failed, not just
-        point back at the predicate) and recorded as provenance by the
-        benches (tools/bench_fused.py, bench.py extras)."""
+        point back at the predicate) and recorded by chip_smoke.py when
+        a run falls to the per-iteration loop."""
         cfg = self.config
         reasons: List[str] = []
         if type(self) is not GBDTModel:
@@ -1571,9 +1579,10 @@ class GBDTModel:
             reasons.append("caller-supplied hist_reduce hook")
         if self._forced_spec is not None:
             reasons.append("forced_splits need host node bookkeeping")
-        if cfg.fused_chunk <= 1:
-            reasons.append(f"fused_chunk={cfg.fused_chunk} (set > 1 to "
-                           "enable fusion)")
+        if cfg.superepoch < 0 or (cfg.superepoch == 0
+                                  and cfg.fused_chunk <= 1):
+            reasons.append(f"fused_chunk={cfg.fused_chunk}, superepoch="
+                           f"{cfg.superepoch}: no epoch size (set one > 1)")
         if self._faults_active():
             reasons.append(
                 "fault injection active: host-side injection sites "
@@ -1584,309 +1593,6 @@ class GBDTModel:
                 "layer's shadow compares and transient re-runs are "
                 "host-driven (docs/Fault-Tolerance.md layer 7)")
         return reasons
-
-    def _fused_chunk_fn(self):
-        fn = self._fused_cache.get("chunk")
-        if fn is None:
-            import functools
-            cfg = self.config
-            grow = make_grower(
-                num_leaves=cfg.num_leaves, num_bins=self.max_bin,
-                params=self.split_params, max_depth=cfg.max_depth,
-                block_rows=self._block_rows,
-                efb=self.efb_dev if self._use_efb else None,
-                gain_scale=self._feature_contri,
-                extra_trees=self._extra_trees, extra_seed=cfg.extra_seed,
-                split_batch=self._split_batch,
-                hist_overlap=self._hist_overlap,
-                mono=self._mono if self._learner_kind == "masked" else None,
-                mono_penalty=cfg.monotone_penalty,
-                interaction_groups=self._inter,
-                bynode_frac=cfg.feature_fraction_bynode,
-                bynode_seed=cfg.feature_fraction_seed + 1,
-                cegb=self._cegb_state,
-                padded_leaves=self._leaf_pad,
-                quant=self._quant,
-                jit=False)
-            obj = self.objective
-            lr = jnp.float32(self.learning_rate)
-            use_goss = self._goss
-            use_bag = self._bagging_active and not use_goss
-            ic = self._ic_grow
-            fin_freq = cfg.finite_check_freq
-            fin_policy = cfg.finite_check_policy
-
-            use_cegb = self._cegb_state is not None
-            nf = self.num_features
-
-            leaf_padded = self._leaf_pad is not None
-
-            def one_iter(carry, xs):
-                score, dead, cuse, ml = carry
-                fmask, it = xs
-                with jax.named_scope("lgbtpu.grad"):
-                    g, h = obj.get_gradients(score[:, 0])
-                if fin_freq > 0 and fin_policy == "clamp":
-                    # clamp is sync-free, so it applies every iteration
-                    g = jnp.nan_to_num(g, nan=0.0, posinf=_FINITE_CLAMP,
-                                       neginf=-_FINITE_CLAMP)
-                    h = jnp.nan_to_num(h, nan=0.0, posinf=_FINITE_CLAMP,
-                                       neginf=0.0)
-                with jax.named_scope("lgbtpu.sample"):
-                    if use_goss:
-                        w = self._goss_vals(g, h, it)
-                    elif use_bag:
-                        w = self._bagging_w(it)
-                    else:
-                        w = jnp.ones_like(g)
-                    vals = jnp.stack([g * w, h * w, w], axis=1)
-                kw = {"is_cat": ic} if ic is not None else {}
-                if self._extra_trees or self._bynode_masked \
-                        or self._quant is not None:
-                    # quant: the scan's iteration index keys the
-                    # stochastic-rounding stream, so fused and per-iter
-                    # paths quantize identically
-                    kw["rng_iter"] = it
-                if use_cegb:
-                    kw["cegb_used"] = cuse
-                if leaf_padded:
-                    # the actual budget is a chunk ARGUMENT (not a baked
-                    # constant) so the fused-chunk HLO is identical
-                    # across a num_leaves bucket — in-process the chunk
-                    # still traces per booster, but the persistent cache
-                    # recognizes the compile
-                    kw["max_leaves"] = ml
-                arrays = grow(self.binned_dev, vals, fmask,
-                              self._nb_grow, self._na_grow, **kw)
-                if use_cegb:
-                    # fold this tree's split features into the CEGB
-                    # cross-tree used set for the next scan iteration
-                    node_on = (jnp.arange(arrays.split_feature.shape[0])
-                               < arrays.num_leaves - 1)
-                    marks = jnp.zeros(nf, jnp.int32) \
-                        .at[arrays.split_feature].add(
-                            node_on.astype(jnp.int32))
-                    cuse = cuse | (marks > 0)
-                if fin_freq > 0 and fin_policy == "clamp":
-                    # clamp BEFORE shrinkage, exactly where the per-iter
-                    # path clamps its host leaf_values — an inf leaf must
-                    # become ±bound*lr on both paths
-                    lv = jnp.nan_to_num(
-                        arrays.leaf_value, nan=0.0, posinf=_FINITE_CLAMP,
-                        neginf=-_FINITE_CLAMP) * lr
-                else:
-                    lv = arrays.leaf_value * lr
-                # finite guard (fused form): ONE fused isfinite reduction
-                # over grad/hess and the new tree's leaf outputs at check
-                # iterations; the per-iteration flag ships with the tree
-                # records, so the whole chunk still costs a single host
-                # sync (the policy engages host-side in train_chunk)
-                if fin_freq > 0 and fin_policy != "clamp":
-                    check_now = ((it + 1) % fin_freq) == 0
-                    fin = (jnp.isfinite(g).all() & jnp.isfinite(h).all()
-                           & jnp.isfinite(lv).all())
-                    bad = check_now & ~fin
-                else:
-                    bad = jnp.bool_(False)
-                # per-iteration semantics stop training at the FIRST
-                # no-split tree (gbdt.cpp "no more leaves..."); once dead,
-                # later scan iterations must contribute nothing, even if a
-                # different feature mask could have split (the host loop
-                # discards their tree records)
-                ok = jnp.where(dead | bad, 0.0,
-                               (arrays.num_leaves > 1).astype(jnp.float32))
-                if fin_freq > 0 and fin_policy == "raise":
-                    # halt at the first tripped check: later iterations
-                    # contribute nothing, so the host can raise at the
-                    # flagged iteration with a consistent score/model
-                    dead = dead | (arrays.num_leaves <= 1) | bad
-                else:
-                    # skip_iter: the flagged iteration contributes a zero
-                    # stump; a NaN-induced natural stump must NOT end
-                    # training
-                    dead = dead | ((arrays.num_leaves <= 1) & ~bad)
-                from ..obs.flops import (note_traced,
-                                         score_update_flops_bytes)
-                note_traced("score",
-                            *score_update_flops_bytes(score.shape[0]),
-                            phase="score", cadence="iter")
-                with jax.named_scope("lgbtpu.score"):
-                    delta = jnp.where(ok > 0.0,
-                                      jnp.take(lv, arrays.leaf_of_row), 0.0)
-                    score = score.at[:, 0].add(delta)
-                if fin_freq > 0 and fin_policy == "skip_iter":
-                    # a tripped check heals the score carry too: a NaN
-                    # that slipped in at an UNCHECKED iteration (freq>1)
-                    # would otherwise re-poison every later gradient and
-                    # the guard would skip forever
-                    score = jnp.where(bad, jnp.nan_to_num(
-                        score, nan=0.0, posinf=_FINITE_CLAMP,
-                        neginf=-_FINITE_CLAMP), score)
-                # keep the scan outputs tree-sized: drop the [N] row->leaf
-                # vector, ship shrunk leaf values
-                out = arrays._replace(leaf_of_row=jnp.zeros((), jnp.int32),
-                                      leaf_value=lv)
-                return (score, dead, cuse, ml), (out, bad)
-
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            def chunk(score, fmasks, iters, cuse0, ml):
-                (score, _, _, _), (out, bad) = jax.lax.scan(
-                    one_iter, (score, jnp.bool_(False), cuse0, ml),
-                    (fmasks, iters))
-                return score, out, bad
-
-            fn = self._fused_cache["chunk"] = chunk
-        return fn
-
-    def train_chunk(self, k: int) -> bool:
-        """Run ``k`` boosting iterations as ONE device program + ONE host
-        fetch of the k small tree records.  Semantically identical to k
-        ``train_one_iter`` calls under ``supports_fused()`` (same RNG
-        streams: feature masks are pre-drawn host-side, GOSS keys are
-        seeded by iteration index in-graph).  Returns True when a
-        no-split iteration occurred (trailing stump repeats discarded)."""
-        if self._elastic is not None:
-            self._elastic.check_peers()      # per-chunk liveness poll
-        if self.valid_sets:
-            raise ValueError(
-                "train_chunk requires no validation sets: per-iteration "
-                "eval/early-stop runs go through train_superepoch, which "
-                "evaluates traced metrics inside the scan (engine.train "
-                "routes there automatically)")
-        if not self._fusable_config():
-            raise ValueError(
-                "train_chunk: config not fusable: "
-                + "; ".join(r for r in self.fused_reasons()
-                            if not r.startswith("fused_chunk=")))
-        cfg = self.config
-        start_iter = self.iter_
-        init0 = 0.0
-        if start_iter == 0 and self.objective is not None \
-                and cfg.boost_from_average and not self._init_applied:
-            init0 = self._boost_from_score(0)
-            self._init_scores = [init0]
-            if init0 != 0.0:
-                self.score = self.score + jnp.float32(init0)
-
-        obs = self._obs
-        if obs is not None:
-            obs.activate()
-            _sp = obs.span("train_chunk", mirror=False, n_iters=k,
-                           iteration=start_iter)
-            if obs.profiler is not None:
-                # the chunk is ONE device program: the capture window
-                # opens if any requested iteration falls inside it
-                for it in range(start_iter, start_iter + k):
-                    obs.profiler.on_iter_begin(it)
-
-        chunk = self._fused_chunk_fn()
-        if cfg.feature_fraction < 1.0:
-            fmasks = jnp.asarray(
-                np.stack([self._feature_mask() for _ in range(k)]))
-        else:
-            fmasks = jnp.ones((k, self.num_features), bool)
-        it0 = start_iter + self._iter_rng_offset
-        iters = jnp.arange(it0, it0 + k, dtype=jnp.int32)
-        cuse0 = jnp.asarray(self._cegb_state.used) \
-            if self._cegb_state is not None \
-            else jnp.zeros(1, bool)
-        self.score, stacked, bad_flags = chunk(self.score, fmasks, iters,
-                                               cuse0,
-                                               jnp.int32(cfg.num_leaves))
-        # the one sync per chunk (tree records + finite-guard flags)
-        host, bad_host = self._eget((stacked, bad_flags), "fused_fetch")
-        if obs is not None:
-            _sp.end()                  # device_get above already blocked
-            if obs.profiler is not None:
-                obs.profiler.on_iter_end(start_iter + k - 1)
-
-        lr = self.learning_rate
-        stopped = False
-        for j in range(k):
-            tj = TreeArrays(*(np.asarray(fld[j]) for fld in host))
-            nl = int(tj.num_leaves)
-            if bool(bad_host[j]):
-                from ..utils.log import Log
-                msg = ("non-finite gradient/hessian or leaf output "
-                       f"detected at iteration {it0 + j + 1} "
-                       f"(finite_check_freq={cfg.finite_check_freq})")
-                if self._bbox is not None:
-                    self._bbox.record(event="finite_check_trip",
-                                      iteration=it0 + j + 1,
-                                      policy=cfg.finite_check_policy,
-                                      fused=True)
-                    self._bbox.dump("finite_check")
-                if cfg.finite_check_policy == "raise":
-                    from ..basic import LightGBMError
-                    raise LightGBMError(
-                        msg + "; aborting (finite_check_policy=raise)")
-                # skip_iter: the iteration already contributed nothing
-                # in-graph; record a zero stump so iteration counts and
-                # model text match the per-iteration path exactly
-                Log.warning(msg + "; iteration contributes nothing "
-                                  "(finite_check_policy=skip_iter)")
-                self.step_counts.append(int(tj.n_steps))
-                ht = Tree(1)
-                ht.shrinkage = lr
-                ht.leaf_value = np.asarray(
-                    [init0 if (start_iter == 0 and j == 0) else 0.0],
-                    np.float64)
-                self.models.append(ht)
-                dev_arrays = TreeArrays(*(fld[j] for fld in stacked))
-                self.device_trees.append(_DeviceTree(
-                    dev_arrays, jnp.zeros_like(dev_arrays.leaf_value), 1))
-                self.tree_weights.append(1.0)
-                self.iter_ += 1
-                continue
-            self.step_counts.append(int(tj.n_steps))
-            lvj = np.asarray(tj.leaf_value, np.float64).copy()
-            if self._cegb_state is not None and nl > 1:
-                # mirror the in-graph CEGB used-set update on the host so
-                # the NEXT chunk starts from the right cross-tree state
-                self._cegb_state.used[
-                    np.asarray(tj.split_feature)[:nl - 1]] = True
-            if nl <= 1:
-                stopped = True
-                lvj[:] = 0.0
-            ht = Tree.from_arrays(tj, self.train_set.used_features,
-                                  self.train_set.bin_mappers)
-            ht.internal_value = ht.internal_value * lr
-            ht.shrinkage = lr
-            bias = init0 if (start_iter == 0 and j == 0) else 0.0
-            ht.leaf_value = lvj[:max(nl, 1)] + bias   # Tree::AddBias
-            self.models.append(ht)
-
-            dev_arrays = TreeArrays(*(fld[j] for fld in stacked))
-            dev_lv = dev_arrays.leaf_value if nl > 1 else \
-                jnp.zeros_like(dev_arrays.leaf_value)
-            steps = round_up_pow2(max(ht.max_depth(), 1))
-            self.device_trees.append(_DeviceTree(dev_arrays, dev_lv, steps))
-            self.tree_weights.append(1.0)
-            self.iter_ += 1
-            if stopped:
-                break
-        if obs is not None:
-            done = self.iter_ - start_iter
-            obs.metrics.counter("train.iterations").inc(done)
-            obs.metrics.counter("train.fused_chunks").inc()
-            for s in self.step_counts[len(self.step_counts) - done:]:
-                obs.metrics.histogram("train.steps_per_tree").observe(s)
-                obs.record_flops(s)
-        if self._bbox is not None:
-            done = self.iter_ - start_iter
-            rec = {"event": "fused_chunk", "iterations": done,
-                   "first_iteration": start_iter + 1,
-                   "steps": self.step_counts[len(self.step_counts)
-                                             - done:]}
-            if self._flops is not None:
-                fl = hb = 0
-                for s in rec["steps"]:
-                    f_, b_ = self._flops.per_iteration(s)
-                    fl, hb = fl + f_, hb + b_
-                rec["flops"], rec["hbm_bytes"] = fl, hb
-            self._bbox.record(**rec)
-        self._last_iter_state = None    # rollback not supported past a chunk
-        return stopped
 
     # -- super-epoch trainer: whole-run on-device boosting -----------------
 
@@ -1945,8 +1651,8 @@ class GBDTModel:
         vals: List[jax.Array] = []
         scal: List[Tuple[str, str]] = []
         for name in sorted(vars(self.objective)):
-            if name == "config":
-                continue            # keyed via Config.to_dict already
+            if name == "config" or name in self.objective.host_only_attrs:
+                continue    # config: keyed via Config.to_dict already
             v = getattr(self.objective, name)
             if isinstance(v, (jax.Array, np.ndarray)):
                 names.append(name)
@@ -2004,10 +1710,12 @@ class GBDTModel:
         over k FULL boosting iterations — gradients, grow, score update,
         valid-set traversal+scoring, traced metric eval, early-stop vote
         — with zero host syncs inside.  The per-iteration tree math is
-        the fused-chunk ``one_iter`` body verbatim (same RNG streams,
-        same finite-guard policies, same dead-gating), extended with the
-        traced eval tail; model data arrays ride as arguments so keyable
-        configs share the compile process-wide (``_SE_CACHE``).
+        ``train_one_iter``'s (same RNG streams: feature masks are
+        pre-drawn host-side, GOSS keys are seeded by iteration index
+        in-graph; same finite-guard policies), followed by the traced
+        eval tail, which is empty without valid sets; model data arrays
+        ride as arguments so no dataset is baked into the executable and
+        keyable configs share the compile process-wide (``_SE_CACHE``).
 
         ``member_args=True`` is the fleet trainer's form: the trailing
         ``mrng = (learning_rate, sampling_seed, quant_seed)`` operand
@@ -2111,6 +1819,7 @@ class GBDTModel:
                 with jax.named_scope("lgbtpu.grad"):
                     g, h = obj.get_gradients(score[:, 0])
                 if fin_freq > 0 and fin_policy == "clamp":
+                    # clamp is sync-free, so it applies every iteration
                     g = jnp.nan_to_num(g, nan=0.0, posinf=_FINITE_CLAMP,
                                        neginf=-_FINITE_CLAMP)
                     h = jnp.nan_to_num(h, nan=0.0, posinf=_FINITE_CLAMP,
@@ -2125,15 +1834,23 @@ class GBDTModel:
                     vals = jnp.stack([g * w, h * w, w], axis=1)
                 kw = {"is_cat": ic} if ic is not None else {}
                 if rng_iter_kw:
+                    # quant: the scan's iteration index keys the
+                    # stochastic-rounding stream, so scanned and per-iter
+                    # paths quantize identically
                     kw["rng_iter"] = it
                 if use_quant_seed:
                     kw["quant_seed"] = q_seed
                 if use_cegb:
                     kw["cegb_used"] = cuse
                 if leaf_padded:
+                    # the actual budget is an ARGUMENT (not a baked
+                    # constant) so the HLO is identical across a
+                    # num_leaves bucket
                     kw["max_leaves"] = ml
                 arrays = grow(binned, vals, fmask, nb, na, **kw)
                 if use_cegb:
+                    # fold this tree's split features into the CEGB
+                    # cross-tree used set for the next scan iteration
                     node_on = (jnp.arange(arrays.split_feature.shape[0])
                                < arrays.num_leaves - 1)
                     marks = jnp.zeros(nf, jnp.int32) \
@@ -2141,11 +1858,19 @@ class GBDTModel:
                             node_on.astype(jnp.int32))
                     cuse = cuse | (marks > 0)
                 if fin_freq > 0 and fin_policy == "clamp":
+                    # clamp BEFORE shrinkage, exactly where the per-iter
+                    # path clamps its host leaf_values — an inf leaf must
+                    # become ±bound*lr on both paths
                     lv = jnp.nan_to_num(
                         arrays.leaf_value, nan=0.0, posinf=_FINITE_CLAMP,
                         neginf=-_FINITE_CLAMP) * lr_
                 else:
                     lv = arrays.leaf_value * lr_
+                # finite guard: ONE fused isfinite reduction over
+                # grad/hess and the new tree's leaf outputs at check
+                # iterations; the per-iteration flag ships with the tree
+                # records, so the epoch still costs a single host sync
+                # (the policy engages host-side in _se_ingest)
                 if fin_freq > 0 and fin_policy != "clamp":
                     check_now = ((it + 1) % fin_freq) == 0
                     fin = (jnp.isfinite(g).all() & jnp.isfinite(h).all()
@@ -2153,12 +1878,23 @@ class GBDTModel:
                     bad = check_now & ~fin
                 else:
                     bad = jnp.bool_(False)
+                # per-iteration semantics stop training at the FIRST
+                # no-split tree (gbdt.cpp "no more leaves..."); once dead,
+                # later scan iterations must contribute nothing, even if a
+                # different feature mask could have split (the host loop
+                # discards their tree records)
                 ok = jnp.where(blocked | bad, 0.0,
                                (arrays.num_leaves > 1)
                                .astype(jnp.float32))
                 if fin_freq > 0 and fin_policy == "raise":
+                    # halt at the first tripped check: later iterations
+                    # contribute nothing, so the host can raise at the
+                    # flagged iteration with a consistent score/model
                     dead = dead | (arrays.num_leaves <= 1) | bad
                 else:
+                    # skip_iter: the flagged iteration contributes a zero
+                    # stump; a NaN-induced natural stump must NOT end
+                    # training
                     dead = dead | ((arrays.num_leaves <= 1) & ~bad)
                 note_traced("score",
                             *score_update_flops_bytes(score.shape[0]),
@@ -2168,6 +1904,10 @@ class GBDTModel:
                                       jnp.take(lv, arrays.leaf_of_row), 0.0)
                     score = score.at[:, 0].add(delta)
                 if fin_freq > 0 and fin_policy == "skip_iter":
+                    # a tripped check heals the score carry too: a NaN
+                    # that slipped in at an UNCHECKED iteration (freq>1)
+                    # would otherwise re-poison every later gradient and
+                    # the guard would skip forever
                     score = jnp.where(bad, jnp.nan_to_num(
                         score, nan=0.0, posinf=_FINITE_CLAMP,
                         neginf=-_FINITE_CLAMP), score)
@@ -2214,6 +1954,8 @@ class GBDTModel:
                     trip = (es_elig & ((eit - esi) >= es_rounds)
                             & ~blocked)
                     stop = stop | trip.any()
+                # keep the scan outputs tree-sized: drop the [N] row->leaf
+                # vector, ship shrunk leaf values
                 out = arrays._replace(
                     leaf_of_row=jnp.zeros((), jnp.int32), leaf_value=lv)
                 return ((score, vsc, esb, esi, esh, stop, dead, cuse,
@@ -2510,6 +2252,9 @@ class GBDTModel:
                     from ..basic import LightGBMError
                     raise LightGBMError(
                         msg + "; aborting (finite_check_policy=raise)")
+                # skip_iter: the iteration already contributed nothing
+                # in-graph; record a zero stump so iteration counts and
+                # model text match the per-iteration path exactly
                 Log.warning(msg + "; iteration contributes nothing "
                                   "(finite_check_policy=skip_iter)")
                 self.step_counts.append(int(tj.n_steps))
@@ -2532,6 +2277,8 @@ class GBDTModel:
             self.step_counts.append(int(tj.n_steps))
             lvj = np.asarray(tj.leaf_value, np.float64).copy()
             if self._cegb_state is not None and nl > 1:
+                # mirror the in-graph CEGB used-set update on the host so
+                # the NEXT epoch starts from the right cross-tree state
                 self._cegb_state.used[
                     np.asarray(tj.split_feature)[:nl - 1]] = True
             if nl <= 1:
@@ -2542,7 +2289,7 @@ class GBDTModel:
             ht.internal_value = ht.internal_value * lr
             ht.shrinkage = lr
             bias = init0 if (start_iter == 0 and j == 0) else 0.0
-            ht.leaf_value = lvj[:max(nl, 1)] + bias
+            ht.leaf_value = lvj[:max(nl, 1)] + bias   # Tree::AddBias
             self.models.append(ht)
 
             dev_arrays = TreeArrays(*(fld[j] for fld in stacked))
@@ -2577,7 +2324,7 @@ class GBDTModel:
                     fl, hb = fl + f_, hb + b_
                 rec["flops"], rec["hbm_bytes"] = fl, hb
             self._bbox.record(**rec)
-        self._last_iter_state = None
+        self._last_iter_state = None   # rollback not supported past an epoch
         return {"evals": np.asarray(ev_host, np.float32).reshape(k, E),
                 "done": done, "stump": stopped, "stop_row": stop_row}
 
@@ -2902,7 +2649,7 @@ class GBDTModel:
             if self._fusable_config():
                 # shrink with f32 semantics (an exact f64 product of f32
                 # operands rounded back to f32 equals the hardware f32
-                # multiply) so the fused-chunk path, which shrinks on
+                # multiply) so the scanned path, which shrinks on
                 # device, yields bit-identical leaf values and scores
                 leaf_values = (leaf_values
                                * np.float64(np.float32(shrinkage))
@@ -3055,8 +2802,8 @@ class GBDTModel:
                 from ..utils.log import Log
                 Log.warning(
                     "rollback_one_iter: no per-iteration state to roll "
-                    "back (last iterations ran as a fused chunk; set "
-                    "fused_chunk=0 if rollback is needed)")
+                    "back (last iterations ran inside a scan; set "
+                    "superepoch=-1 if rollback is needed)")
             return
         st = self._last_iter_state
         for k in range(self.num_class):

@@ -34,8 +34,12 @@ class ObjectiveFunction:
     need_renew_tree_output = False
     # True when get_gradients advances host-side state per call (e.g. a
     # host RNG counter): such objectives cannot be traced once and scanned
-    # (the fused-chunk path would freeze one draw for all iterations)
+    # (the scan would freeze one draw for all iterations)
     host_state_per_iter = False
+    # scalar attributes that only the host reads (boost_from_score's
+    # statistics), never get_gradients: the scanned program's sharing key
+    # leaves them out, so two datasets of one shape share one program
+    host_only_attrs: Tuple[str, ...] = ()
 
     def __init__(self, config: Config):
         self.config = config
@@ -247,6 +251,7 @@ class RegressionTweedie(ObjectiveFunction):
 
 class BinaryLogloss(ObjectiveFunction):
     name = "binary"
+    host_only_attrs = ("_cnt_pos", "_cnt_neg")
 
     def __init__(self, config):
         super().__init__(config)

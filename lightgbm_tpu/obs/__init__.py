@@ -106,7 +106,7 @@ class ObsSession:
     # -- spans -------------------------------------------------------------
     def span(self, name: str, mirror: bool = True, **args) -> Span:
         """Open ``lgbtpu.<name>`` on the tracer and, unless it encloses
-        other spans' whole job (``iter``, a fused chunk, a super-epoch:
+        other spans' whole job (``iter``, a super-epoch:
         ``mirror=False``), on the profiler's host plane.  Close it
         through ``end_setup`` / ``end_phase`` / ``end_eval`` so that the
         registry sees it too."""

@@ -10,14 +10,14 @@ already exist separately:
   recorded per iteration by ``ObsSession.record_flops``),
 - ``train.phase_seconds{phase=...}`` histograms (fenced spans: wall
   time attributed to the phase that queued the work),
-- the peak table below (extending the one bench.py used to carry
-  privately, with HBM bandwidth added so the roofline has both axes).
+- the peak table below (FLOP/s and HBM bandwidth, so the roofline has
+  both axes).
 
 ``perf_summary`` is a pure function of a metrics snapshot, so the
 static keys (flops, hbm_bytes) inherit the snapshot's dp == serial
 determinism and the whole join is unit-testable without a device.
-Surfaced in ``Booster.telemetry_snapshot()``, the serve ``/metrics``
-endpoint and bench points.
+Surfaced in ``Booster.telemetry_snapshot()`` and the serve ``/metrics``
+endpoint.
 """
 
 from __future__ import annotations
@@ -26,8 +26,7 @@ import re
 from typing import Dict, Optional, Tuple
 
 # bf16/f32 MXU peak FLOP/s and HBM bandwidth (bytes/s) per chip, by
-# device-kind substring.  FLOP/s column == the table bench.py shipped;
-# bandwidth from the public TPU system specs (v4 1228 GB/s, v5e
+# device-kind substring.  Bandwidth from the public TPU system specs (v4 1228 GB/s, v5e
 # 819 GB/s, v5p 2765 GB/s, v6e 1640 GB/s).  Unknown kinds report raw
 # FLOP/s with no MFU/verdict — or the caller pins peaks via the
 # ``telemetry_peak_flops`` / ``telemetry_peak_hbm_gbs`` params.

@@ -136,7 +136,7 @@ def dp_hist_bytes_per_iter(n_shards: int, chunk: int, padded_bins: int,
     """Closed-form wire-byte estimate for the data-parallel owner-shard
     histogram reduce-scatter over one iteration — the PR 1 per-shard
     hist-bytes math (``OwnerShardPlan.hist_bytes``) times the reduce
-    cadence, usable without building a mesh (bench.py extras).  The
+    cadence, usable without building a mesh.  The
     scattered tensor per step is ``[n_shards * chunk * split_batch,
     padded_bins, 3]`` at ``itemsize``-byte lanes: f32 for the default
     path, int32 for quantized training (quant_train) — 4 bytes either

@@ -31,8 +31,7 @@ run, not because the constants are exact):
 
 - histogram: 2 FLOPs per multiply-add of the one-hot contraction —
   ``2 * C * N * F * Bp`` per full-N pass (the MXU useful work; padded
-  bins included because the hardware computes them).  This is exactly
-  the formula ``bench.py`` used to carry privately.
+  bins included because the hardware computes them).
 - split scan / partition / traversal: elementwise-op estimates with
   per-cell constants documented at each formula.
 
@@ -284,9 +283,8 @@ def fused_forest_flops_bytes(n_rows: int, n_trees: int, steps: int,
 def train_hist_flops_per_iter(n_rows: int, n_feat: int, num_bins: int,
                               num_leaves: int) -> float:
     """Useful histogram FLOPs per boosting iteration: one C=3 full-N
-    contraction per smaller-child pass, (num_leaves - 1) passes/tree —
-    the headline number bench.py reports (its former private
-    ``_hist_flops_per_iter``, now derived from the shared formula)."""
+    contraction per smaller-child pass, (num_leaves - 1) passes/tree,
+    derived from the shared formula."""
     f, _ = hist_flops_bytes(n_rows, n_feat, num_bins, channels=3)
     return float(f) * (int(num_leaves) - 1)
 

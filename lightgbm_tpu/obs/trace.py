@@ -6,8 +6,7 @@ runtime wall-clock scopes lie unless each span's device work is fenced.
 ``fence()`` below fences with a ``jax.device_get`` of a value *derived
 from* the work being timed: a fetch is a sync that cannot complete
 before its producer does, whatever the backend's dispatch model; every
-hand-rolled copy of it (tools/profile_iter.py, bench_hist.py) should go
-through here.
+timed span should go through here rather than a hand-rolled copy.
 
 Event model: spans are Chrome-trace "complete" events (``ph": "X"``)
 with microsecond ``ts``/``dur`` on the monotonic clock, written one

@@ -6,7 +6,7 @@ one C=3K one-hot contraction) and the row-block size of the
 ``lax.scan`` (``hist_block_rows``'s budget heuristic, a number measured
 once on one v5e and hard-coded since).  Neither is knowable from shapes
 alone — the measured sweet spot moved between CPU and TPU and between
-f32 and int8 operands (tools/bench_hist.py history) — so this module
+f32 and int8 operands — so this module
 measures instead of guessing:
 
 - **one-shot sweep** (:func:`tune`): time the SHIPPED
@@ -137,8 +137,8 @@ def _block_candidates(n_cols: int, num_bins: int, itemsize: int,
 def _measure_ms(binned, vals, slot, k: int, block_rows: int,
                 num_bins: int, reps: int) -> float:
     """Wall ms of one slotted pass, amortized over ``reps`` in-graph
-    repetitions (one dispatch and one fetch per measurement, as in
-    tools/bench_hist.py) and closed with ``obs.trace.fence``."""
+    repetitions (one dispatch and one fetch per measurement) and closed
+    with ``obs.trace.fence``."""
     import time
 
     import jax

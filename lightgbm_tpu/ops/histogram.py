@@ -56,7 +56,7 @@ from jax import lax
 
 
 # Cap on the row-block (lax.scan chunk) size for the histogram pass.
-# Measured on TPU v5e (tools/bench_hist.py, 1M x 28 x 63 bins): with the
+# Measured on TPU v5e (1M x 28 x 63 bins): with the
 # [C, rows] x [rows, F*B] orientation below, 8192-row blocks run ~1.8x
 # faster than VMEM-sized 888-row blocks — XLA tiles the one-hot
 # internally, so second-guessing VMEM only shrank the matmuls.

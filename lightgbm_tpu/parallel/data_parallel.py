@@ -25,9 +25,8 @@ TPU-native redesign of the reference DataParallelTreeLearner
 
 ``owner_shard=False`` restores the previous design — ONE full-tensor
 ``lax.psum`` of ``[3, F, B]`` with the split decision recomputed
-replicated on every shard — kept for A/B benchmarking
-(tools/bench_hist.py --sharded) and as a config escape hatch
-(``dp_owner_shard=false``).
+replicated on every shard — kept for A/B comparison and as a config
+escape hatch (``dp_owner_shard=false``).
 
 The same grower program (grower.py) is used for both — distribution is a
 ``shard_map`` wrapper plus reduce/expand/select hooks, not a separate

@@ -48,7 +48,7 @@ _FRAME_VERSION = 1
 _HEADER_LEN = len(_FRAME_MAGIC) + 2 + 8 + 32
 
 # running count of payload bytes this process has allgathered for
-# binning — bench.py's ``binning_wire_bytes`` extra reads it
+# binning
 _WIRE_BYTES = {"sent": 0}
 
 

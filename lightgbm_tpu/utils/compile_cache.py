@@ -19,10 +19,10 @@ making compile time a managed, *measured* resource:
    warm-start observable instead of assumed: process-global counters of
    backend compiles / persistent-cache hits+misses / compile seconds
    (fed by ``jax.monitoring``), and named trace counters bumped at
-   trace time by the library's jitted entry points (grower, fused
-   chunk, traversal, forest).  Surfaced through
-   ``Booster.telemetry_snapshot()``, the serve ``/metrics`` endpoint,
-   ``bench.py`` records, and pinned by tools/check_retraces.py.
+   trace time by the library's jitted entry points (grower, super-epoch,
+   traversal, forest).  Surfaced through
+   ``Booster.telemetry_snapshot()`` and the serve ``/metrics`` endpoint,
+   and pinned by tools/check_retraces.py.
 """
 
 from __future__ import annotations

@@ -221,7 +221,7 @@ def clear() -> None:
 
 def enabled() -> bool:
     """Whether ANY site is armed (used to gate zero-cost fast paths,
-    e.g. the fused-chunk program which cannot host per-iteration
+    e.g. the scanned program which cannot host per-iteration
     injection)."""
     return bool(_spec)
 

@@ -324,8 +324,8 @@ class TestFiniteGuard:
         assert np.isfinite(np.concatenate(
             [t.leaf_value for t in bst.trees])).all()
 
-    # -- fused-chunk compatibility (the guard flags ride the one host
-    #    sync per chunk) — NaN is seeded into the device score because
+    # -- scan compatibility (the guard flags ride the one host
+    #    sync per epoch) — NaN is seeded into the device score because
     #    labels are AvoidInf-sanitized at ingestion ------------------------
     FUSED = dict(BASE, tpu_learner="masked", boost_from_average=False,
                  finite_check_freq=1)
@@ -340,16 +340,16 @@ class TestFiniteGuard:
 
     def test_fused_raise(self):
         bst = self._poisoned("raise", 8)
-        assert bst.supports_fused()
+        assert bst._model.supports_fused()
         with pytest.raises(LightGBMError, match="iteration 1"):
-            bst.update_chunk(8)
+            bst.update_superepoch(8, 0)
 
     def test_fused_skip_iter_stumps_then_heals(self):
         # iteration 1 trips the check -> zero stump AND the score carry
         # is sanitized, so iterations 2..8 recover and train real trees
         bst = self._poisoned("skip_iter", 8)
-        stopped = bst.update_chunk(8)
-        assert not stopped
+        out = bst.update_superepoch(8, 0)
+        assert out["done"] == 8 and not out["stump"]
         leaves = [t.num_leaves for t in bst.trees]
         assert leaves[0] == 1 and float(bst.trees[0].leaf_value[0]) == 0.0
         assert all(nl > 1 for nl in leaves[1:])
@@ -365,7 +365,7 @@ class TestFiniteGuard:
 
     def test_fused_clamp_matches_per_iteration_clamp(self):
         bf = self._poisoned("clamp", 8)
-        bf.update_chunk(8)
+        bf.update_superepoch(8, 0)
         bp = self._poisoned("clamp", 0)
         for _ in range(8):
             bp.update()

@@ -1,8 +1,10 @@
-"""Fused-chunk training parity: k train_one_iter calls == one train_chunk(k).
+"""Scan parity without a valid set: k train_one_iter calls == one scan of k.
 
-The fused path (GBDTModel.train_chunk) must produce byte-identical model
-strings to the per-iteration path — same grower, same RNG streams (feature
-masks pre-drawn host-side, GOSS keys seeded by iteration index in-graph).
+The super-epoch with an empty eval tail (GBDTModel.train_superepoch, which
+``lgb.train`` takes when ``fused_chunk`` > 1) must produce byte-identical
+model strings to the per-iteration path — same grower, same RNG streams
+(feature masks pre-drawn host-side, GOSS keys seeded by iteration index
+in-graph).
 """
 
 import jax.numpy as jnp
@@ -92,8 +94,8 @@ def test_fused_mid_chunk_stump_parity():
 
 
 def test_fused_respects_remainder():
-    # rounds not divisible by the chunk: remainder runs per-iter, total
-    # tree count must still be exact
+    # rounds not divisible by the epoch: the remainder is a shorter scan,
+    # total tree count must still be exact
     x, y = _data()
     b = _train(dict(BASE, fused_chunk=10), x, y, rounds=17)
     assert len(b.trees) == 17
@@ -101,8 +103,8 @@ def test_fused_respects_remainder():
 
 def test_fused_bagging_parity():
     # bagging masks are drawn IN-GRAPH keyed by the refresh epoch
-    # (gbdt.cpp:230-264 analog), so bagging configs fuse and the fused
-    # chunk reproduces the per-iteration models exactly
+    # (gbdt.cpp:230-264 analog), so bagging configs scan and the scan
+    # reproduces the per-iteration models exactly
     x, y = _data()
     p = dict(BASE, bagging_freq=2, bagging_fraction=0.7)
     b_fused = _train(dict(p, fused_chunk=6), x, y, rounds=12)

@@ -212,7 +212,7 @@ class TestIngestE2E:
     def test_bounded_residency_one_chunk_in_flight(self, tmp_path):
         # the bounded-memory contract, structurally: however many chunks
         # the spool holds, the sequence keeps at most ONE decoded — RSS
-        # cannot scale with chunk count (bench.py gates the measured MB)
+        # cannot scale with chunk count
         x, y = _toy(n=2000, decimals=1)
         path = str(tmp_path / "train.csv")
         _write_csv(path, x, y, fmt="%.1f")

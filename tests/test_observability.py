@@ -282,7 +282,7 @@ class TestMetrics:
         # boost-from-average runs in the first iteration alone
         assert snap["train.phase_seconds{phase=init_score}"]["count"] == 1
 
-    def test_fused_chunk_counts_iterations(self):
+    def test_scan_counts_iterations(self):
         x, y = _small_data(2400)
         base = {"objective": "binary", "num_leaves": 7,
                 "min_data_in_leaf": 5, "verbosity": 0, "max_bin": 31,
@@ -291,11 +291,11 @@ class TestMetrics:
         ds = lgb.Dataset(x, label=y, params=base)
         ds.construct()
         bst = lgb.Booster(params=base, train_set=ds)
-        assert bst.supports_fused()
-        bst.update_chunk(4)
+        assert bst._model.supports_fused()
+        bst.update_superepoch(4, 0)
         snap = bst.telemetry_snapshot()
         assert snap["train.iterations"]["value"] == 4.0
-        assert snap["train.fused_chunks"]["value"] == 1.0
+        assert snap["train.superepochs"]["value"] == 1.0
         assert snap["train.steps_per_tree"]["count"] == 4
 
 
@@ -334,7 +334,7 @@ class TestComm:
     def test_dp_counters_match_owner_shard_hist_math(self):
         """comm.payload_bytes{site=dp.hist_reduce} per pass equals
         n_shards x OwnerShardPlan.hist_bytes(1, B) — the PR 1 per-shard
-        histogram byte math (bench.py extras / mesh.owner_shard_plan),
+        histogram byte math (mesh.owner_shard_plan),
         observed in-flight via the telemetry counters."""
         import jax
         if len(jax.devices()) < 8:

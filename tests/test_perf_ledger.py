@@ -1,4 +1,4 @@
-"""Perf ledger + flight recorder + bench regression gate (ISSUE 9).
+"""Perf ledger + flight recorder (ISSUE 9).
 
 - FlopLedger formulas vs brute-force op counts on tiny shapes;
 - trace-time site registration (obs/flops.note_traced) agrees with the
@@ -9,17 +9,12 @@
 - flight recorder: JSONL dump of the last-K ring on an injected
   nan_grads fault, watchdog-fire dump, serve batch-failure dump,
   zero-cost (no ring, no file) when disabled;
-- tools/bench_diff.py: green on identical pairs, nonzero on a
-  synthetically regressed pair, stale-pin detection, --update re-pin
-  (subprocess, the test_zretrace lint mold);
 - Prometheus text exposition of the metrics snapshot + the serve
   ``/metrics?format=prom`` endpoint.
 """
 
 import json
 import os
-import subprocess
-import sys
 import time
 import urllib.request
 
@@ -33,11 +28,6 @@ from lightgbm_tpu.obs.flops import (FlopLedger, hist_flops_bytes,
                                     split_scan_flops_bytes,
                                     traced_sites,
                                     train_hist_flops_per_iter)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_DIFF = os.path.join(REPO, "tools", "bench_diff.py")
-
-sys.path.insert(0, os.path.join(REPO, "tools"))
 
 
 def _small_data(n=1200, f=8, seed=3):
@@ -112,7 +102,6 @@ class TestFlopFormulas:
         assert pb == 16 * n + 3 * n * 4
 
     def test_train_hist_flops_per_iter_is_the_bench_formula(self):
-        # the formula bench.py used to carry privately:
         # 2 * 3 * n * F * Bp * (leaves - 1)
         assert train_hist_flops_per_iter(1000, 28, 63, 31) == \
             2.0 * 3 * 1000 * 28 * 64 * 30
@@ -312,90 +301,6 @@ class TestFlightRecorder:
         events = read_jsonl(path)
         assert events[0]["reason"] == "serve_batch_failure"
         assert any(r.get("event") == "batch_error" for r in events[1:])
-
-
-# -- bench_diff perf gate ---------------------------------------------------
-
-def _bench_rec(value=100.0, extra=None):
-    return {"metric": "higgs1m_binary_train_iters_per_sec",
-            "value": value, "unit": "iters/s", "vs_baseline": 1.0,
-            "extra": {"serve_p99_ms": 5.0} if extra is None else extra}
-
-
-class TestBenchDiff:
-    def _run(self, *args, timeout=120):
-        return subprocess.run([sys.executable, BENCH_DIFF, *args],
-                              capture_output=True, text=True,
-                              timeout=timeout, cwd=REPO)
-
-    def _files(self, tmp_path, old, new, budget_text):
-        op, np_, bp = (str(tmp_path / n)
-                       for n in ("old.json", "new.json", "budget.txt"))
-        with open(op, "w") as f:
-            json.dump(old, f)
-        with open(np_, "w") as f:
-            json.dump(new, f)
-        with open(bp, "w") as f:
-            f.write(budget_text)
-        return op, np_, bp
-
-    BUDGET = "value = higher 0.1\nserve_p99_ms = lower 0.2\n"
-
-    def test_identical_pair_is_green(self, tmp_path):
-        op, np_, bp = self._files(tmp_path, _bench_rec(), _bench_rec(),
-                                  self.BUDGET)
-        out = self._run(np_, op, "--budget", bp)
-        assert out.returncode == 0, out.stdout + out.stderr
-        assert "perf gate: clean" in out.stdout
-
-    def test_regressed_pair_exits_nonzero(self, tmp_path):
-        op, np_, bp = self._files(
-            tmp_path, _bench_rec(100.0),
-            _bench_rec(80.0, extra={"serve_p99_ms": 9.0}), self.BUDGET)
-        out = self._run(np_, op, "--budget", bp)
-        assert out.returncode == 1
-        assert "regression: value" in out.stderr
-        assert "regression: serve_p99_ms" in out.stderr
-
-    def test_within_tolerance_noise_passes(self, tmp_path):
-        op, np_, bp = self._files(
-            tmp_path, _bench_rec(100.0),
-            _bench_rec(91.0, extra={"serve_p99_ms": 5.9}), self.BUDGET)
-        out = self._run(np_, op, "--budget", bp)
-        assert out.returncode == 0, out.stderr
-
-    def test_stale_pin_and_disappeared_metric(self, tmp_path):
-        op, np_, bp = self._files(
-            tmp_path, _bench_rec(), _bench_rec(extra={}),
-            self.BUDGET + "ghost_metric = higher 0.1\n")
-        out = self._run(np_, op, "--budget", bp)
-        assert out.returncode == 1
-        assert "stale budget entry" in out.stderr
-        assert "metric disappeared: serve_p99_ms" in out.stderr
-
-    def test_update_repins_and_goes_green(self, tmp_path):
-        rec = _bench_rec(
-            120.0, extra={"serve_p99_ms": 4.0, "serve_rows_per_s": 9e4,
-                          "higgs1m_255leaf_iters_per_sec": 2.5,
-                          "higgs1m_255leaf_auc": 0.97})
-        op, np_, bp = self._files(tmp_path, rec, rec, self.BUDGET)
-        out = self._run(np_, "--budget", bp, "--update")
-        assert out.returncode == 0, out.stderr
-        from bench_diff import load_budget
-        pins = load_budget(bp)
-        assert pins["value"] == ("higher", 0.1)          # kept
-        assert pins["serve_p99_ms"] == ("lower", 0.2)    # kept
-        assert pins["serve_rows_per_s"][0] == "higher"   # auto-added
-        assert pins["higgs1m_255leaf_iters_per_sec"][0] == "higher"
-        assert "higgs1m_255leaf_auc" not in pins         # not gateable
-        out = self._run(np_, op, "--budget", bp)
-        assert out.returncode == 0, out.stderr
-
-    def test_shipped_budget_parses_and_pins_the_primary(self):
-        from bench_diff import BUDGET as REAL, load_budget
-        pins = load_budget(REAL)
-        assert pins.get("value", ("", 0))[0] == "higher"
-        assert any(d == "lower" for d, _ in pins.values())
 
 
 # -- Prometheus exposition --------------------------------------------------

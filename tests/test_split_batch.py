@@ -98,14 +98,14 @@ def test_batched_exhausts_splits_like_strict(data):
         assert sorted(refs) == list(range(1, nn))
 
 
-def test_reset_parameter_invalidates_fused_chunk(data):
-    """reset_parameter must retrace the fused chunk program — the old
+def test_reset_parameter_invalidates_scan_program(data):
+    """reset_parameter must retrace the scanned program — the old
     jitted closure has the previous learning rate baked in."""
     x, y = data
     ds = lgb.Dataset(x, label=y, params={"max_bin": 63})
     bst = lgb.train(_params(4, fused_chunk=5), ds, num_boost_round=5)
     bst.reset_parameter({"learning_rate": 0.77})
-    bst.update_chunk(5)          # must NOT reuse the lr=0.1 jitted chunk
+    bst.update_superepoch(5, 5)  # must NOT reuse the lr=0.1 jitted scan
     shr = {t.shrinkage for t in bst.trees}
     assert 0.77 in shr and 0.1 in shr
     # device score must agree with the host trees' raw predictions

@@ -71,8 +71,7 @@ def _run(params, rounds=40, n_valid=1, es=5, seed=7, n_class=1,
                               reference=dtr))
         vn.append(f"v{vi}")
     rec = {}
-    # always present: a replay-safe callback keeps the plain fused-chunk
-    # loop out of the way so the super-epoch path is what's exercised
+    # always present: a replay-safe callback, which the plan must admit
     cbs = [lgb.record_evaluation(rec)]
     if es and n_valid:
         cbs.append(lgb.early_stopping(es, verbose=False))
@@ -132,7 +131,7 @@ def test_superepoch_explicit_k():
 
 def test_superepoch_no_valid_sets():
     # no valid sets + a replayable callback: epochs run with an empty
-    # eval_spec (the plain fused-chunk loop is blocked by the callback)
+    # eval_spec
     pa = dict(BASE, fused_chunk=8)
     pb = dict(BASE, fused_chunk=0, superepoch=-1)
     ba, _, na = _run(pa, es=0, n_valid=0, rounds=24)
@@ -233,3 +232,81 @@ def test_unfusable_superepoch_error_names_blocker():
                     keep_training_booster=True)
     with pytest.raises(ValueError, match="num_class"):
         bst._model.train_superepoch(4, 0)
+
+
+# -- a run without a valid set is all super-epochs (PR 32) -----------------
+
+NOVALID = dict(BASE, fused_chunk=8, metric="None")
+
+
+def _train_novalid(seed, params=NOVALID, rounds=8):
+    x, y = _data(n=1600, seed=seed)
+    return lgb.train(dict(params), lgb.Dataset(x, label=y),
+                     num_boost_round=rounds)
+
+
+def test_train_without_valid_set_compiles_once_for_two_datasets():
+    # the scanned program takes the dataset as an argument and is shared
+    # process-wide, so a second call on other data of the same shape
+    # traces nothing and compiles nothing that the cache does not hold
+    from lightgbm_tpu.utils.compile_cache import (compile_stats,
+                                                  trace_counts)
+    _train_novalid(seed=11)
+    traces, comp = trace_counts(), compile_stats()
+    assert traces.get("superepoch", 0) >= 1
+    bst = _train_novalid(seed=12)
+    assert bst.num_trees() == 8
+    assert trace_counts() == traces
+    after = compile_stats()
+    assert after["cache_misses"] == comp["cache_misses"]
+    assert after["count"] - comp["count"] \
+        == after["cache_hits"] - comp["cache_hits"]
+
+
+def test_scanned_program_holds_no_dataset_constant(monkeypatch):
+    # lower the program lgb.train dispatches and hold every constant of
+    # its HLO under the binned matrix's N x F bytes: a dataset baked into
+    # the executable makes every call compile anew
+    import re
+
+    import jax
+    from lightgbm_tpu.models import gbdt
+    dispatched = []
+    build = GBDTModel._build_superepoch
+
+    def recording_build(self, *a, **k):
+        fn = build(self, *a, **k)
+
+        def call(*args):
+            dispatched.append((fn, jax.tree.map(
+                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), args)))
+            return fn(*args)
+        return call
+    monkeypatch.setattr(GBDTModel, "_build_superepoch", recording_build)
+    monkeypatch.setattr(gbdt, "_SE_CACHE", type(gbdt._SE_CACHE)())
+    bst = _train_novalid(seed=13)
+    assert len(dispatched) == 1, "lgb.train dispatched no scanned program"
+    fn, shapes = dispatched[0]
+    n_rows, n_feat = bst._model.binned_dev.shape
+    assert (n_rows, n_feat) == (1600, 12)
+    text = fn.lower(*shapes).as_text()
+    sizes = []          # bytes of every constant: tensor<2x3xui8>, <f32>
+    for m in re.finditer(
+            r"stablehlo\.constant[^\n]*?: tensor<((?:\d+x)*)[a-z]+(\d+)>",
+            text):
+        dims = [int(d) for d in m.group(1).split("x")[:-1]]
+        sizes.append(int(np.prod(dims)) * max(int(m.group(2)) // 8, 1))
+    assert sizes and max(sizes) < n_rows * n_feat, max(sizes)
+
+
+def test_run_without_valid_set_is_all_superepochs():
+    bst = _train_novalid(seed=14, params=dict(NOVALID, telemetry=True),
+                         rounds=24)
+    snap = bst.telemetry_snapshot()
+    assert snap["train.superepochs"]["value"] == 3.0
+    assert snap["train.iterations"]["value"] == 24.0
+    assert not [key for key in snap if "chunk" in key], snap.keys()
+    plain = _train_novalid(seed=14, params=dict(NOVALID, telemetry=True,
+                                                superepoch=-1), rounds=24)
+    assert "train.superepochs" not in plain.telemetry_snapshot()
+    assert _norm(bst.model_to_string()) == _norm(plain.model_to_string())

@@ -369,7 +369,7 @@ class TestPurityLintTamper:
 
     def test_traced_reachability_covers_the_hot_paths(self):
         """The reachable-function inference must cover the grower, the
-        fused chunk, the forest walk and the fused serve program — the
+        super-epoch scan, the forest walk and the fused serve program — the
         bodies the issue names; an indexing regression that loses them
         would green-wash the whole pass."""
         from analyze import check_purity
@@ -377,7 +377,7 @@ class TestPurityLintTamper:
         for needle in (
                 "lightgbm_tpu/grower.py:make_grower.grow_tree",
                 "lightgbm_tpu/models/gbdt.py:"
-                "GBDTModel._fused_chunk_fn.chunk",
+                "GBDTModel._build_superepoch_body.sepoch_body.one_iter",
                 "lightgbm_tpu/predict_device.py:_forest_walk",
                 "lightgbm_tpu/predict_device.py:fused_forest_predict",
                 "lightgbm_tpu/ops/histogram.py:compute_histogram",

@@ -24,7 +24,9 @@ verifier):
 2. **Reachability.**  From the roots, any name referenced in a
    reachable function that resolves to a package-internal function
    (direct call, ``lax.fori_loop``/``scan``/``cond`` callback, nested
-   closure) is reachable too.
+   closure) is reachable too; so is what a local name stands for: a
+   method it aliases, or the inner functions of the builder whose
+   result it holds.
 3. **Findings** inside reachable functions: ``np.*`` calls (dtype
    constructors and ``iinfo``/``finfo`` excepted), ``time.*`` /
    ``random.*`` / ``np.random.*`` / ``os.*`` / ``open`` / ``print``
@@ -145,21 +147,54 @@ def _jit_arg_name(arg: ast.AST) -> Optional[str]:
     return None
 
 
-def _scope_defs(body) -> List[ast.AST]:
-    """Function/class definitions belonging to this scope: descends
-    into compound statements (if/for/while/with/try) but not into
-    nested functions or classes — those open scopes of their own."""
-    out: List[ast.AST] = []
+_DEFS = (ast.FunctionDef, ast.AsyncFunctionDef, ast.ClassDef)
+
+
+def _scope_stmts(body):
+    """Statements belonging to this scope: descends into compound
+    statements (if/for/while/with/try) but not into nested functions or
+    classes — those are yielded, and open scopes of their own."""
     stack = list(body)
     while stack:
         n = stack.pop()
-        if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef,
-                          ast.ClassDef)):
-            out.append(n)
-            continue
-        for child in ast.iter_child_nodes(n):
-            if isinstance(child, (ast.stmt, ast.excepthandler)):
-                stack.append(child)
+        yield n
+        if not isinstance(n, _DEFS):
+            stack.extend(
+                child for child in ast.iter_child_nodes(n)
+                if isinstance(child, (ast.stmt, ast.excepthandler)))
+
+
+def _scope_defs(body) -> List[ast.AST]:
+    """Function/class definitions belonging to this scope."""
+    return [n for n in _scope_stmts(body) if isinstance(n, _DEFS)]
+
+
+def _scope_bindings(body) -> Dict[str, List[Tuple[str, str]]]:
+    """``name = value`` statements of this scope (not of nested defs),
+    as what the name may stand for: ``("alias", "self.f")`` for ``name =
+    self.f`` (either arm of a conditional too), ``("result", "self.build")``
+    for ``name = self.build(...)``, the value a builder hands back."""
+    def ref(node: ast.AST) -> Optional[str]:
+        if isinstance(node, ast.Name):
+            return node.id
+        if isinstance(node, ast.Attribute) \
+                and isinstance(node.value, ast.Name):
+            return f"{node.value.id}.{node.attr}"
+        return None
+
+    def refs(value: ast.AST) -> List[Tuple[str, str]]:
+        if isinstance(value, ast.IfExp):
+            return refs(value.body) + refs(value.orelse)
+        kind, node = ("result", value.func) \
+            if isinstance(value, ast.Call) else ("alias", value)
+        name = ref(node)
+        return [(kind, name)] if name is not None else []
+
+    out: Dict[str, List[Tuple[str, str]]] = {}
+    for n in _scope_stmts(body):
+        if isinstance(n, ast.Assign) and len(n.targets) == 1 \
+                and isinstance(n.targets[0], ast.Name):
+            out.setdefault(n.targets[0].id, []).extend(refs(n.value))
     return out
 
 
@@ -216,6 +251,13 @@ def _index_module(idx: _Index, root: str, path: str) -> None:
         for n in defs:
             if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 local[n.name] = ("func", rel, f"{prefix}{n.name}")
+        if prefix:
+            # a function's local names that stand for package functions:
+            # the closure a jitted body calls through (``body =
+            # self._build_body(...)``; ``draw = self._draw if on else None``)
+            for name, bound in _scope_bindings(body).items():
+                if bound:
+                    local.setdefault(name, ("bound", bound))
         for n in defs:
             if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 qual = f"{prefix}{n.name}"
@@ -342,6 +384,22 @@ def _reachable(idx: _Index) -> Dict[Tuple[str, str], _Func]:
             got = _lookup(idx, fn.rel, fn.env, fn.cls, name)
             if got is not None and (got.rel, got.qual) not in seen:
                 work.append(got)
+            e = fn.env.get(name)
+            if e is not None and e[0] == "bound":
+                for kind, ref in e[1]:
+                    tgt = _lookup(idx, fn.rel, fn.env, fn.cls, ref)
+                    if tgt is None:
+                        continue
+                    if kind == "alias":
+                        work.append(tgt)
+                        continue
+                    # a builder's result: what runs under the trace are
+                    # the functions it defines, not its own host code
+                    inner = tgt.qual + "."
+                    work.extend(
+                        f for (r, q), f in idx.by_key.items()
+                        if r == tgt.rel and q.startswith(inner)
+                        and "." not in q[len(inner):])
     return seen
 
 

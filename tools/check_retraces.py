@@ -15,7 +15,7 @@ structurally:
   time — cache-state-independent, so the counts are deterministic for
   a fixed code + config matrix;
 - the canonical matrix below (leaf-budget sweep, bagging/GOSS
-  sampling, two valid-set sizes, fused chunks, serve batch mix) runs
+  sampling, two valid-set sizes, super-epochs, serve batch mix) runs
   on CPU and the per-scenario counts must EXACTLY match
   ``tools/retrace_budget.txt``;
 - entries in the budget file that the matrix no longer produces are
@@ -145,15 +145,7 @@ def run_matrix() -> Dict[str, int]:
                valid=[(x[:200], y[:200]), (x[200:430], y[200:430])],
                metric=["binary_logloss"])
 
-    # 4. fused chunks: one chunk trace per booster today (the chunk
-    #    closes over the objective), but the leaf budget rides as an
-    #    argument so the HLO — and the persistent-cache key — is shared
-    #    across the bucket
-    with _Scope("fused", measured):
-        for nl in (31, 40):
-            _train(lgb, x, y, num_leaves=nl, fused_chunk=2)
-
-    # 4b. super-epoch scan (ISSUE 16): a num_leaves sweep at k=8 with a
+    # 4. super-epoch scan (ISSUE 16): a num_leaves sweep at k=8 with a
     #    valid set + traced metric stays ONE scan trace — the leaf
     #    budget pads 31/63 onto L=64 and `_superepoch_key` carries only
     #    bucketed shapes, so the whole-run scan (k grows + k traced
@@ -166,7 +158,7 @@ def run_matrix() -> Dict[str, int]:
                    valid=[(x[:200], y[:200])],
                    metric=["binary_logloss"])
 
-    # 4c. fleet training (ISSUE 19): an N=8 member roster mixing
+    # 4b. fleet training (ISSUE 19): an N=8 member roster mixing
     #    num_leaves 31/63 and a learning-rate grid trains through ONE
     #    vmapped super-epoch scan trace — the leaf budget pads every
     #    member onto L=64, per-member lr/seeds ride as batched operands,

@@ -486,8 +486,7 @@ def run_continual_soak(duration_s: float = 4.0, clients: int = 3,
         report = {
             "duration_s": round(time.perf_counter() - t0, 3),
             "mode": "continual",
-            # headline bench numbers (bench.py continual point ->
-            # perf_budget.txt pins): chunk-arrival-to-serving lag of the
+            # headline numbers: chunk-arrival-to-serving lag of the
             # freshest generation, and mean wall time per generation
             "freshness_lag_s": fresh.get("freshness_lag_s"),
             "gen_s": round(gen_hist["sum"] / gen_hist["count"], 4)
