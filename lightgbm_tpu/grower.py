@@ -608,11 +608,6 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 out = jnp.where(hit[k], v[k], out)
             return out
 
-        from .obs.flops import note_partition_rule
-        note_partition_rule(
-            "sparse" if sparse else
-            "select" if is_cat is None else "select+rank",
-            row_gathers=int(sparse and nslots > 1) + int(is_cat is not None))
         if sparse:
             raw = _spd.column(binned, feat_k[0]) if nslots == 1 else \
                 _spd.column_per_row(binned, of_slot(feat_k, jnp.int32(0)))
@@ -651,6 +646,40 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         return new_leaf_of_row, jnp.where(
             go_left, of_slot(tleft_k, jnp.int32(-1)),
             of_slot(tright_k, jnp.int32(-1)))
+
+    def _note_partition_rule(columns, is_cat, nslots):
+        """Count the rule that ``_partition_rows`` takes over the training
+        rows, once a trace (``grower.partition_rule{rule=}``)."""
+        from .obs.flops import note_partition_rule
+        sparse = columns is None
+        note_partition_rule(
+            "sparse" if sparse else
+            "select" if is_cat is None else "select+rank",
+            row_gathers=int(sparse and nslots > 1) + int(is_cat is not None))
+
+    def _followers_at_root(followers):
+        """``(columns, leaves)`` of the followers before the first split:
+        one ``column_reader`` a follower, made once a tree beside the
+        training matrix's, and every row at the root.  Followers are row
+        sets that take no part in the tree (the held-out sets of a booster,
+        ``[Nv, F|G]`` binned like the training matrix): the grower only
+        carries them through every split it makes, so that their leaves are
+        known when the tree is."""
+        with jax.named_scope("lgbtpu.walk"):
+            return (tuple(column_reader(f) for f in followers),
+                    tuple(jnp.zeros(f.shape[0], jnp.int32)
+                          for f in followers))
+
+    def _follow(followers, fcolumns, fleaves, *step):
+        """The step's partition (``step``: ``is_cat`` ... ``rank_k`` as
+        ``_partition_rows`` takes them, no contraction slots) on every
+        follower's ``leaf_of_row``.  Under ``lgbtpu.walk``, the scope of
+        the held-out rows' way through a new tree: inside ``lgbtpu.grow``
+        the innermost scope is the one an operation is booked to."""
+        with jax.named_scope("lgbtpu.walk"):
+            return tuple(
+                _partition_rows(f, c, lor, *step)[0]
+                for f, c, lor in zip(followers, fcolumns, fleaves))
 
     gscale = None if gain_scale is None else jnp.asarray(gain_scale,
                                                          jnp.float32)
@@ -912,7 +941,12 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                   na_bin_part=None, is_cat=None,
                   rng_iter=None, cegb_used=None,
                   num_bin_part=None, max_leaves=None,
-                  quant_seed=None) -> TreeArrays:
+                  quant_seed=None, followers=None):
+        """``followers``: an optional tuple of binned matrices whose rows
+        the grower carries through its splits without their touching a
+        histogram, a count or a gain (``_follow``); the return value is
+        then ``(tree, leaves)`` with one final ``leaf_of_row`` a follower,
+        what a walk of the finished tree over those rows gives."""
         trace_event("grower")
         if max_leaves is None:
             if padded:
@@ -926,6 +960,8 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         binned_view = view_fn(binned)
         with jax.named_scope("lgbtpu.partition"):
             columns = column_reader(binned)
+        _note_partition_rule(columns, is_cat, 1)
+        fcolumns, fleaves0 = _followers_at_root(followers or ())
         scales = None
         scan_expand = _expand
         if use_quant:
@@ -948,13 +984,15 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         st = _init_state(n, L, L - 1, feature_mask.shape[0], hist0, total0,
                          root_out, res0, cuse0)
 
-        def split_step(st: _GrowState) -> _GrowState:
+        def split_step(carry):
+            st, _ = carry
             # one split per step, so the node id IS the split count so far
             i = st.num_leaves - 1
             leaf = jnp.argmax(st.bg).astype(jnp.int32)
             can_split = (st.bg[leaf] > 0.0) & (~st.done)
 
-            def do_split(st: _GrowState) -> _GrowState:
+            def do_split(carry):
+                st, fleaves = carry
                 # partition-site static accounting (obs/flops.py): a
                 # trace-time Python side effect, zero runtime cost
                 from .obs.flops import note_traced, partition_flops_bytes
@@ -975,11 +1013,13 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 rc = jnp.where(fix_r, i, st.right_child).at[i].set(~new_leaf)
 
                 # --- partition rows (CUDADataPartition::Split analog) -----
+                step = (is_cat, na_bin_part, num_bin_part, leaf[None],
+                        new_leaf[None], feat[None], thr[None], dleft[None],
+                        icat[None], rank_vec[None])
                 with jax.named_scope("lgbtpu.partition"):
                     leaf_of_row, _ = _partition_rows(
-                        binned, columns, st.leaf_of_row, is_cat, na_bin_part,
-                        num_bin_part, leaf[None], new_leaf[None], feat[None],
-                        thr[None], dleft[None], icat[None], rank_vec[None])
+                        binned, columns, st.leaf_of_row, *step)
+                fleaves = _follow(followers or (), fcolumns, fleaves, *step)
 
                 # --- histograms: smaller child + subtraction --------------
                 smaller_left = lsum[2] <= rsum[2]
@@ -1095,10 +1135,11 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                     done=st.done,
                     is_cat_node=st.is_cat_node.at[i].set(icat),
                     cat_rank=st.cat_rank.at[i].set(rank_vec),
-                )
+                ), fleaves
 
-            return lax.cond(can_split, do_split,
-                            lambda s: s._replace(done=jnp.bool_(True)), st)
+            return lax.cond(
+                can_split, do_split,
+                lambda c: (c[0]._replace(done=jnp.bool_(True)), c[1]), carry)
 
         # while_loop, not a fixed L-1 fori_loop: a tree that stops early
         # (no positive gain) exits instead of running no-op tail steps —
@@ -1107,9 +1148,10 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         # state through the cond).  The exit bound is the TRACED actual
         # budget ``limit`` (== L unless leaf-padded), which is what lets
         # one padded trace serve a whole num_leaves bucket.
-        st = lax.while_loop(
-            lambda s: (~s.done) & (s.num_leaves < limit), split_step, st)
-        return TreeArrays(
+        st, fleaves = lax.while_loop(
+            lambda c: (~c[0].done) & (c[0].num_leaves < limit), split_step,
+            (st, fleaves0))
+        tree = TreeArrays(
             num_leaves=st.num_leaves,
             split_feature=st.split_feature,
             threshold_bin=st.threshold_bin,
@@ -1129,6 +1171,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             cat_rank=st.cat_rank,
             n_steps=st.num_leaves - 1,
         )
+        return tree if followers is None else (tree, fleaves)
 
     # K clamps against the ACTUAL budget, not the padded one: the
     # super-step width is baked into RNG streams (bynode/extra_trees key
@@ -1140,8 +1183,9 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                           na_bin_part=None, is_cat=None,
                           rng_iter=None, cegb_used=None,
                           num_bin_part=None, max_leaves=None,
-                          quant_seed=None) -> TreeArrays:
-        """K-splits-per-super-step grower (split_batch above).
+                          quant_seed=None, followers=None):
+        """K-splits-per-super-step grower (split_batch above);
+        ``followers`` and the return value as ``grow_tree``'s.
 
         Per-leaf state arrays carry K scratch slots past the real range
         (leaves ``L..L+K-1``, nodes ``L-1..L-2+K``): slots of the top-K
@@ -1161,6 +1205,8 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         binned_view = view_fn(binned)
         with jax.named_scope("lgbtpu.partition"):
             columns = column_reader(binned)
+        _note_partition_rule(columns, is_cat, K)
+        fcolumns, fleaves0 = _followers_at_root(followers or ())
         scales = None
         scan_expand = _expand
         if use_quant:
@@ -1188,7 +1234,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         nC = K if use_subtraction else 2 * K
 
         def super_step(carry):
-            s, st = carry
+            s, st, _ = carry
             gains, leaves = lax.top_k(lax.slice_in_dim(st.bg, 0, L), K)
             num_nodes = st.num_leaves - 1
             budget = (limit - 1) - num_nodes
@@ -1197,7 +1243,8 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             valid = (gains > 0.0) & (kidx < budget) & (~st.done)
             can_split = valid[0]
 
-            def do_split(st: _GrowState) -> _GrowState:
+            def do_split(carry):
+                st, fleaves = carry
                 # one partition pass serves all K splits of the super-
                 # step (trace-time note; obs/flops.py)
                 from .obs.flops import note_traced, partition_flops_bytes
@@ -1229,11 +1276,13 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                     tright_k = jnp.where(smaller_left, -1, kidx)
                 else:
                     tleft_k, tright_k = kidx, K + kidx
+                step = (is_cat, na_bin_part, num_bin_part, leaf_sel,
+                        new_leaf_sel, feat_k, thr_k, dleft_k, icat_k, rank_k)
                 with jax.named_scope("lgbtpu.partition"):
                     leaf_of_row, tslot = _partition_rows(
-                        binned, columns, st.leaf_of_row, is_cat, na_bin_part,
-                        num_bin_part, leaf_sel, new_leaf_sel, feat_k, thr_k,
-                        dleft_k, icat_k, rank_k, tleft_k, tright_k)
+                        binned, columns, st.leaf_of_row, *step, tleft_k,
+                        tright_k)
+                fleaves = _follow(followers or (), fcolumns, fleaves, *step)
 
                 # --- batched child histograms: one C=3K contraction -------
                 hist_c = _hist(binned_view, vals, tslot, nC,
@@ -1376,11 +1425,12 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                     done=st.done,
                     is_cat_node=st.is_cat_node.at[node_sel].set(icat_k),
                     cat_rank=st.cat_rank.at[node_sel].set(rank_k),
-                )
+                ), fleaves
 
-            return s + 1, lax.cond(can_split, do_split,
-                                   lambda s: s._replace(done=jnp.bool_(True)),
-                                   st)
+            return (s + 1,) + lax.cond(
+                can_split, do_split,
+                lambda c: (c[0]._replace(done=jnp.bool_(True)), c[1]),
+                carry[1:])
 
         # while_loop, not a fixed trip count: a super-step splits only the
         # leaves that HAVE positive gain (chain-shaped trees take 1 split
@@ -1391,10 +1441,10 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         # moment the budget is exhausted or no leaf can split; the step
         # counter ``s`` is carried for the bynode RNG stream.  As in the
         # strict grower, the bound is the TRACED actual budget.
-        s_final, st = lax.while_loop(
+        s_final, st, fleaves = lax.while_loop(
             lambda c: (~c[1].done) & (c[1].num_leaves < limit), super_step,
-            (jnp.int32(0), st))
-        return TreeArrays(
+            (jnp.int32(0), st, fleaves0))
+        tree = TreeArrays(
             num_leaves=st.num_leaves,
             split_feature=st.split_feature[:L - 1],
             threshold_bin=st.threshold_bin[:L - 1],
@@ -1414,6 +1464,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             cat_rank=st.cat_rank[:L - 1],
             n_steps=s_final,
         )
+        return tree if followers is None else (tree, fleaves)
 
     fn = grow_tree_batched if K > 1 else grow_tree
     if not jit:

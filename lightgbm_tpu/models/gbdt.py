@@ -27,7 +27,8 @@ from ..dataset import Dataset
 from ..grower import make_grower, TreeArrays
 from ..objectives import ObjectiveFunction
 from ..ops.split import SplitParams
-from ..predict_device import add_tree_score, round_up_pow2, traverse_tree_binned
+from ..predict_device import (add_tree_score, leaf_values_of_rows,
+                              round_up_pow2, traverse_tree_binned)
 from ..tree_model import Tree
 
 # finite_check_policy=clamp replaces non-finite gradients/hessians/leaf
@@ -98,6 +99,27 @@ def _tree_leaves(binned, dt: _DeviceTree, na_bin, efb_maps=None):
         binned, dt.split_feature, dt.threshold_bin, dt.default_left,
         dt.left_child, dt.right_child, na_bin, dt.is_cat_node,
         dt.cat_rank, efb_maps, steps=dt.steps)
+
+
+def _followers(grower_follows: bool, linear: bool, matrices):
+    """The held-out matrices as the grower's followers, or None where a new
+    tree's held-out leaves come from a walk of its node tables.
+
+    The rule of both training loops, from what the booster is and holds:
+    the grower is ``make_grower``'s own over unsharded rows
+    (``grower_follows``: not the partitioned learner, not a sharded one,
+    whose rows and hooks are theirs, no caller's reduce hook), every
+    held-out matrix is dense (a ``SparseBinned`` has no columns to slice)
+    and the trees are not linear (their leaves are fitted on the host from
+    raw values).  The grower then partitions the held-out rows beside the
+    training rows, step by step, and their leaves are ready when the tree
+    is, with no per-row look-up of a node table (PERF.md section 6, PR 33).
+    Finished trees are still walked: replay, rollback, DART, predict."""
+    from ..sparse_data import SparseBinned
+    if not (grower_follows and matrices) or linear \
+            or any(isinstance(m, SparseBinned) for m in matrices):
+        return None
+    return tuple(matrices)
 
 
 class GBDTModel:
@@ -708,6 +730,10 @@ class GBDTModel:
                 cegb=self._cegb_state,
                 padded_leaves=self._leaf_pad)
             self.grower = make_grower(**mg_kwargs)
+        # make_grower's own grower over unsharded rows carries follower
+        # row sets through its splits (_followers)
+        self._grower_follows = (mg_kwargs is not None
+                                and not self._custom_hist_reduce)
         if obs is not None:
             # what the process-wide memo of jitted growers answered
             # (grower.py _SHARED_GROWERS): hit = this booster runs a
@@ -1295,6 +1321,21 @@ class GBDTModel:
         return gm
 
     # -- plumbing ----------------------------------------------------------
+    def _valid_followers(self) -> Optional[tuple]:
+        """This booster's held-out matrices where the grower follows them
+        (``_followers``), else None."""
+        return _followers(self._grower_follows, self.config.linear_tree,
+                          [vb for _, vb, _ in self.valid_sets])
+
+    def _count_valid_leaves(self, followed: bool, trees: int = 1) -> None:
+        """``train.valid_leaves{source=partition|walk}``: one count a tree
+        a held-out set, by where the tree's held-out leaves came from."""
+        if self._obs is not None and self.valid_sets:
+            self._obs.metrics.counter(
+                "train.valid_leaves",
+                source="partition" if followed else "walk").inc(
+                    trees * len(self.valid_sets))
+
     def add_valid_set(self, valid: Dataset) -> None:
         valid.construct(self.config)
         nv = valid.num_data
@@ -1776,6 +1817,7 @@ class GBDTModel:
         steps = self._se_steps()
         efb_maps = self.efb_maps
         n_rows = self.num_data
+        grower_follows = self._grower_follows
 
         # eval plumbing: one traced metric per (valid set, metric) entry,
         # in booster.eval_valid() order.  The in-scan eval exists ONLY
@@ -1811,6 +1853,8 @@ class GBDTModel:
             obj = copy.copy(obj_template)
             for nm, arr in zip(arr_names, obj_arrs):
                 setattr(obj, nm, arr)
+            followers = _followers(grower_follows, cfg.linear_tree,
+                                   [op[0] for op in valid_ops])
 
             def one_iter(carry, xs):
                 score, vsc, esb, esi, esh, stop, dead, cuse, ml = carry
@@ -1847,7 +1891,11 @@ class GBDTModel:
                     # constant) so the HLO is identical across a
                     # num_leaves bucket
                     kw["max_leaves"] = ml
-                arrays = grow(binned, vals, fmask, nb, na, **kw)
+                if followers is not None:
+                    arrays, vleaves = grow(binned, vals, fmask, nb, na,
+                                           followers=followers, **kw)
+                else:
+                    arrays = grow(binned, vals, fmask, nb, na, **kw)
                 if use_cegb:
                     # fold this tree's split features into the CEGB
                     # cross-tree used set for the next scan iteration
@@ -1911,18 +1959,23 @@ class GBDTModel:
                     score = jnp.where(bad, jnp.nan_to_num(
                         score, nan=0.0, posinf=_FINITE_CLAMP,
                         neginf=-_FINITE_CLAMP), score)
-                # valid-set scoring: same traversal + leaf-gather the
+                # valid-set scoring, by the per-iteration path's rule
+                # (_followers): the held-out leaves come out of the
+                # grower with the tree, or from the same traversal the
                 # per-iteration path runs (predict_device add_tree_score
                 # at weight 1.0 == plain gather-add), under ONE static
                 # step budget so every tree of the epoch shares the trace
                 new_vsc = []
                 for vi2 in range(len(valid_ops)):
-                    leaf = traverse_tree_binned(
-                        valid_ops[vi2][0], arrays.split_feature,
-                        arrays.threshold_bin, arrays.default_left,
-                        arrays.left_child, arrays.right_child, na_bin,
-                        arrays.is_cat_node, arrays.cat_rank, efb_maps,
-                        steps=steps)
+                    if followers is not None:
+                        leaf = vleaves[vi2]
+                    else:
+                        leaf = traverse_tree_binned(
+                            valid_ops[vi2][0], arrays.split_feature,
+                            arrays.threshold_bin, arrays.default_left,
+                            arrays.left_child, arrays.right_child, na_bin,
+                            arrays.is_cat_node, arrays.cat_rank, efb_maps,
+                            steps=steps)
                     with jax.named_scope("lgbtpu.score"):
                         vd = jnp.where(ok > 0.0, jnp.take(lv, leaf), 0.0)
                         new_vsc.append(vsc[vi2].at[:, 0].add(vd))
@@ -2308,6 +2361,8 @@ class GBDTModel:
         if obs is not None:
             obs.metrics.counter("train.iterations").inc(done)
             obs.metrics.counter("train.superepochs").inc()
+            self._count_valid_leaves(
+                self._valid_followers() is not None, done)
             for s in self.step_counts[len(self.step_counts) - done:]:
                 obs.metrics.histogram("train.steps_per_tree").observe(s)
                 obs.record_flops(s)
@@ -2504,17 +2559,27 @@ class GBDTModel:
                     gkw["max_leaves"] = jnp.int32(cfg.num_leaves)
             vals_g = self._prep_vals(vals)
             fmask_g = self._prep_fmask(fmask)
+            followers = self._valid_followers()
+            if followers is not None:
+                gkw["followers"] = followers
+            vleaves = [None]    # the followers' leaves in the tree kept
 
-            def _run_grow(fn):
+            def _run_grow(fn, keep=None):
                 if self._dist == "feature":
                     return fn(self.binned_dev, vals_g, fmask_g,
                               self._nb_grow, self._na_grow,
                               self._na_grow, **gkw)
-                return fn(self.binned_dev, vals_g, fmask_g,
-                          self._nb_grow, self._na_grow, **gkw)
+                out = fn(self.binned_dev, vals_g, fmask_g,
+                         self._nb_grow, self._na_grow, **gkw)
+                if followers is not None:
+                    # the tree alone is what the callers compare and fetch
+                    out, leaves = out
+                    if keep is not None:
+                        keep[0] = leaves
+                return out
 
             def _grow():
-                a = _run_grow(self.grower)
+                a = _run_grow(self.grower, vleaves)
                 if faultinject.enabled():
                     # SDC chaos substrate (integrity.py tests/soak): one
                     # deterministic bit of the new tree's leaf-count
@@ -2699,7 +2764,7 @@ class GBDTModel:
                 ht.leaf_value = host_values[:max(nl, 1)].copy()
                 # score update via row->leaf gather (no traversal needed)
                 lv_dev = jnp.asarray(dev_values, jnp.float32)
-                delta = jnp.take(lv_dev, arrays.leaf_of_row)
+                delta = leaf_values_of_rows(lv_dev, arrays.leaf_of_row)
                 if faultinject.enabled():
                     delta = faultinject.maybe_bitflip("score_sdc", delta)
                 if check_now:
@@ -2749,6 +2814,10 @@ class GBDTModel:
                         vdelta = np.pad(
                             vdelta, (0, len(vscore) - vds.num_data))
                     vd = jnp.asarray(vdelta, jnp.float32)
+                elif followers is not None:
+                    # the grower carried the held-out rows through the
+                    # tree's splits: the delta is the train score's own
+                    vd = leaf_values_of_rows(lv_dev, vleaves[0][vi])
                 else:
                     vd = _apply_tree(jnp.zeros_like(vscore[:, k]), vbinned,
                                      dt, self.na_bin_dev, 1.0, self.efb_maps)
@@ -2756,6 +2825,7 @@ class GBDTModel:
                 self.valid_sets[vi] = (vds, vbinned,
                                        vscore.at[:, k].add(vd))
             iter_state["valid_deltas"].append(vdeltas)
+            self._count_valid_leaves(followers is not None)
             if obs is not None:
                 obs.end_phase(_sp, [vs for _, _, vs in self.valid_sets]
                               or None)

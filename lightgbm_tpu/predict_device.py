@@ -1,10 +1,16 @@
 """Device-side tree traversal over binned data.
 
-Used for validation-set score updates each iteration (the reference's
-``ScoreUpdater::AddScore(tree)`` path, score_updater.hpp:21-128) and for
-batched leaf prediction.  The traversal is a fixed-depth ``fori_loop`` of
-vectorized gathers: every row walks one level per step; finished rows carry
-their (negative-encoded) leaf id unchanged — static shapes, no divergence.
+Walks trees that are already finished (the reference's
+``ScoreUpdater::AddScore(tree)`` path, score_updater.hpp:21-128): a valid
+set's replay of the trees grown before it was added, rollback and dropped
+iterations, DART's drop-and-rescale, batched leaf prediction and serving.
+A new tree's validation-set score update takes this walk only where the
+grower cannot carry the held-out rows through its own partition
+(``models/gbdt._followers``: the partitioned and the sharded learners,
+sparse-binned valid sets, linear trees).  The traversal is a fixed-depth
+``fori_loop`` of vectorized gathers: every row walks one level per step;
+finished rows carry their (negative-encoded) leaf id unchanged — static
+shapes, no divergence.
 
 Numerical and categorical decisions share one predicate: per-node
 ``cat_rank`` maps bin -> decision rank (identity for numerical nodes), go
@@ -81,6 +87,17 @@ def add_tree_score(score, binned, split_feature, threshold_bin, default_left,
                                 steps=steps)
     with jax.named_scope("lgbtpu.score"):
         return score + weight * jnp.take(leaf_value, leaf)
+
+
+@jax.jit
+@jax.named_scope("lgbtpu.score")
+def leaf_values_of_rows(leaf_value, leaf_of_row):
+    """A tree's score delta of rows whose leaves are known: the training
+    rows' out of the grower's partition, and the held-out rows' where the
+    grower carried them through it (``models/gbdt._followers``).  One
+    program under the score's scope, so that a device trace books the
+    per-iteration loop's look-up to ``lgbtpu.score`` as the scan's."""
+    return jnp.take(leaf_value, leaf_of_row)
 
 
 # (round_up_pow2 moved to utils/shapes.py — the ONE bucketing policy

@@ -333,10 +333,11 @@ def test_partition_by_selects_grows_the_trees_the_look_ups_grew(name):
         assert (np.asarray(t.split_feature)[nodes] >= 6).any()
 
 
-def row_gathers_of_the_step(opts, args, kw):
+def row_gathers_of_the_step(opts, args, kw, rows=None):
     """``(operand shape, result shape)`` of every ``gather`` inside the
-    grower's loop whose result's leading dimension is the row count."""
-    n = args[0].shape[0]
+    grower's loop whose result's leading dimension is the row count (or
+    one of ``rows``)."""
+    rows = rows or (args[0].shape[0],)
     grow = make_grower(jit=False, **opts)
     shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args[:2]]
     closed = jax.make_jaxpr(lambda b, v: grow(b, v, *args[2:], **kw))(*shapes)
@@ -345,7 +346,8 @@ def row_gathers_of_the_step(opts, args, kw):
         for eqn in jaxpr.eqns:
             step = inside or eqn.primitive.name == "while"
             shape = tuple(eqn.outvars[0].aval.shape)
-            if step and eqn.primitive.name == "gather" and shape[:1] == (n,):
+            if step and eqn.primitive.name == "gather" \
+                    and shape[:1] and shape[0] in rows:
                 yield tuple(eqn.invars[0].aval.shape), shape
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 yield from walk(sub, step)

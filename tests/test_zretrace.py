@@ -187,7 +187,10 @@ class TestBudgetFile:
         # and the unbucketed negative control measurably above it
         assert budget.get("leaf_sweep.grower") == 1
         assert budget.get("negative_unbucketed.grower", 0) > 1
-        assert "valid_sizes.add_tree_score" in budget
+        # two valid-set sizes of one row bucket, in either order: the
+        # grower that follows them is traced once, and no walk at all
+        assert budget.get("valid_sizes.grower") == 1
+        assert "valid_sizes.add_tree_score" not in budget
         assert "serve_buckets.forest" in budget
 
 

@@ -138,12 +138,17 @@ def run_matrix() -> Dict[str, int]:
         for nl in (33, 63):
             _train(lgb, x, y, num_leaves=nl, split_batch=32)
 
-    # 3. two valid-set sizes row-bucket onto one traversal shape, so
-    #    early stopping over mixed valid sets stops re-tracing
+    # 3. two valid-set sizes row-bucket onto one shape (256 rows), so
+    #    early stopping over mixed valid sets stops re-tracing.  The
+    #    grower carries the valid sets' rows through its partition
+    #    (gbdt._followers), so their shapes are part of ITS signature and
+    #    no tree walk is traced at all: the two sizes in either order are
+    #    one grower trace, where unbucketed they would be two
     with _Scope("valid_sizes", measured):
-        _train(lgb, x, y, rounds=3, num_leaves=15,
-               valid=[(x[:200], y[:200]), (x[200:430], y[200:430])],
-               metric=["binary_logloss"])
+        sizes = [(x[:200], y[:200]), (x[200:430], y[200:430])]
+        for valid in (sizes, sizes[::-1]):
+            _train(lgb, x, y, rounds=3, num_leaves=15, valid=valid,
+                   metric=["binary_logloss"])
 
     # 4. super-epoch scan (ISSUE 16): a num_leaves sweep at k=8 with a
     #    valid set + traced metric stays ONE scan trace — the leaf
