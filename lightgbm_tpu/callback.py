@@ -103,7 +103,7 @@ def log_telemetry(period: int = 10, collect: Dict = None) -> Callable:
         if ev and ev.get("count"):
             parts.append(f"eval={ev['sum']:.2f}s")
         wire = sum(rec["value"] for key, rec in snap.items()
-                   if key.startswith("comm.wire_bytes{"))
+                   if key.startswith("comm.bytes{"))
         if wire:
             parts.append(f"comm={wire / 1e6:.2f}MB")
         return " ".join(parts) or "(no telemetry data)"

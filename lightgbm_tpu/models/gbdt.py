@@ -106,8 +106,10 @@ def _followers(grower_follows: bool, linear: bool, matrices):
     tree's held-out leaves come from a walk of its node tables.
 
     The rule of both training loops, from what the booster is and holds:
-    the grower is ``make_grower``'s own over unsharded rows
-    (``grower_follows``: not the partitioned learner, not a sharded one,
+    the grower is ``make_grower``'s own body over rows that every worker
+    holds whole (``grower_follows``: the one-chip masked grower, or the
+    feature-sharded one, whose rows are replicated and whose splits carry
+    global feature ids; not the partitioned learner, not a row-sharded one,
     whose rows and hooks are theirs, no caller's reduce hook), every
     held-out matrix is dense (a ``SparseBinned`` has no columns to slice)
     and the trees are not linear (their leaves are fitted on the host from
@@ -275,6 +277,11 @@ class GBDTModel:
                 f"ignoring tree_learner={dist}: a caller-supplied "
                 "hist_reduce hook takes over cross-shard reduction")
         self._custom_hist_reduce = hist_reduce is not None
+        # the learner one device would grow this model with (a sharded
+        # learner is the masked one whatever this says): the leaf values'
+        # shrinkage of the feature-sharded learner follows it,
+        # _shrinks_in_float32
+        self._serial_kind = learner
         self._fused_cache: Dict[str, object] = {}
         self._mesh = None
         self._row_pad = 0
@@ -282,7 +289,12 @@ class GBDTModel:
         self._global_counts = None
         self._dist_axis = "feature" if dist == "feature" else "data"
         if dist is not None and hist_reduce is None:
+            if obs is not None:
+                _sp = obs.span("booster.mesh", learner=dist)
             self._mesh = self._resolve_mesh(config, self._dist_axis)
+            if obs is not None:
+                obs.end_setup(_sp, devices=0 if self._mesh is None
+                              else int(self._mesh.size))
             if self._mesh is None:
                 dist = None             # single device -> serial (warned)
             elif has_node_controls or inter is not None \
@@ -470,7 +482,16 @@ class GBDTModel:
                 if self.is_cat_dev is not None:
                     self._ic_grow = jnp.asarray(np.concatenate(
                         [is_cat, np.zeros(self._feat_pad, bool)]))
-            self.binned_dev = jnp.asarray(feat_binned)
+            # every worker holds every row: the matrix goes to the mesh
+            # once, replicated and committed, and the columns' metadata as
+            # the grower's shard_map takes it.  Left on one device, jit
+            # sends the matrix to the others at every tree
+            self.binned_dev = self._on_mesh(feat_binned)
+            self._na_part = self._on_mesh(self._na_grow)
+            self._nb_grow = self._on_mesh(self._nb_grow, self._dist_axis)
+            self._na_grow = self._on_mesh(self._na_grow, self._dist_axis)
+            if self._ic_grow is not None:
+                self._ic_grow = self._on_mesh(self._ic_grow, self._dist_axis)
         else:
             self.binned_dev = jnp.asarray(feat_binned)
         if self._sparse:
@@ -730,10 +751,12 @@ class GBDTModel:
                 cegb=self._cegb_state,
                 padded_leaves=self._leaf_pad)
             self.grower = make_grower(**mg_kwargs)
-        # make_grower's own grower over unsharded rows carries follower
-        # row sets through its splits (_followers)
-        self._grower_follows = (mg_kwargs is not None
-                                and not self._custom_hist_reduce)
+        # make_grower's own body over rows that every worker holds whole
+        # carries follower row sets through its splits (_followers): the
+        # one-chip grower and the feature-sharded one of one process
+        self._grower_follows = (
+            (mg_kwargs is not None or (dist == "feature" and self._pc == 1))
+            and not self._custom_hist_reduce)
         if obs is not None:
             # what the process-wide memo of jitted growers answered
             # (grower.py _SHARED_GROWERS): hit = this booster runs a
@@ -757,7 +780,11 @@ class GBDTModel:
         if ds.metadata.init_score is not None:
             s = np.asarray(ds.metadata.init_score, np.float32)
             init += s.reshape(self.num_data, -1)
-        self.score = jnp.asarray(init)
+        self.score = self._row_state(init)
+        if dist == "feature" and self.objective is not None:
+            # the row state lives where the grower's rows do: a label left
+            # on one device is sent to the others at every gradient
+            self.objective.place_row_state(self._on_mesh)
         self._init_applied = ds.metadata.init_score is not None
         if obs is not None:
             obs.end_to_device(_sp, (self.score, [
@@ -1197,16 +1224,16 @@ class GBDTModel:
         return gscore, self._global_fp
 
     def _note_grower_memory(self, obs, args, kwargs) -> None:
-        """Once a booster, where one jitted masked grower on one device
-        grows its trees: the bytes of temporaries XLA laid out for the
-        executable the iteration just ran (``grower.temp_bytes``) and the
-        logical bytes of its per-leaf histogram state
-        (``grower.hist_state_bytes``: leaf slots x 3 x columns x bins),
-        one observation each, so a reader of several boosters takes
-        ``sum / count``."""
+        """Once a booster, where one jitted masked grower grows its trees:
+        the bytes of temporaries XLA laid out for the executable the
+        iteration just ran (``grower.temp_bytes``) and the logical bytes of
+        its per-leaf histogram state (``grower.hist_state_bytes``: leaf
+        slots x 3 x columns x bins), one observation each, so a reader of
+        several boosters takes ``sum / count``.  Of a sharded grower both
+        are one worker's share (XLA analyses the program of one device; a
+        grower says what columns of the state a worker holds,
+        ``state_columns``): one that says neither is left out."""
         self._grower_memory_noted = True
-        if self._dist is not None:
-            return
         from ..grower import compiled_grower_temp_bytes
         temp = compiled_grower_temp_bytes(self.grower, args, kwargs)
         if temp is None:
@@ -1215,8 +1242,13 @@ class GBDTModel:
         # the batched grower keeps K scratch slots past the leaf budget
         k = min(self._split_batch, self.config.num_leaves - 1)
         slots = (self._leaf_pad or self.config.num_leaves) + (k if k > 1 else 0)
-        cols = self.binned_dev.num_features if self._sparse \
-            else self.binned_dev.shape[1]
+        if self._dist is not None:
+            cols = getattr(self.grower, "state_columns", None)
+            if cols is None:
+                return
+        else:
+            cols = self.binned_dev.num_features if self._sparse \
+                else self.binned_dev.shape[1]
         bins = int(self.efb_dev.group_bins) if self._use_efb else self.max_bin
         obs.metrics.histogram("grower.hist_state_bytes").observe(
             slots * 3 * int(cols) * bins * 4)
@@ -1227,13 +1259,11 @@ class GBDTModel:
         identity otherwise.  Padded rows carry zero weight so they never
         contribute to histograms."""
         if self._dist == "feature":
-            # round 0's stack lies on one device, uncommitted; every later
-            # one follows the score onto the mesh (the grower's replicated
-            # leaf_of_row), and jit traces the grower anew for the second
-            # placement: give both the one the program runs on
-            from jax.sharding import NamedSharding, PartitionSpec
-            return jax.device_put(
-                vals, NamedSharding(self._mesh, PartitionSpec()))
+            # the stack follows the score, which lies on the mesh from the
+            # booster's construction; a caller's gradients (``fobj``) come
+            # from one device, and jit traces the grower anew for another
+            # placement: give every stack the one the program runs on
+            return self._on_mesh(vals)
         if self._dist not in ("data", "voting"):
             return vals
         if self._row_pad:
@@ -1289,8 +1319,24 @@ class GBDTModel:
 
     def _prep_fmask(self, fmask: jax.Array) -> jax.Array:
         if self._feat_pad:
-            return jnp.concatenate([fmask, jnp.zeros(self._feat_pad, bool)])
+            fmask = jnp.concatenate([fmask, jnp.zeros(self._feat_pad, bool)])
+        if self._dist == "feature":
+            return self._on_mesh(fmask, self._dist_axis)
         return fmask
+
+    def _row_state(self, x) -> jax.Array:
+        """Per-row state (a score, a held-out matrix) where the grower's
+        rows lie: on every device of the feature-sharded learner's mesh,
+        else on the default device."""
+        return self._on_mesh(x) if self._dist == "feature" \
+            else jnp.asarray(x)
+
+    def _on_mesh(self, x, axis: Optional[str] = None):
+        """``x`` placed on the learner's mesh and committed there:
+        replicated, or its leading axis split over ``axis``."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        return jax.device_put(
+            x, NamedSharding(self._mesh, PartitionSpec(axis)))
 
     @staticmethod
     def _interaction_allow(config: Config, ds: Dataset):
@@ -1361,7 +1407,8 @@ class GBDTModel:
                 if pad:
                     vb = np.concatenate(
                         [vb, np.zeros((pad, vb.shape[1]), vb.dtype)])
-            binned = jnp.asarray(vb)
+            # held-out rows lie where the training rows do (_followers)
+            binned = self._row_state(vb)
         init = np.zeros((nv + pad, self.num_class), np.float32)
         if valid.metadata.init_score is not None:
             init[:nv] += np.asarray(valid.metadata.init_score, np.float32) \
@@ -1381,7 +1428,7 @@ class GBDTModel:
                 k = ti % self.num_class
                 init[:nv, k] += (self.tree_weights[ti]
                                  * self.models[ti].predict(raw))
-        score = jnp.asarray(init)
+        score = self._row_state(init)
         if obs is not None:
             obs.end_to_device(_sp, (binned, score))
         # replay existing device trees (continued training)
@@ -1532,11 +1579,13 @@ class GBDTModel:
                 self._feature_mask()
 
     # -- scanned multi-iteration path (one host sync per epoch) -------------
-    def _fusable_config(self) -> bool:
+    def _fusable_config(self, serial_kind: Optional[str] = None) -> bool:
         """Whether this model/objective/sampling combination has scan-path
         semantics (independent of whether the scan is enabled) — also gates
         the f32 leaf-shrinkage in train_one_iter so toggling ``fused_chunk``
-        never changes the trained model."""
+        never changes the trained model.  ``serial_kind``: the same
+        question of the serial learner of that kind on one device
+        (``_shrinks_in_float32``)."""
         cfg = self.config
         return (type(self) is GBDTModel
                 and self.objective is not None
@@ -1544,10 +1593,20 @@ class GBDTModel:
                 and not self.objective.host_state_per_iter
                 and self.num_class == 1
                 and not cfg.linear_tree
-                and self._learner_kind == "masked"
-                and self._dist is None
+                and (serial_kind or self._learner_kind) == "masked"
+                and (self._dist is None or serial_kind is not None)
                 and not self._custom_hist_reduce
                 and self._forced_spec is None)
+
+    def _shrinks_in_float32(self) -> bool:
+        """Whether ``train_one_iter`` shrinks a tree's leaf values as the
+        scan does, in float32: where the scan could run this booster, and
+        under the feature-sharded learner where it could run the serial
+        learner this configuration picks on one device, whose trees the
+        feature-sharded learner's are to the byte.  The row-sharded
+        learners shrink in float64 as they did."""
+        return self._fusable_config(
+            self._serial_kind if self._dist == "feature" else None)
 
     def supports_fused(self) -> bool:
         """True when whole iterations can run fused on device via
@@ -2564,13 +2623,13 @@ class GBDTModel:
                 gkw["followers"] = followers
             vleaves = [None]    # the followers' leaves in the tree kept
 
+            # the feature-sharded grower takes its columns' metadata in
+            # shards and, for the partition, whole
+            na_part = (self._na_part,) if self._dist == "feature" else ()
+
             def _run_grow(fn, keep=None):
-                if self._dist == "feature":
-                    return fn(self.binned_dev, vals_g, fmask_g,
-                              self._nb_grow, self._na_grow,
-                              self._na_grow, **gkw)
                 out = fn(self.binned_dev, vals_g, fmask_g,
-                         self._nb_grow, self._na_grow, **gkw)
+                         self._nb_grow, self._na_grow, *na_part, **gkw)
                 if followers is not None:
                     # the tree alone is what the callers compare and fetch
                     out, leaves = out
@@ -2616,7 +2675,7 @@ class GBDTModel:
                 if not self._grower_memory_noted:
                     self._note_grower_memory(
                         obs, (self.binned_dev, vals_g, fmask_g,
-                              self._nb_grow, self._na_grow), gkw)
+                              self._nb_grow, self._na_grow) + na_part, gkw)
                 _sp = obs.phase("fetch", self.iter_)
             # ONE batched host transfer of the tree-sized fields; the [N]
             # leaf_of_row stays on device (only pulled when renew/linear
@@ -2711,7 +2770,7 @@ class GBDTModel:
                         leaf_values[:nl].copy())
 
             shrinkage = 1.0 if cfg.boosting == "rf" else self.learning_rate
-            if self._fusable_config():
+            if self._shrinks_in_float32():
                 # shrink with f32 semantics (an exact f64 product of f32
                 # operands rounded back to f32 equals the hardware f32
                 # multiply) so the scanned path, which shrinks on

@@ -50,6 +50,14 @@ class ObjectiveFunction:
         w = metadata.weight
         self.weight = jnp.asarray(w, jnp.float32) if w is not None else None
 
+    def place_row_state(self, put) -> None:
+        """Move the device arrays ``init`` made (label, weight and what a
+        subclass derives from them, all per row) through ``put``: a learner
+        whose rows lie on several devices keeps them where its rows are."""
+        for name, value in vars(self).items():
+            if isinstance(value, jax.Array):
+                setattr(self, name, put(value))
+
     def get_gradients(self, score: jax.Array) -> Tuple[jax.Array, jax.Array]:
         raise NotImplementedError
 

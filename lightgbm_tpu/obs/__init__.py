@@ -61,6 +61,7 @@ SPAN_PREFIX = "lgbtpu."
 # under in ``train.setup_seconds{stage=}`` (docs/Observability.md)
 SETUP_STAGES = {"cv.fold_setup": "fold_setup", "dataset.subset": "subset",
                 "booster.init": "booster_init",
+                "booster.mesh": "mesh",
                 "booster.to_device": "to_device",
                 "grower.make": "grower_make"}
 _SESSION_IDS = itertools.count(1)
@@ -128,10 +129,14 @@ class ObsSession:
         is the host's part of the placement.  The transfer goes on behind
         the next fold's host work, and a fence here made the traced job
         of five folds longer on the v5e (PERF.md §3); the transfer's own
-        time is the runtime's events on the profiler's host plane."""
+        time is the runtime's events on the profiler's host plane.  An
+        array placed on several devices counts once for each: its shards'
+        bytes, so four times its own where it is replicated over four."""
         import jax
-        nbytes = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(arrays)
-                     if isinstance(a, jax.Array))
+        nbytes = sum(int(s.data.nbytes)
+                     for a in jax.tree_util.tree_leaves(arrays)
+                     if isinstance(a, jax.Array)
+                     for s in a.addressable_shards)
         self.metrics.counter("xfer.h2d_bytes").inc(nbytes)
         return self.end_setup(sp, bytes=nbytes)
 
@@ -218,7 +223,7 @@ class ObsSession:
             self.metrics.counter("comm.calls", **labels).inc(mult)
             self.metrics.counter("comm.payload_bytes", **labels).inc(
                 site.payload_bytes * mult)
-            self.metrics.counter("comm.wire_bytes", **labels).inc(
+            self.metrics.counter("comm.bytes", **labels).inc(
                 site.wire_bytes * mult)
 
     # -- compute accounting ------------------------------------------------

@@ -110,12 +110,14 @@ class CommLedger:
                      wire_payload=payload * self.axis_size)
         return lax.all_gather(x, axis_name, **kw)
 
-    def note_all_gather(self, x, *, site: str,
-                        cadence: str = "step") -> None:
+    def note_all_gather(self, x, *, site: str, cadence: str = "step",
+                        copies: int = 1) -> None:
         """Record an all_gather performed elsewhere (ops/split.py
         ``gather_best`` stays collective-owning; the learner builders
-        note its payload here at trace time)."""
-        payload = _nbytes(x)
+        note its payload here at trace time).  ``copies``: how many of
+        ``x`` one execution gathers, where the site is traced under a
+        ``vmap`` that hides the batch from ``x``'s shape."""
+        payload = _nbytes(x) * int(copies)
         self._record(site, "all_gather", payload, cadence,
                      wire_payload=payload * self.axis_size)
 

@@ -120,11 +120,16 @@ def gather_best(res: SplitResult, axis_name: str) -> SplitResult:
     instead follow EFB group order, which need not follow feature order
     (duplicated columns bundled into different groups would then split on
     a different feature than serial).  Within a shard the local argmax
-    already reproduces serial's (dir, feature, bin) order."""
-    g = lax.all_gather(res, axis_name)       # one collective: pytree [S, ...]
-    tie = g.gain == jnp.max(g.gain)
-    win = jnp.argmin(jnp.where(tie, g.feature, jnp.int32(2 ** 30)))
-    return jax.tree.map(lambda a: a[win], g)
+    already reproduces serial's (dir, feature, bin) order.
+
+    Under the device scope ``lgbtpu.sync``: what a trace books there is
+    the exchange and the choice among the gathered candidates, the one
+    part of a sharded grower's step that waits on the other chips."""
+    with jax.named_scope("lgbtpu.sync"):
+        g = lax.all_gather(res, axis_name)   # one collective: pytree [S, ...]
+        tie = g.gain == jnp.max(g.gain)
+        win = jnp.argmin(jnp.where(tie, g.feature, jnp.int32(2 ** 30)))
+        return jax.tree.map(lambda a: a[win], g)
 
 
 def threshold_l1(s: jax.Array, l1: float) -> jax.Array:

@@ -21,6 +21,17 @@ Leaf-budget trace sharing (ROADMAP item 1 remainder): ``padded_leaves``
 + per-call traced ``max_leaves`` + a process-level memo of the jitted
 shard_map program, so a ``num_leaves`` sweep inside one bucket runs ONE
 feature-parallel grower trace (pinned by tools/check_retraces.py).
+
+Rows are replicated, so a booster's held-out matrices ride the grower's
+partition here as they do on one chip (``followers``, grower.py
+``_follow``): every worker carries every follower's ``leaf_of_row``
+through the same global splits and hands the same leaves back.
+
+What a worker holds (the cell ``epsilon-b255-fp4.cv5`` measures it on four
+v5e chips, PERF.md section 4): every row of the binned matrix and of each
+follower, and ``1/n`` of the columns' histograms, per-leaf state and split
+search.  What crosses chips is ``gather_best``'s one all-gather of the
+candidates, under the device scope ``lgbtpu.sync``.
 """
 
 from __future__ import annotations
@@ -34,7 +45,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..grower import TreeArrays, make_grower
+from ..grower import make_grower
 from ..obs.comm import CommLedger
 from ..ops.split import SplitParams, SplitResult, gather_best
 from ..utils.memo import memo_get_or_build
@@ -79,18 +90,27 @@ def make_fp_grower(mesh: Mesh, *, num_features: int, num_leaves: int,
                        hist_overlap=hist_overlap,
                        padded_leaves=padded_leaves, quant=quant))
 
-    def grow(binned, vals, feature_mask, num_bin, na_bin, na_bin_part=None,
-             is_cat=None, max_leaves=None, rng_iter=None):
+    def _args(binned, vals, feature_mask, num_bin, na_bin, na_bin_part=None,
+              is_cat=None, max_leaves=None, rng_iter=None, followers=None):
         if na_bin_part is None:
             na_bin_part = na_bin
-        if is_cat is None:
-            is_cat = jnp.zeros(num_bin.shape[0], bool)
         ml = jnp.int32(num_leaves if max_leaves is None else max_leaves)
         ri = jnp.int32(0 if rng_iter is None else rng_iter)
-        return jitted(binned, vals, feature_mask, num_bin, na_bin,
-                      na_bin_part, is_cat, ml, ri)
+        # ``is_cat`` stays None where no feature is categorical, as on one
+        # chip: the partition then takes no per-row look-up of a rank
+        # (grower.py ``_partition_rows``, rule ``select``)
+        return (binned, vals, feature_mask, num_bin, na_bin, na_bin_part,
+                is_cat, ml, ri, followers)
 
+    def grow(*args, **kwargs):
+        """The tree, replicated; with ``followers`` (replicated binned
+        matrices) ``(tree, their leaf_of_row)``, as ``make_grower``'s."""
+        return jitted(*_args(*args, **kwargs))
+
+    grow.lower = lambda *args, **kwargs: jitted.lower(*_args(*args, **kwargs))
     grow.comm = ledger
+    # columns of the per-leaf histogram state that one worker holds
+    grow.state_columns = num_features // n_shards
     return grow
 
 
@@ -107,12 +127,20 @@ def _build(mesh: Mesh, *, num_features, num_leaves, num_bins, params,
         return lax.dynamic_slice_in_dim(binned, idx * f_local, f_local,
                                         axis=1)
 
+    # candidates a step's exchange carries: both children of each of the
+    # step's K splits (make_grower's own clamp of K)
+    k = max(1, min(int(split_batch), int(num_leaves) - 1))
+
     def select_best(res: SplitResult) -> SplitResult:
         # contiguous slices globalize by offset; the winner sync is the
         # shared SyncUpGlobalBestSplit allgather (ops/split.gather_best)
         idx = lax.axis_index(axis)
         res = res._replace(feature=res.feature + idx * f_local)
-        ledger.note_all_gather(res, site="fp.best_split")
+        # the hook runs once for the root and, under vmap, once for a
+        # step's 2K children: vmap hides the batch from the traced shapes,
+        # so the step's payload is the candidate's times 2K
+        ledger.note_all_gather(res, site="fp.root_split", cadence="tree")
+        ledger.note_all_gather(res, site="fp.best_split", copies=2 * k)
         return gather_best(res, axis)
 
     inner = make_grower(
@@ -125,17 +153,17 @@ def _build(mesh: Mesh, *, num_features, num_leaves, num_bins, params,
         # no scale pmax or row offset needed (module docstring)
         quant=quant, jit=False)
 
-    out_specs = jax.tree.map(lambda _: P(), TreeArrays(
-        *(0,) * len(TreeArrays._fields)))
-
-    def wrapped(binned, vals, fm, nb, na, nabp, ic, ml, ri):
+    def wrapped(binned, vals, fm, nb, na, nabp, ic, ml, ri, followers):
         return inner(binned, vals, fm, nb, na, nabp, ic, rng_iter=ri,
-                     max_leaves=ml)
+                     max_leaves=ml, followers=followers)
 
+    # a spec stands for its argument's whole subtree: ``is_cat`` may be
+    # None and ``followers`` None or a tuple of matrices; the result is the
+    # tree, or the tree and the followers' leaves, replicated either way
     f = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(P(None, None), P(None, None), P(axis), P(axis), P(axis),
-                  P(None), P(axis), P(), P()),
-        out_specs=out_specs, check_vma=False)
+                  P(None), P(axis), P(), P(), P()),
+        out_specs=P(), check_vma=False)
 
     return jax.jit(f), ledger

@@ -353,10 +353,10 @@ class TestComm:
         # counter = wire bytes x total grower steps over both iterations
         snap = bst.telemetry_snapshot()
         steps = sum(m.step_counts)
-        key = "comm.wire_bytes{collective=psum_scatter,site=dp.hist_reduce}"
+        key = "comm.bytes{collective=psum_scatter,site=dp.hist_reduce}"
         assert snap[key]["value"] == sites["dp.hist_reduce"].wire_bytes \
             * steps
-        key = "comm.wire_bytes{collective=psum,site=dp.root_sum}"
+        key = "comm.bytes{collective=psum,site=dp.root_sum}"
         assert snap[key]["value"] == sites["dp.root_sum"].wire_bytes * 2
         assert ledger.bytes_per_iteration(1) == sum(
             s.wire_bytes for s in ledger.sites())
@@ -380,7 +380,7 @@ class TestComm:
             assert s_snap["train.steps_per_tree"][fld] \
                 == d_snap["train.steps_per_tree"][fld]
         assert not any(k.startswith("comm.") for k in s_snap)
-        assert any(k.startswith("comm.wire_bytes") for k in d_snap)
+        assert any(k.startswith("comm.bytes") for k in d_snap)
 
     def test_bench_comm_extra_math(self):
         from lightgbm_tpu.obs.comm import dp_hist_bytes_per_iter
