@@ -34,7 +34,9 @@ from jax import lax
 
 from .ops.histogram import compute_histogram
 from . import sparse_data as _spd
-from .ops.split import (SplitParams, SplitResult, find_best_split,
+# LANES, a TPU vector register's: a matrix narrower than this has all its
+# columns in every tile of its row-major form
+from .ops.split import (LANES, SplitParams, SplitResult, find_best_split,
                         leaf_output, monotone_penalty_factor)
 from .utils.compile_cache import trace_event
 
@@ -123,9 +125,148 @@ def slot_histograms(h: jax.Array, nslots: int) -> jax.Array:
     return h.reshape((3, nslots) + h.shape[1:]).swapaxes(0, 1)
 
 
-# lanes of a TPU vector register: a matrix narrower than this has all its
-# columns in every tile of its row-major form
-LANES = 128
+# -- the rows a contraction is handed (PERF.md §6, PR 35) -------------------
+# A step's contraction needs the rows of its target leaves (the smaller
+# child of each split), a tenth of the rows in most steps of a 255-leaf
+# tree.  Before the pass they are compacted into a row bucket, the smallest
+# of a static ladder of capacities that holds them: rung r holds
+# ``ceil(N / 2**r)`` rows, rung 0 is the pass over all N rows.  Which rungs
+# a shape gets follows from what a row costs, in nanoseconds on the v5e
+# (the builder's chip readings at 320,000 x 2,000 and 8.4M x 28 uint8,
+# PERF.md §6, PR 35):
+
+# to contract: every weight tile of a row block's one-hot (features x padded
+# bins / 128 lanes) takes the block's 3 x padded channels of accumuland
+# pieces through one of 4 MXUs at 1.5 GHz, 128 rows a block
+# (``ops/hist_kernel.py``; PERF.md §6, PR 27: the kernel runs within 4-15%
+# of it, 845 / 209 / 10.9 ns a row at 2,000 x 255, 2,000 x 63 and 28 x 255)
+MXU_NS_PER_TILE_ROW = 1.0 / (4 * 1.5 * 128)
+# to make a bucket's row indices, a row of N: one sort of the rows' numbers
+# (0.4 ms at 320,000 rows and 18 at 8.4M inside the grower; alone 1.0 and
+# 19.7, ``nonzero`` 3.5 and 76.5, a cumulative sum and a scatter 2.2 and 52.1)
+INDEX_NS_PER_ROW = 2.5
+# to gather a row into the bucket: its accumulands and slot, and its bins by
+# the byte (inside the grower 23 ns a row of 28 bytes, 42 a row of 2,000)
+GATHER_NS_PER_ROW = 25.0
+GATHER_NS_PER_BYTE = 0.01
+# a rung is worth its branch where it saves a tenth of the full pass
+LEAST_SAVING = 0.1
+# rungs are counted in a vector of this length a tree (``rung_steps``)
+RUNGS = 8
+# under this many rows a pass is one grid step or two of the kernel
+LEAST_BUCKET_ROWS = 2048
+
+
+def rung_capacity(n: int, rung: int) -> int:
+    """Rows that rung ``rung`` of the ladder holds: all ``n`` at rung 0."""
+    return -(-int(n) // 2 ** rung)
+
+
+def rows_contracted(n: int, rung_steps) -> int:
+    """Rows a tree's contractions were handed: ``rung_steps[r]`` passes at
+    rung r of a grower over ``n`` rows (``TreeArrays.rung_steps``)."""
+    return sum(int(c) * rung_capacity(n, r) for r, c in enumerate(rung_steps))
+
+
+def contract_ns_per_row(features: int, num_bins: int, channels: int) -> float:
+    """What the one-hot contraction costs a row by ``tile_plan``'s own
+    arithmetic: weight tiles times streamed accumuland rows."""
+    from .obs.flops import padded_bins
+    cp = -(-int(channels) // 16) * 16
+    return features * padded_bins(num_bins) / 128 * 3 * cp \
+        * MXU_NS_PER_TILE_ROW
+
+
+def compact_ladder(n: int, features: int, row_bytes: int, num_bins: int,
+                   channels: int) -> tuple:
+    """The rungs below the full pass that ``[n, features]`` bins of
+    ``row_bytes`` a row get, as halvings of ``n`` in rising order: rung r is
+    there where making the indices over all rows, gathering its ``n / 2**r``
+    and contracting them costs at least ``LEAST_SAVING`` less than
+    contracting all ``n``, and its bucket is no smaller than
+    ``LEAST_BUCKET_ROWS``.  Short or empty where a row costs little more to
+    contract than to gather (a narrow table)."""
+    contract = contract_ns_per_row(features, num_bins, channels)
+    a_row = GATHER_NS_PER_ROW + GATHER_NS_PER_BYTE * row_bytes + contract
+    return tuple(
+        r for r in range(1, RUNGS)
+        if rung_capacity(n, r) >= LEAST_BUCKET_ROWS
+        and INDEX_NS_PER_ROW + a_row / 2 ** r
+        < (1.0 - LEAST_SAVING) * contract)
+
+
+def pick_rung(count, caps):
+    """Index into ``[full pass] + caps`` (``caps`` falling) of the smallest
+    bucket that holds ``count`` rows: a bucket is chosen only where ``count
+    <= cap`` was seen to hold, so it can never be too small."""
+    return functools.reduce(
+        jnp.add, [(count <= c).astype(jnp.int32) for c in caps],
+        jnp.int32(0))
+
+
+def row_reader(binned_view) -> Callable:
+    """``rows(idx) -> [len(idx), F]`` of the dense binned matrix for rising
+    row numbers ``idx`` (one past the end reads the last row).  Call it once
+    a tree, outside the grow loop, as ``column_reader``.
+
+    A matrix narrower than a vector register's lanes is turned once, so
+    that a row is gathered lane by lane from a copy that is the gather's
+    alone.  Gathered from the matrix itself, the TPU's compiler lays the
+    matrix out that way for the whole loop and turns it back for the kernel
+    at every pass over all rows (3 ms of 8.4M x 28 a step, under no scope;
+    PERF.md §6, PR 35).  A wide one is gathered as it lies."""
+    take = functools.partial(jnp.take, mode="clip", indices_are_sorted=True)
+    if binned_view.shape[1] >= LANES:
+        return lambda idx: take(binned_view, idx, axis=0)
+    with jax.named_scope("lgbtpu.hist.compact"):
+        by_column = binned_view.T
+    return lambda idx: take(by_column, idx, axis=1).T
+
+
+def contract_compacted(contract, binned_view, vals, tslot, rungs,
+                       rows: Optional[Callable] = None):
+    """``contract(binned_view, vals, tslot)`` over the target rows alone
+    (``tslot >= 0``; the rest add nothing to it), and the rung of the row
+    bucket it was handed, 0 for all rows.  ``rows`` is the matrix's
+    ``row_reader``, made outside the loop this is called in.
+
+    The target rows are compacted into the smallest bucket of ``rungs``
+    that holds them: their indices in rising order (a sort of the rows'
+    numbers with the others' set past the end: the rows keep their relative
+    order), then one ``take`` each of the bins, the accumulands and the
+    slots, under ``lgbtpu.hist.compact``; the bucket's unused rows carry
+    slot -1, which a contraction meets with no row of its one-hot.
+    The rungs are the branches of one ``switch``, chosen by ``pick_rung``
+    from the count of the very mask that is compacted; more rows than the
+    largest bucket holds, or no rung at all, is the pass over all rows."""
+    if not rungs:
+        return contract(binned_view, vals, tslot), jnp.int32(0)
+    n = vals.shape[0]
+    caps = [rung_capacity(n, r) for r in rungs]
+    rows = rows or row_reader(binned_view)
+    with jax.named_scope("lgbtpu.hist.compact"):
+        target = tslot >= 0
+        which = pick_rung(jnp.sum(target, dtype=jnp.int32), caps)
+
+    def bucket(cap):
+        def f():
+            with jax.named_scope("lgbtpu.hist.compact"):
+                # the bucket's unused places read ``n``, which every
+                # ``take`` clips to the last row
+                idx = jnp.sort(jnp.where(
+                    target, jnp.arange(n, dtype=jnp.int32), n))[:cap]
+                take = functools.partial(
+                    jnp.take, indices=idx, axis=0, mode="clip",
+                    indices_are_sorted=True)
+                s = jnp.where(idx < n, take(tslot), -1)
+                b, v = rows(idx), take(vals)
+            return contract(b, v, s)
+        return f
+
+    hist = lax.switch(
+        which, [lambda: contract(binned_view, vals, tslot)]
+        + [bucket(c) for c in caps])
+    return hist, jnp.asarray((0,) + tuple(rungs), jnp.int32)[which]
 
 
 def column_reader(binned) -> Optional[Callable]:
@@ -217,6 +358,9 @@ class TreeArrays(NamedTuple):
     #                              (== splits for strict leaf-wise; < splits
     #                              for split_batch>1 super-steps) — perf
     #                              observability, not part of the model
+    rung_steps: jax.Array        # [RUNGS] int32 — contractions of the tree
+    #                              by the rung of the row bucket they were
+    #                              handed (0: all rows; ``rows_contracted``)
 
 
 class _GrowState(NamedTuple):
@@ -271,8 +415,6 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 select_best: Optional[Callable] = None,
                 mono_view: Optional[Callable] = None,
                 subtract: bool = True,
-                gather: bool = False, min_gather_rows: int = 4096,
-                count_reduce: Optional[Callable] = None,
                 sum_reduce: Optional[Callable] = None,
                 efb=None,
                 gain_scale=None,
@@ -287,6 +429,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 quant=None,
                 scale_reduce: Optional[Callable] = None,
                 row_offset: Optional[Callable] = None,
+                vmapped: bool = False,
                 jit: bool = True):
     """Build a jitted ``grow_tree(binned, vals, feature_mask, num_bin, na_bin,
     na_bin_part=None)``.
@@ -328,20 +471,10 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
       while ``na_bin_part`` carries the global array for row partitioning.
     - select_best: cross-shard reduction of a SplitResult (feature-parallel
       argmax + feature-index globalization; identity for serial).
-    - gather/min_gather_rows: child histograms are built from a COMPACTED
-      row gather into the smallest power-of-2 capacity tier that fits the
-      child (``lax.switch`` over tiers), so per-split matmul work is
-      ∝ rows-in-smaller-child like the reference
-      (serial_tree_learner.cpp:283-323 smaller-leaf discipline;
-      cuda_histogram_constructor's leaf-indexed construction) instead of a
-      full-N masked pass.  Below ``min_gather_rows`` tiers stop (compile
-      cost isn't worth it).  DEFAULT OFF: measured on TPU v5e
-      XLA's row gather costs ~22 ns/row and ``nonzero`` ~3 ms/1M rows, so
-      the tiered path is ~2.4x SLOWER than the masked full pass it tries
-      to avoid; it also multiplies compile time by the tier count.
-    - count_reduce: makes the tier choice uniform across shards (pmax over
-      the mesh axis) so collectives inside the switch stay congruent; must
-      be set whenever hist_reduce crosses shards.
+    - vmapped: the caller runs the body under ``jax.vmap`` (the fleet's
+      member axis).  Not a choice of the user's: a fact about the caller
+      that the body cannot see, and the one exclusion of the row buckets
+      below that it must be told.
     - efb: an ``EFBDevice`` — ``binned`` is then the BUNDLED group matrix
       [N, G] (dataset.cpp:239 FastFeatureBundling); histograms are built
       and subtracted in the narrow group space (the HBM-bandwidth win) and
@@ -418,6 +551,22 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
       choice, ops/hist_tune.py) with the batched super-step.
       Sparse-binned data keeps the masked form (its per-slot total
       reduction has a different summation order).
+
+    **The rows a contraction is handed.**  A step's target rows (the
+    smaller child of each split) are compacted into the smallest row bucket
+    of ``compact_ladder``'s that holds them, and the contraction runs over
+    the bucket (``_contractor``, scope ``lgbtpu.hist.compact`` for the index
+    making and the gather); the ladder follows from the shapes alone and
+    may be empty.  Every product and every float32 sum of the full pass is
+    made, in another order of the blocks.  It applies to this function's
+    own body over dense rows that every worker holds whole, as the
+    followers do (``gbdt._followers``): the one-chip grower and the
+    feature-sharded one, the strict and the batched.  A ``hist_reduce``
+    hook (the row-sharded learners, whose shards would each pick a bucket
+    of their own around a collective), integer accumulands (``quant``), a
+    ``SparseBinned`` matrix and a ``vmapped`` body (a ``switch`` under
+    ``vmap`` runs every branch) keep the pass over all rows.  The tree
+    says what it was handed: ``TreeArrays.rung_steps``.
     """
     L_req = int(num_leaves)
     L = int(padded_leaves) if padded_leaves and int(padded_leaves) > L_req \
@@ -508,58 +657,45 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             return _expand(dequantize_hist(h, scales, axis=0), t)
         return vals, scales, scan_expand
 
-    def _make_child_hist(n: int, scales=None):
-        """Child-histogram builder: tiered gather (see ``gather`` above)
-        with a masked full-N pass as the top tier / fallback."""
-        caps = []
-        if gather:
-            c = int(min_gather_rows)
-            while c < n:
-                caps.append(c)
-                c *= 2
+    compacts = hist_reduce is None and not use_quant and not vmapped
 
-        def child_hist(binned_view, vals, leaf_of_row, child_id):
-            in_child = leaf_of_row == child_id
+    def _contractor(binned_view, vals, nslots, scales=None, masked=False):
+        """``contract(tslot) -> (histograms, rung)`` for one tree, made once
+        outside its grow loop: the histograms of the rows whose ``tslot`` is
+        not negative, by slot, as ``_hist`` returns them, and the rung of
+        the row bucket the contraction was handed (0: all rows;
+        ``contract_compacted`` over the ladder that the rule above and
+        ``compact_ladder`` give these shapes).  ``masked`` is the strict
+        grower's form without a slot: the other rows' accumulands zeroed."""
+        def pass_(b, v, s):
+            if masked:
+                return _hist(b, v * (s >= 0).astype(v.dtype)[:, None],
+                             scales=scales)
+            return _hist(b, v, s, nslots, scales=scales)
 
-            def full_pass(_):
-                if hist_overlap \
-                        and not isinstance(binned_view, _spd.SparseBinned):
-                    # overlap path: the mask rides as a 1-slot id so the
-                    # 0/1 multiply happens INSIDE the row-block scan —
-                    # byte-identical products, but the per-split scan
-                    # operand is one [N] int32 vector instead of a
-                    # fresh [N, 3] masked temp (see make_grower doc)
-                    sl = jnp.where(in_child, jnp.int32(0), jnp.int32(-1))
-                    return _hist(binned_view, vals, slot=sl, nslots=1,
-                                 scales=scales)
-                mask = in_child.astype(vals.dtype)[:, None]
-                return _hist(binned_view, vals * mask, scales=scales)
+        rungs, rows = (), None
+        if compacts and not isinstance(binned_view, _spd.SparseBinned):
+            f = binned_view.shape[1]
+            rungs = compact_ladder(
+                vals.shape[0], f, f * binned_view.dtype.itemsize, Bh,
+                vals.shape[1] * nslots)
+            rows = row_reader(binned_view) if rungs else None
+        return lambda tslot: contract_compacted(
+            pass_, binned_view, vals, tslot, rungs, rows)
 
-            if not caps:
-                return full_pass(None)
-            count = jnp.sum(in_child.astype(jnp.int32))
-            if count_reduce is not None:
-                count = count_reduce(count)
-            tier = jnp.searchsorted(jnp.asarray(caps, jnp.int32), count,
-                                    side="left")
-
-            def gather_tier(cap):
-                def f(_):
-                    idx = jnp.nonzero(in_child, size=cap, fill_value=n)[0]
-                    safe = jnp.minimum(idx, n - 1)
-                    if isinstance(binned_view, _spd.SparseBinned):
-                        b_g = binned_view.take_rows(safe)
-                    else:
-                        b_g = jnp.take(binned_view, safe, axis=0)
-                    v_g = jnp.take(vals, safe, axis=0) \
-                        * (idx < n)[:, None].astype(vals.dtype)
-                    return _hist(b_g, v_g, scales=scales)
-                return f
-
-            return lax.switch(tier, [gather_tier(c) for c in caps]
-                              + [full_pass], None)
-
-        return child_hist
+    def _child_contractor(binned_view, vals, scales=None):
+        """The strict grower's ``child_hist(leaf_of_row, child_id) ->
+        (histogram, rung)``, made once a tree.  With ``hist_overlap`` the
+        mask rides as a 1-slot id, so the 0/1 multiply happens INSIDE the
+        row-block scan — byte-identical products, but the per-split scan
+        operand is one [N] int32 vector instead of a fresh [N, 3] masked
+        temp (see make_grower doc)."""
+        contract = _contractor(
+            binned_view, vals, 1, scales,
+            masked=not hist_overlap
+            or isinstance(binned_view, _spd.SparseBinned))
+        return lambda leaf_of_row, child_id: contract(jnp.where(
+            leaf_of_row == child_id, jnp.int32(0), jnp.int32(-1)))
 
     def _partition_rows(binned, columns, leaf_of_row, is_cat, na_bin_part,
                         num_bin_part, leaf_k, new_leaf_k, feat_k, thr_k,
@@ -933,6 +1069,10 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 jnp.arange(B, dtype=jnp.int32)[None], (nnode, B)) + 0,
         )
 
+    def _root_pass():
+        """A tree's ``rung_steps`` before its first split: the root's pass."""
+        return jnp.zeros(RUNGS, jnp.int32).at[0].set(1)
+
     # every operation of a grower carries the scope ``lgbtpu.grow`` and,
     # inside it, its phase's (partition, hist.onehot, hist.contract,
     # hist.state, split): the device trace is folded by the innermost
@@ -968,7 +1108,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             vals, scales, scan_expand = _quant_prepare(
                 n, vals, feature_mask, rng_iter, n_leaves=2,
                 quant_seed=quant_seed)
-        child_hist = _make_child_hist(n, scales)
+        child_hist = _child_contractor(binned_view, vals, scales)
         if na_bin_part is None:
             na_bin_part = na_bin
         if num_bin_part is None:
@@ -985,14 +1125,14 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                          root_out, res0, cuse0)
 
         def split_step(carry):
-            st, _ = carry
+            st = carry[0]
             # one split per step, so the node id IS the split count so far
             i = st.num_leaves - 1
             leaf = jnp.argmax(st.bg).astype(jnp.int32)
             can_split = (st.bg[leaf] > 0.0) & (~st.done)
 
             def do_split(carry):
-                st, fleaves = carry
+                st, fleaves, rung_steps = carry
                 # partition-site static accounting (obs/flops.py): a
                 # trace-time Python side effect, zero runtime cost
                 from .obs.flops import note_traced, partition_flops_bytes
@@ -1024,8 +1164,8 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 # --- histograms: smaller child + subtraction --------------
                 smaller_left = lsum[2] <= rsum[2]
                 smaller_id = jnp.where(smaller_left, leaf, new_leaf)
-                hist_small = child_hist(binned_view, vals, leaf_of_row,
-                                        smaller_id)
+                hist_small, rung = child_hist(leaf_of_row, smaller_id)
+                rung_steps = rung_steps.at[rung].add(1)
                 if use_subtraction:
                     with jax.named_scope("lgbtpu.hist.state"):
                         hist_large = st.hist[leaf] - hist_small
@@ -1034,8 +1174,8 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                     # reduced hist feature sets differ between parent and
                     # children, so the larger child is constructed too
                     larger_id = jnp.where(smaller_left, new_leaf, leaf)
-                    hist_large = child_hist(binned_view, vals, leaf_of_row,
-                                            larger_id)
+                    hist_large, rung = child_hist(leaf_of_row, larger_id)
+                    rung_steps = rung_steps.at[rung].add(1)
                 with jax.named_scope("lgbtpu.hist.state"):
                     hl_leaf = jnp.where(smaller_left, hist_small, hist_large)
                     hl_new = jnp.where(smaller_left, hist_large, hist_small)
@@ -1135,11 +1275,12 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                     done=st.done,
                     is_cat_node=st.is_cat_node.at[i].set(icat),
                     cat_rank=st.cat_rank.at[i].set(rank_vec),
-                ), fleaves
+                ), fleaves, rung_steps
 
             return lax.cond(
                 can_split, do_split,
-                lambda c: (c[0]._replace(done=jnp.bool_(True)), c[1]), carry)
+                lambda c: (c[0]._replace(done=jnp.bool_(True)),) + c[1:],
+                carry)
 
         # while_loop, not a fixed L-1 fori_loop: a tree that stops early
         # (no positive gain) exits instead of running no-op tail steps —
@@ -1148,9 +1289,9 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         # state through the cond).  The exit bound is the TRACED actual
         # budget ``limit`` (== L unless leaf-padded), which is what lets
         # one padded trace serve a whole num_leaves bucket.
-        st, fleaves = lax.while_loop(
+        st, fleaves, rung_steps = lax.while_loop(
             lambda c: (~c[0].done) & (c[0].num_leaves < limit), split_step,
-            (st, fleaves0))
+            (st, fleaves0, _root_pass()))
         tree = TreeArrays(
             num_leaves=st.num_leaves,
             split_feature=st.split_feature,
@@ -1170,6 +1311,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             is_cat_node=st.is_cat_node,
             cat_rank=st.cat_rank,
             n_steps=st.num_leaves - 1,
+            rung_steps=rung_steps,
         )
         return tree if followers is None else (tree, fleaves)
 
@@ -1232,9 +1374,10 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         neg_inf = jnp.float32(-jnp.inf)
         kidx = jnp.arange(K, dtype=jnp.int32)
         nC = K if use_subtraction else 2 * K
+        contract = _contractor(binned_view, vals, nC, scales)
 
         def super_step(carry):
-            s, st, _ = carry
+            s, st = carry[:2]
             gains, leaves = lax.top_k(lax.slice_in_dim(st.bg, 0, L), K)
             num_nodes = st.num_leaves - 1
             budget = (limit - 1) - num_nodes
@@ -1244,7 +1387,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             can_split = valid[0]
 
             def do_split(carry):
-                st, fleaves = carry
+                st, fleaves, rung_steps = carry
                 # one partition pass serves all K splits of the super-
                 # step (trace-time note; obs/flops.py)
                 from .obs.flops import note_traced, partition_flops_bytes
@@ -1285,8 +1428,8 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                 fleaves = _follow(followers or (), fcolumns, fleaves, *step)
 
                 # --- batched child histograms: one C=3K contraction -------
-                hist_c = _hist(binned_view, vals, tslot, nC,
-                               scales=scales)                # [3nC, Fh, Bh]
+                hist_c, rung = contract(tslot)               # [3nC, Fh, Bh]
+                rung_steps = rung_steps.at[rung].add(1)
                 with jax.named_scope("lgbtpu.hist.state"):
                     hist_c = slot_histograms(hist_c, nC)     # [nC, 3, Fh, Bh]
                     if use_subtraction:
@@ -1425,11 +1568,11 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
                     done=st.done,
                     is_cat_node=st.is_cat_node.at[node_sel].set(icat_k),
                     cat_rank=st.cat_rank.at[node_sel].set(rank_k),
-                ), fleaves
+                ), fleaves, rung_steps
 
             return (s + 1,) + lax.cond(
                 can_split, do_split,
-                lambda c: (c[0]._replace(done=jnp.bool_(True)), c[1]),
+                lambda c: (c[0]._replace(done=jnp.bool_(True)),) + c[1:],
                 carry[1:])
 
         # while_loop, not a fixed trip count: a super-step splits only the
@@ -1441,9 +1584,9 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
         # moment the budget is exhausted or no leaf can split; the step
         # counter ``s`` is carried for the bynode RNG stream.  As in the
         # strict grower, the bound is the TRACED actual budget.
-        s_final, st, fleaves = lax.while_loop(
+        s_final, st, fleaves, rung_steps = lax.while_loop(
             lambda c: (~c[1].done) & (c[1].num_leaves < limit), super_step,
-            (jnp.int32(0), st, fleaves0))
+            (jnp.int32(0), st, fleaves0, _root_pass()))
         tree = TreeArrays(
             num_leaves=st.num_leaves,
             split_feature=st.split_feature[:L - 1],
@@ -1463,6 +1606,7 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
             is_cat_node=st.is_cat_node[:L - 1],
             cat_rank=st.cat_rank[:L - 1],
             n_steps=s_final,
+            rung_steps=rung_steps,
         )
         return tree if followers is None else (tree, fleaves)
 
@@ -1475,13 +1619,13 @@ def make_grower(*, num_leaves: int, num_bins: int, params: SplitParams,
     # hooks are callables (unkeyable) -> those growers jit privately.
     key = None
     if all(h is None for h in (hist_reduce, hist_view, hist_expand,
-                               select_best, mono_view, count_reduce,
-                               sum_reduce, scale_reduce, row_offset)):
+                               select_best, mono_view, sum_reduce,
+                               scale_reduce, row_offset)):
         key = _grower_key(dict(
             L=L, B=B, K=K, padded=padded, params=params,
             hist_overlap=hist_overlap,
             max_depth=max_depth, block_rows=block_rows, subtract=subtract,
-            gather=gather, min_gather_rows=min_gather_rows, efb=efb,
+            vmapped=vmapped, efb=efb,
             gain_scale=gain_scale, extra_trees=extra_trees,
             extra_seed=extra_seed, mono=mono, mono_penalty=mono_penalty,
             interaction_groups=interaction_groups, bynode_frac=bynode_frac,

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .grower import TreeArrays
+from .grower import RUNGS, TreeArrays
 from .ops.histogram import compute_histogram
 from .ops.split import (SplitParams, SplitResult, dequantize_hist,
                         find_best_split, leaf_output,
@@ -688,6 +688,8 @@ class PartitionedGrower:
             is_cat_node=jnp.asarray(is_cat_node),
             cat_rank=jnp.asarray(cat_rank),
             n_steps=jnp.int32(num_leaves - 1),
+            # this learner contracts row ranges of its own: no bucket
+            rung_steps=jnp.zeros(RUNGS, jnp.int32),
         )
 
     @staticmethod

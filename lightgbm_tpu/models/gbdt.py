@@ -1382,6 +1382,23 @@ class GBDTModel:
                 source="partition" if followed else "walk").inc(
                     trees * len(self.valid_sets))
 
+    def _note_contracted(self, rung_steps) -> None:
+        """What a new tree's contractions were handed (``TreeArrays
+        .rung_steps``): ``hist.rows_contracted``, the rows of all its
+        passes, and ``hist.compact_steps{rung=}``, its passes over a row
+        bucket by rung (``N/4``: a quarter of the grower's rows; grower.py
+        ``compact_ladder``).  The partitioned learner reports no pass."""
+        steps = np.asarray(rung_steps)
+        if self._obs is None or not steps.any():
+            return
+        from ..grower import rows_contracted
+        self._obs.metrics.histogram("hist.rows_contracted").observe(
+            rows_contracted(self.binned_dev.shape[0], steps))
+        for rung in np.flatnonzero(steps[1:]) + 1:
+            self._obs.metrics.counter(
+                "hist.compact_steps", rung=f"N/{2 ** rung}").inc(
+                    int(steps[rung]))
+
     def add_valid_set(self, valid: Dataset) -> None:
         valid.construct(self.config)
         nv = valid.num_data
@@ -1846,6 +1863,8 @@ class GBDTModel:
             cegb=self._cegb_state,
             padded_leaves=self._leaf_pad,
             quant=self._quant,
+            # the fleet runs this body under vmap over its members
+            vmapped=member_args,
             jit=False)
         if obj_parts is not None:
             arr_names = obj_parts[0]
@@ -2370,6 +2389,7 @@ class GBDTModel:
                 Log.warning(msg + "; iteration contributes nothing "
                                   "(finite_check_policy=skip_iter)")
                 self.step_counts.append(int(tj.n_steps))
+                self._note_contracted(tj.rung_steps)
                 ht = Tree(1)
                 ht.shrinkage = lr
                 ht.leaf_value = np.asarray(
@@ -2387,6 +2407,7 @@ class GBDTModel:
                     break
                 continue
             self.step_counts.append(int(tj.n_steps))
+            self._note_contracted(tj.rung_steps)
             lvj = np.asarray(tj.leaf_value, np.float64).copy()
             if self._cegb_state is not None and nl > 1:
                 # mirror the in-graph CEGB used-set update on the host so
@@ -2715,6 +2736,7 @@ class GBDTModel:
             # perf observability: grower loop steps per tree (== splits
             # for strict leaf-wise; the super-step count for split_batch)
             self.step_counts.append(int(host.n_steps))
+            self._note_contracted(host.rung_steps)
             if "cegb_used" in gkw and nl > 1:
                 self._cegb_state.used[
                     np.asarray(host.split_feature)[:nl - 1]] = True

@@ -95,7 +95,8 @@ def _dp_out_specs(axis: str) -> TreeArrays:
         default_left=P(), left_child=P(), right_child=P(), split_gain=P(),
         leaf_value=P(), leaf_weight=P(), leaf_count=P(), internal_value=P(),
         internal_weight=P(), internal_count=P(), leaf_depth=P(),
-        leaf_of_row=P(axis), is_cat_node=P(), cat_rank=P(), n_steps=P())
+        leaf_of_row=P(axis), is_cat_node=P(), cat_rank=P(), n_steps=P(),
+        rung_steps=P())
 
 
 def owner_hist_reduce(axis: str, n_shards: int, chunk: int,

@@ -164,7 +164,8 @@ def _build(mesh: Mesh, *, num_leaves, num_bins, params, top_k, max_depth,
         default_left=P(), left_child=P(), right_child=P(), split_gain=P(),
         leaf_value=P(), leaf_weight=P(), leaf_count=P(), internal_value=P(),
         internal_weight=P(), internal_count=P(), leaf_depth=P(),
-        leaf_of_row=P(axis), is_cat_node=P(), cat_rank=P(), n_steps=P())
+        leaf_of_row=P(axis), is_cat_node=P(), cat_rank=P(), n_steps=P(),
+        rung_steps=P())
 
     def wrapped(binned, vals, fm, nb, na, nabp, ic, ml, ri):
         return inner(binned, vals, fm, nb, na, nabp, ic, rng_iter=ri,

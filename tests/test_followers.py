@@ -73,20 +73,22 @@ def test_a_grower_without_followers_returns_the_tree_alone():
 
 
 # sha256 (16 hex digits) of ``str(jax.make_jaxpr(grow)(...))`` of the
-# follower-less grower, taken from the parent commit (ab57858): what
-# ``lgb.train`` without a held-out set traces is the program it traced before
-# followers existed.  A later change to the grower re-pins these from its own
-# parent, beside the trees' digests.
+# follower-less grower: what ``lgb.train`` without a held-out set traces is
+# the program it traced before followers existed.  Taken at PR 33 from its
+# parent commit (ab57858) and held until PR 35, which changed the grower's
+# program itself (the count of a tree's contractions by rung rides the loop)
+# and re-pinned these from its own tree, beside the trees' digests, which did
+# not move.  A later change to the grower does the same.
 FOLLOWERLESS_JAXPR = {
-    "cell": "1e67a8d6236b4480",
-    "efb_bundles_k8": "10599145fd710849",
-    "no_subtraction_k8": "c5be9e94c9a74652",
-    "strict_nan_categorical": "658862c9de036cee",
+    "cell": "204a02ecf454022e",
+    "efb_bundles_k8": "6778b60e2593a08d",
+    "no_subtraction_k8": "9bdb380046019327",
+    "strict_nan_categorical": "a8b4e151a038de58",
 }
 
 
 @pytest.mark.parametrize("name", list(FOLLOWERLESS_JAXPR))
-def test_the_followerless_grower_traces_the_parents_program(name):
+def test_the_followerless_grower_traces_the_pinned_program(name):
     opts, args, kw = cell_shaped_grower() if name == "cell" \
         else partition_case(name)
     grow = make_grower(jit=False, **opts)
