@@ -1,11 +1,8 @@
-"""Seeded inputs of Epsilon's shape.
-
-A copy of ``bench.py``'s ``make_epsilon_like`` (listed in PERF.md's open
-questions for deletion there): wide, dense, normalised features and two
-balanced classes, without Epsilon's bytes, since the run has no network.
+"""Seeded inputs of Epsilon's shape: wide, dense, normalised features and
+two balanced classes, without Epsilon's bytes, since the run has no network.
 A configuration names its generator (``data.generator``) and ``run.py``
-finds ``generators/<name>.py`` by that name, so a cell of another shape adds
-a file here; ``bench.py``'s ``make_higgs_like`` is the one to copy for HIGGS.
+finds ``generators/<name>.py`` by that name, so a cell whose columns are of
+another kind adds a file here.
 """
 
 import numpy as np
@@ -13,9 +10,8 @@ import numpy as np
 
 def make(n_rows: int, n_features: int, seed: int):
     """Epsilon's shape: wide, dense, normalised features and a label that
-    is linear in the first 16 of them plus noise (``bench.py``'s
-    ``make_epsilon_like``), drawn in float32 row chunks so that the host
-    never holds a float64 copy."""
+    is linear in the first 16 of them plus noise, drawn in float32 row
+    chunks so that the host never holds a float64 copy."""
     if n_features < 16:
         raise ValueError("epsilon_like needs at least 16 features")
     rng = np.random.default_rng(int(seed))

@@ -1,6 +1,6 @@
 """Share of the traced job's iterations that ran inside a scan-fused program
-(a super-epoch or a fused chunk), from the telemetry counters: scan programs
-run times the rounds each covers, over train.iterations.  100 means the
+(a super-epoch), from the telemetry counters: scan programs run times the
+rounds each covers, over train.iterations.  100 means the
 defaults engaged a scan loop for every iteration, 0 that the per-iteration
 loop ran them all (lgb.cv).  Not capped: a reading over 100 is a miscount."""
 
@@ -14,5 +14,5 @@ def read(ctx):
     done = _value(c, "train.iterations")
     if not done:
         return None
-    scans = _value(c, "train.superepochs") + _value(c, "train.fused_chunks")
-    return 100.0 * ctx.get("scan_rounds", 0) * scans / done
+    return 100.0 * ctx.get("scan_rounds", 0) \
+        * _value(c, "train.superepochs") / done

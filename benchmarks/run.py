@@ -385,10 +385,15 @@ def worst_of(followed, curves, recorded) -> dict:
            "count_gap": max(r["count_gap"] for r in flat),
            "leaf_gap_median": max(float(np.median(r["leaf_gap"]))
                                   for r in flat),
-           "leaf_gap_max": max(float(r["leaf_gap"].max()) for r in flat),
-           "gain_gap_median": max(float(np.median(r["gain_gap"]))
-                                  for r in flat)}
-    short = [r["split_shortfall"] for r in flat if "split_shortfall" in r]
+           "leaf_gap_max": max(float(r["leaf_gap"].max()) for r in flat)}
+    # a tree may hold no split whose gain is more than rounding
+    # (reference.NOUGHT): it has leaves to compare and no gain
+    gains = [float(np.median(r["gain_gap"])) for r in flat
+             if len(r["gain_gap"])]
+    if gains:
+        got["gain_gap_median"] = max(gains)
+    short = [r["split_shortfall"] for r in flat
+             if len(r.get("split_shortfall", ()))]
     if short:
         got["split_shortfall"] = float(np.concatenate(short).max())
     if recorded is not None:
@@ -433,6 +438,12 @@ def split_check_of(cell: dict, trees, n_features: int, seed: int):
             "min_data": params.get("min_data_in_leaf", 20)}
 
 
+def routes_missing(trees) -> bool:
+    """Whether any node of ``trees`` routes missing values by a direction of
+    its own: the program met NaN in that column when it fitted its bins."""
+    return any((tree.missing_type == "NaN").any() for tree in trees)
+
+
 def fault_readings(trees, x, y, kw, recorded) -> dict:
     """What the comparison reads with a fault planted in the trees that the
     reference is given, at the run's own size, on the job's first booster:
@@ -468,6 +479,15 @@ def fault_readings(trees, x, y, kw, recorded) -> dict:
         sound = ref.follow(trees, x, y, **kw)
         out["state_unchanged"]["auc_gap"] = max(
             abs(r["auc"] - s["auc"]) for r, s in zip(stuck, sound))
+    if routes_missing(trees):
+        # every node's default direction ignored: the missing rows all go
+        # right, as under a reference that routes ``value <= threshold``
+        # alone (or a partition that does)
+        ignored = copy.deepcopy(trees)
+        for tree in ignored:
+            tree.default_left[:] = False
+        out["direction_ignored"] = worst_of(
+            [ref.follow(ignored, x, y, **no_valid)], None, None)
     return out
 
 
@@ -509,7 +529,8 @@ def compare(cell: dict, members, recorded, x, y, xv, yv, seed: int,
             # against the reference's, on the same trees and rows
             low = [dict(count_gap=0.0,
                         leaf_gap=ref.rel_gap(c["value"], r["value"]),
-                        gain_gap=ref.rel_gap(c["gain"], r["gain"]),
+                        gain_gap=ref.rel_gap(c["gain"][r["gains_compared"]],
+                                             r["gain"][r["gains_compared"]]),
                         auc=c.get("auc"))
                    for c, r in zip(ref.follow(trees, x, y,
                                               accumuland="bfloat16", **kw),
@@ -543,16 +564,22 @@ def compare(cell: dict, members, recorded, x, y, xv, yv, seed: int,
                          for k, c in low_compared.items()}}
         readings["faults"] = fault_readings(first[0], x, y, first[1],
                                             recorded)
-        runner = [r["runner_up"] for out in followed for r in out
-                  if "runner_up" in r]
-        if runner:
-            runner = np.concatenate(runner)
-            # a search that takes the second-best feature at every node:
-            # the compared number is the worst node's, the least node's
-            # says how close two features can lie
-            readings["faults"]["second_best_feature"] = {
-                "split_shortfall": float(runner.max()),
-                "least_node": float(runner.min())}
+        # a search that takes the second-best feature at every node, and
+        # one that never places a node's missing rows left (read where the
+        # trees route missing values: without them it is the sound search):
+        # the compared number is the worst node's, the least node's says
+        # how close two features, or the two placements, can lie
+        searches = {"second_best_feature": "runner_up"}
+        if any(routes_missing(trees) for _, (trees, _), _ in results):
+            searches["one_direction_search"] = "missing_right_only"
+        for fault, key in searches.items():
+            found = [r[key] for out in followed for r in out
+                     if len(r.get(key, ()))]
+            if found:
+                found = np.concatenate(found)
+                readings["faults"][fault] = {
+                    "split_shortfall": float(found.max()),
+                    "least_node": float(found.min())}
         readings["per_booster"] = [worst_of([out], None, None)
                                    for out in followed]
     return compared, readings

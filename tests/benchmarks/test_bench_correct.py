@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from bench_testlib import (CV_CELL, HIGGS_CELL, HIGGS_ON_CPU, HIGGS_SMALL,
-                           ON_CPU, ROOT,
-                           SMALL, TRAIN_CELL, root_with_train_cell, v5e_peak)
+                           ON_CPU, ROOT, SMALL, TRAIN_CELL,
+                           root_with_train_cell, v5e_peak)
 
 from benchmarks import reference, run
 
@@ -32,8 +32,8 @@ def failed(result, root=ROOT):
                   or ("at_least" in lim[k] and c["value"] < c["limit"]))
 
 
-def small_data(seed, cell=CV_CELL, sizes=SMALL):
-    x, y, _, _ = run.make_data(run.load_cell(cell), seed, sizes, False)
+def small_data(seed, cell=CV_CELL, sizes=SMALL, root=ROOT):
+    x, y, _, _ = run.make_data(run.load_cell(cell, root), seed, sizes, False)
     return x, y
 
 
@@ -118,10 +118,10 @@ def doctored_cv(lgb, alter_of_fold):
     return cv
 
 
-def state_unchanged(lgb, seed, cell, sizes):
+def state_unchanged(lgb, seed, cell, sizes, root=ROOT):
     """Every step returns its scores unchanged: each tree is grown from
     the first tree's gradients again."""
-    _, y = small_data(seed, cell, sizes)
+    _, y = small_data(seed, cell, sizes, root)
 
     def alter_of_fold(k, rows):
         s0 = reference.init_score(y[rows].astype(np.float64))
@@ -136,7 +136,7 @@ def state_unchanged(lgb, seed, cell, sizes):
     return doctored_cv(lgb, alter_of_fold)
 
 
-def half_batch(lgb, seed, cell, sizes):
+def half_batch(lgb, seed, cell, sizes, root=ROOT):
     """Half of every fold's rows left out; means are taken over the rest."""
     def cv(params, ds, num_boost_round, folds, **kw):
         halved = [(tr[:len(tr) // 2], te) for tr, te in folds]
@@ -145,7 +145,7 @@ def half_batch(lgb, seed, cell, sizes):
     return cv
 
 
-def answer_altered(lgb, seed, cell, sizes):
+def answer_altered(lgb, seed, cell, sizes, root=ROOT):
     """One leaf of one fold's second tree says the opposite."""
     def alter(model):
         worst = max(leaves_of(model["tree_info"][1]["tree_structure"]),
@@ -154,28 +154,32 @@ def answer_altered(lgb, seed, cell, sizes):
     return doctored_cv(lgb, lambda k, rows: alter if k == 3 else None)
 
 
-def metric_altered(lgb, seed, cell, sizes):
+def metric_altered(lgb, seed, cell, sizes, root=ROOT, off=1e-3):
     """The held-out layer: a mean AUC reported a thousandth off what the
     folds' trees give."""
     def cv(params, ds, num_boost_round, folds, **kw):
         out = lgb.cv(params, ds, num_boost_round=num_boost_round,
                      folds=folds, **kw)
         out["valid auc-mean"] = list(out["valid auc-mean"])
-        out["valid auc-mean"][-1] += 1e-3
+        out["valid auc-mean"][-1] += off
         return out
     return cv
 
 
-def best_feature_overlooked(lgb, seed, cell, sizes):
+def best_feature_overlooked(lgb, seed, cell, sizes, root=ROOT):
     """The split search never sees the feature that separates best: the
     program trains on a matrix whose strongest column is noise, the
     reference searches the true one."""
-    x, y = small_data(seed, cell, sizes)
+    x, y = small_data(seed, cell, sizes, root)
     p = float(y.mean())
+    # under the cell's own floor: without one, under a rare label, the
+    # "strongest" column is whichever has a failed part at its far end
+    floor = run.load_cell(cell, root)["config"]["params"][
+        "min_sum_hessian_in_leaf"]
     strongest = int(np.argmax(reference.best_exact_gains(
         x, np.arange(len(x)), p - y.astype(np.float64),
         np.full(len(x), p * (1 - p)), np.arange(x.shape[1]),
-        min_hess=0.0, min_data=1)))
+        min_hess=floor, min_data=1)))
 
     def cv(params, ds, num_boost_round, folds, **kw):
         blind = x.copy()
@@ -205,6 +209,15 @@ def test_planted_fault_is_not_correct(fault, catches, cell, sizes, params):
               **params)
     assert not r["correct"]
     assert catches in failed(r), r["compared"]
+
+
+def test_a_table_without_holes_reads_neither_fault_of_the_directions():
+    """Neither fault is read where no node routes a missing value: the
+    trees say so, no key of a cell or a configuration."""
+    r = drive(CV_CELL, 11, control=True)
+    assert set(r["readings"]["faults"]) == {
+        "state_unchanged", "answer_altered", "half_batch",
+        "second_best_feature"}
 
 
 def test_altered_metric_value_on_the_train_mix_is_not_correct(tmp_path):
@@ -242,43 +255,136 @@ def test_round_bfloat16_keeps_eight_bits():
     assert abs(got[2] + 3.14159) < 2.0 ** -7
 
 
+def three_node_tree(default_left, missing_type):
+    """Node 0 splits column 2 at 0.5 and its left child, node 1, column 0
+    at -0.25; leaves 0, 1 (under node 1) and 2."""
+    return reference.FlatTree(
+        split_feature=np.array([2, 0]), threshold=np.array([0.5, -0.25]),
+        left=np.array([1, ~0]), right=np.array([~2, ~1]),
+        split_gain=np.ones(2), internal_count=np.array([500, 0]),
+        leaf_value=np.zeros(3), leaf_count=np.zeros(3, np.int64),
+        shrinkage=1.0, default_left=np.array(default_left),
+        missing_type=np.array(missing_type))
+
+
 def test_route_on_rows_is_route_on_their_copy():
     rng = np.random.default_rng(3)
     x = rng.standard_normal((500, 6)).astype(np.float32)
-    tree = reference.FlatTree(
-        split_feature=np.array([2, 0]), threshold=np.array([0.1, -0.3]),
-        left=np.array([1, ~0]), right=np.array([~1, ~2]),
-        split_gain=np.ones(2), internal_count=np.array([500, 0]),
-        leaf_value=np.zeros(3), leaf_count=np.zeros(3, np.int64),
-        shrinkage=1.0)
+    x[rng.random((500, 6)) < 0.3] = np.nan
+    tree = three_node_tree([True, False], ["NaN", "NaN"])
     rows = rng.permutation(500)[:137]
     assert (reference.route(tree, x, rows)
             == reference.route(tree, x[rows])).all()
     assert reference.node_depths(tree).tolist() == [0, 1]
 
 
+@pytest.mark.parametrize("default_left,missing_type,leaves", [
+    # column 2 missing: left at node 0; then column 0 missing: right
+    ([True, False], ["NaN", "NaN"], {"both": 1, "col2": 0, "col0": 1}),
+    ([False, True], ["NaN", "NaN"], {"both": 2, "col2": 2, "col0": 0}),
+    # a node of type None takes a NaN as 0.0: 0.0 <= 0.5 is left at node
+    # 0 whatever its default_left says, 0.0 <= -0.25 is right at node 1
+    ([False, True], ["None", "None"], {"both": 1, "col2": 0, "col0": 1}),
+    ([False, False], ["None", "NaN"], {"both": 1, "col2": 0, "col0": 1})],
+    ids=["left-right", "right-left", "none-none", "none-nan"])
+def test_route_sends_a_missing_value_where_the_node_says(
+        default_left, missing_type, leaves):
+    """Upstream's ``NumericalDecision`` on three rows: column 2 and column
+    0 missing; column 2 alone (column 0 is -1.0: left at node 1); column 0
+    alone (column 2 is 0.0: left at node 0)."""
+    nan = np.float32(np.nan)
+    x = np.zeros((3, 6), np.float32)
+    x[0, [0, 2]] = nan
+    x[1, 2], x[1, 0] = nan, -1.0
+    x[2, 0] = nan
+    got = reference.route(three_node_tree(default_left, missing_type), x)
+    assert got.tolist() == [leaves["both"], leaves["col2"], leaves["col0"]]
+
+
+@pytest.mark.parametrize("default_left", [
+    [False, False], [True, True], [True, False]], ids=str)
+def test_route_on_a_table_without_holes_is_the_plain_walk(default_left):
+    """Leaf for leaf what ``value <= threshold`` alone gives, whatever the
+    nodes' directions and types say."""
+    rng = np.random.default_rng(4)
+    x = rng.standard_normal((700, 6)).astype(np.float32)
+    x[:50, 2], x[50:90, 0] = 0.5, -0.25        # values on the thresholds
+    want = np.where(x[:, 2] <= 0.5, np.where(x[:, 0] <= -0.25, 0, 1), 2)
+    assert {0, 1, 2} == set(want[:90])
+    for missing_type in (["NaN", "NaN"], ["None", "NaN"]):
+        tree = three_node_tree(default_left, missing_type)
+        assert (reference.route(tree, x) == want).all()
+
+
+def dumped(missing_type):
+    leaf = {"leaf_value": 0.5, "leaf_count": 3}
+    return {"num_leaves": 2, "shrinkage": 0.1, "tree_structure": {
+        "split_index": 0, "split_feature": 4, "split_gain": 2.0,
+        "threshold": 1.5, "decision_type": "<=", "default_left": True,
+        "missing_type": missing_type, "internal_count": 6,
+        "left_child": dict(leaf, leaf_index=0),
+        "right_child": dict(leaf, leaf_index=1)}}
+
+
+@pytest.mark.parametrize("missing_type", ["NaN", "None"])
+def test_flatten_tree_reads_each_nodes_direction_and_type(missing_type):
+    tree = reference.flatten_tree(dumped(missing_type))
+    assert tree.default_left.tolist() == [True]
+    assert tree.missing_type.tolist() == [missing_type]
+    assert (tree.split_feature[0], tree.threshold[0]) == (4, 1.5)
+
+
+def test_flatten_tree_refuses_zero_as_missing():
+    """One semantics a PR: ``zero_as_missing`` is not followed."""
+    with pytest.raises(ValueError, match="not Zero"):
+        reference.flatten_tree(dumped("Zero"))
+
+
+@pytest.mark.parametrize("missing", [0, 37, 120])
 @pytest.mark.parametrize("min_hess,min_data", [(0.0, 1), (5.0, 1), (0.0, 40)])
-def test_best_exact_gains_against_every_threshold_tried(min_hess, min_data):
+def test_best_exact_gains_against_every_threshold_tried(min_hess, min_data,
+                                                        missing):
+    """A loop over every threshold and, where the node has missing rows,
+    over both sides for them (with them right, the last real value is a
+    threshold too: all real rows against the missing ones); without any, a
+    loop over ``value <= t`` alone."""
     rng = np.random.default_rng(5)
     n = 200
     x = np.round(rng.standard_normal((n, 3)), 1).astype(np.float32)  # ties
+    # both signs of NaN: the one with its sign bit set sorts before -inf
+    x[rng.permutation(n)[:missing], 0] = np.nan
+    x[rng.permutation(n)[:missing], 2] = -np.float32(np.nan)
     g = rng.standard_normal(n)
     h = rng.uniform(0.05, 0.25, n)
     rows = rng.permutation(n)[:150]
-    got = reference.best_exact_gains(x, rows, g[rows], h[rows], [0, 2],
-                                     min_hess=min_hess, min_data=min_data)
-    for f, gain in zip([0, 2], got):
+    kw = dict(min_hess=min_hess, min_data=min_data)
+    got = reference.best_exact_gains(x, rows, g[rows], h[rows], [0, 2], **kw)
+    placed = reference.exact_gains(x, rows, g[rows], h[rows], [0, 2], **kw)
+    assert (got == placed.max(axis=1)).all()
+    for f, gain, (right, left) in zip([0, 2], got, placed):
         v, gg, hh = x[rows, f], g[rows], h[rows]
-        best = -np.inf
-        for t in np.unique(v)[:-1]:
-            left = v <= t
-            if min(hh[left].sum(), hh[~left].sum()) < min_hess or \
-                    min(left.sum(), (~left).sum()) < min_data:
-                continue
-            best = max(best, gg[left].sum() ** 2 / hh[left].sum()
-                       + gg[~left].sum() ** 2 / hh[~left].sum()
-                       - gg.sum() ** 2 / hh.sum())
-        assert gain == pytest.approx(best, rel=1e-9)
+        nan = np.isnan(v)
+        real = np.unique(v[~nan])
+        best = {"right": -np.inf, "left": -np.inf}
+        for side in (("right", "left") if nan.any() else ("right",)):
+            cuts = real if side == "right" and nan.any() else real[:-1]
+            for t in cuts:
+                goes_left = (v <= t) | (nan & (side == "left"))
+                if min(hh[goes_left].sum(), hh[~goes_left].sum()) \
+                        < min_hess or min(goes_left.sum(),
+                                          (~goes_left).sum()) < min_data:
+                    continue
+                best[side] = max(
+                    best[side],
+                    gg[goes_left].sum() ** 2 / hh[goes_left].sum()
+                    + gg[~goes_left].sum() ** 2 / hh[~goes_left].sum()
+                    - gg.sum() ** 2 / hh.sum())
+        assert right == pytest.approx(best["right"], rel=1e-9)
+        assert left == pytest.approx(best["left"], rel=1e-9)
+        assert gain == max(best.values()) or gain == pytest.approx(
+            max(best.values()), rel=1e-9)
+        if not missing:
+            assert left == -np.inf
 
 
 @pytest.mark.parametrize("cell,scan_share,steps", [

@@ -38,13 +38,18 @@ def test_the_manifest_has_the_configuration_its_cell_and_its_metric():
     cell = entry("workloads", CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == (CONFIG, "cv5", 1)
-    # added at the end of their lists, beside what was there
-    assert m["configs"][-1]["name"] == CONFIG
-    assert m["workloads"][-1]["name"] == CELL
-    metric = m["per_layer"][-1]
-    assert metric == {"name": "grower_temp_gib", "unit": "GiB",
-                      "better": "lower", "source": "program_counter",
-                      "layer": "grower", "moves": "train_iter_s"}
+    # added beside what was there, and before what came later: entries are
+    # looked up by name, so a later cell or metric breaks nothing here
+    for kind, name, later in (("configs", CONFIG, "epsilon-b255-fp4"),
+                              ("workloads", CELL, "epsilon-b255-fp4.cv5"),
+                              ("per_layer", "grower_temp_gib",
+                               "sync_iter_ms")):
+        names = [e["name"] for e in m[kind]]
+        assert 0 < names.index(name) < names.index(later)
+    assert entry("per_layer", "grower_temp_gib") == {
+        "name": "grower_temp_gib", "unit": "GiB", "better": "lower",
+        "source": "program_counter", "layer": "grower",
+        "moves": "train_iter_s"}
 
 
 def test_no_width_is_cut_and_only_the_bin_count_differs_from_epsilon_l255():
@@ -76,7 +81,7 @@ def test_the_cell_is_found_by_name_with_limits_of_its_own():
     assert cell["rounds"] == 2 and cell["traffic"]["entry"] == "cv"
     assert cell["config"]["params"]["max_bin"] == 255
     assert set(cell["limits"]) == set(run.load_cell(CV_CELL)["limits"])
-    assert [m["name"] for m in cell["per_layer"]][-1] == "grower_temp_gib"
+    assert "grower_temp_gib" in [m["name"] for m in cell["per_layer"]]
 
 
 @pytest.mark.parametrize("seed", [31, 2 ** 31 + 32])

@@ -18,6 +18,7 @@ WIDTH = re.compile(r"(_dim|_rank)$|hidden|intermediate|latent|head|expan")
 # (cut only where a chip or a run's time forces it); where each is run from
 SHAPES = {"features": "data", "max_bin": "params", "num_leaves": "params"}
 SCALE = {"train_rows": "data", "valid_rows": "data"}
+HIGGS_CONFIG = "higgs-l255"     # the configuration that config_with edits
 
 
 def metric_entries():
@@ -151,10 +152,11 @@ def test_a_configuration_of_another_shape_is_added_by_data_alone(tmp_path):
 
 
 def config_with(**changes):
-    """The second configuration's entry and file body, with ``changes`` to
-    what it runs (``data`` or ``params`` keys) and to ``reduced``."""
+    """The entry and file body of ``HIGGS_CONFIG`` (the narrow table at 255
+    bins: the cases below say what they change those from), with ``changes``
+    to what it runs (``data`` or ``params`` keys) and to ``reduced``."""
     m = manifest()
-    cfg = dict(m["configs"][-1])
+    cfg = dict(next(c for c in m["configs"] if c["name"] == HIGGS_CONFIG))
     with open(os.path.join(ROOT, cfg["file"])) as fh:
         body = json.load(fh)
     for key, value in changes.items():

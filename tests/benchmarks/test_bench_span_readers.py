@@ -115,12 +115,14 @@ def test_the_span_readers_metrics_are_in_the_manifest():
 
 SCOPED = {"lgbtpu.hist.contract": 1300.0, "lgbtpu.partition": 220.0,
           "lgbtpu.hist.state": 80.0, "lgbtpu.split": 150.0,
-          "lgbtpu.walk": 190.0, "lgbtpu.grow": 5.0, "(no scope)": 8.0}
+          "lgbtpu.walk": 190.0, "lgbtpu.grow": 5.0, "(no scope)": 8.0,
+          "lgbtpu.hist.compact": 57.0}
 
 
 @pytest.mark.parametrize("name,ms", [
     ("contract_iter_ms", 1300.0), ("partition_iter_ms", 220.0 + 80.0),
-    ("split_iter_ms", 150.0), ("walk_iter_ms", 190.0)])
+    ("split_iter_ms", 150.0), ("walk_iter_ms", 190.0),
+    ("compact_iter_ms", 57.0)])
 def test_scope_reader_reads_its_scopes(name, ms):
     assert reader(name)({"scope_iter_ms": SCOPED}) == pytest.approx(ms)
     # the scan's one-hot is the contraction's too
@@ -131,11 +133,13 @@ def test_scope_reader_reads_its_scopes(name, ms):
 
 
 @pytest.mark.parametrize("name", ["contract_iter_ms", "partition_iter_ms",
-                                  "split_iter_ms", "walk_iter_ms"])
+                                  "split_iter_ms", "walk_iter_ms",
+                                  "compact_iter_ms"])
 def test_scope_reader_reads_nothing_without_its_scopes(name):
     """Off the chip or from a trace that came without its scopes (nothing
     folded), and in a job that never ran the scope (no held-out rows: no
-    walk): the metric is left out, never 0."""
+    walk; a table whose rule compacts nothing): the metric is left out,
+    never 0."""
     assert reader(name)({}) is None
     assert reader(name)({"scope_iter_ms": {}}) is None
     assert reader(name)({"scope_iter_ms": {"lgbtpu.grow": 5.0}}) is None

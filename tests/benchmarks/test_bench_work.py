@@ -13,9 +13,11 @@ def three_leaf_tree():
     return {"num_leaves": 3, "shrinkage": 0.1, "tree_structure": {
         "split_index": 0, "split_feature": 2, "split_gain": 9.0,
         "threshold": 0.5, "decision_type": "<=", "internal_count": 1000,
+        "default_left": True, "missing_type": "None",
         "left_child": {
             "split_index": 1, "split_feature": 0, "split_gain": 4.0,
             "threshold": -1.0, "decision_type": "<=", "internal_count": 700,
+            "default_left": False, "missing_type": "NaN",
             "left_child": {"leaf_index": 0, "leaf_value": 0.1,
                            "leaf_count": 450},
             "right_child": {"leaf_index": 2, "leaf_value": -0.2,
