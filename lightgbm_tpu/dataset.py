@@ -203,6 +203,28 @@ class Dataset:
     produces the packed binned matrix.
     """
 
+    # ``binned`` is kept in ``_binned``; a row subset keeps instead its
+    # parent's matrix and its sorted rows (``_binned_rows``) and gathers
+    # them when ``binned`` is first read, so that ``lgb.cv`` can gather a
+    # fold's rows on the device (fold_rows.py) and every other caller gets
+    # the same array as before
+    _binned = None
+    _binned_rows = None
+    # the ``lgb.cv`` call (fold_rows.FoldRows) that hands this Dataset to a
+    # fold's Booster, while it sets that fold up
+    _fold_rows = None
+
+    @property
+    def binned(self) -> Optional[np.ndarray]:
+        if self._binned_rows is not None:
+            parent, idx = self._binned_rows
+            self._binned, self._binned_rows = parent[idx], None
+        return self._binned
+
+    @binned.setter
+    def binned(self, value: Optional[np.ndarray]) -> None:
+        self._binned, self._binned_rows = value, None
+
     def __init__(self, data, label=None, weight=None, group=None, init_score=None,
                  feature_name: Union[str, List[str]] = "auto",
                  categorical_feature: Union[str, List] = "auto",
@@ -995,16 +1017,24 @@ class Dataset:
         """Row-subset copy (Dataset::CopySubrow, dataset.h:486 analog).
         Indices are SORTED like the reference python subset (basic.py
         used_indices sort) — rows keep their original relative order.
+        The binned rows are gathered when ``binned`` is first read, so that
+        ``lgb.cv`` can gather a fold's on the device instead (fold_rows.py).
         ``_obs``: the telemetry session of the booster this subset is
-        made for (``lgb.cv``), which times the gather."""
+        made for (``lgb.cv``), which times the gathers."""
         self.construct()
         if _obs is not None:
             _sp = _obs.span("dataset.subset", rows=len(used_indices))
-        idx = np.sort(np.asarray(used_indices, dtype=np.int64))
+        idx = np.array(used_indices, dtype=np.int64)
+        if (idx[1:] < idx[:-1]).any():
+            idx.sort()      # lgb.cv's folds come sorted: no sort for them
+        parent_binned = self.binned
         sub = Dataset.__new__(Dataset)
         sub.__dict__.update({k: v for k, v in self.__dict__.items()})
         sub.num_data = len(idx)
-        sub.binned = self.binned[idx] if self.binned is not None else None
+        sub.binned = None
+        if parent_binned is not None:
+            sub._binned_rows = (parent_binned, idx)
+        sub._fold_rows = None
         sub.binned_sparse = self.binned_sparse.subset_rows(idx) \
             if self.binned_sparse is not None else None
         # raw rows slice cheaply when the parent holds them in memory;
@@ -1032,9 +1062,9 @@ class Dataset:
             sub.metadata.set_group(np.unique(qidx, return_counts=True)[1])
         sub.reference = self
         if _obs is not None:
-            _obs.end_setup(_sp, bytes=sum(
-                int(a.nbytes) for a in (sub.binned, sub.raw_data)
-                if isinstance(a, np.ndarray)))
+            # the rows gathered here: the raw values (the bins wait)
+            _obs.end_setup(_sp, bytes=int(sub.raw_data.nbytes)
+                           if isinstance(sub.raw_data, np.ndarray) else 0)
         return sub
 
     def _group_from_parent(self, parent: "Dataset", idx: np.ndarray) -> None:

@@ -404,12 +404,13 @@ class GBDTModel:
                 stochastic=(config.quant_round == "stochastic"),
                 seed=int(config.seed))
 
-        if self._sparse:
-            feat_binned = ds.binned_sparse.flat
-        elif self._use_efb:
-            feat_binned = ds.binned
-        else:
-            feat_binned = ds.feature_binned()
+        # a fold of an lgb.cv call whose rows that call's placement serves
+        # is gathered from it on the device (fold_rows.py); the rest is
+        # gathered and sent from the host
+        fold_rows = self._fold_rows_of(ds)
+        if fold_rows is None:
+            feat_binned = ds.binned_sparse.flat if self._sparse \
+                else ds.binned if self._use_efb else ds.feature_binned()
         num_bin = np.asarray([ds.bin_mappers[f].num_bin for f in ds.used_features],
                              np.int32)
         na_bin = np.asarray([ds.bin_mappers[f].na_bin for f in ds.used_features],
@@ -471,10 +472,11 @@ class GBDTModel:
             n_sh = self._mesh.shape[self._dist_axis]
             self._feat_pad = (-self.num_features) % n_sh
             if self._feat_pad:
-                feat_binned = np.concatenate(
-                    [feat_binned, np.zeros((feat_binned.shape[0],
-                                            self._feat_pad),
-                                           feat_binned.dtype)], axis=1)
+                if fold_rows is None:
+                    feat_binned = np.concatenate(
+                        [feat_binned, np.zeros((feat_binned.shape[0],
+                                                self._feat_pad),
+                                               feat_binned.dtype)], axis=1)
                 pad_i = np.full(self._feat_pad, 2, np.int32)
                 self._nb_grow = jnp.asarray(np.concatenate([num_bin, pad_i]))
                 self._na_grow = jnp.asarray(np.concatenate(
@@ -486,14 +488,22 @@ class GBDTModel:
             # once, replicated and committed, and the columns' metadata as
             # the grower's shard_map takes it.  Left on one device, jit
             # sends the matrix to the others at every tree
-            self.binned_dev = self._on_mesh(feat_binned)
+            if fold_rows is None:
+                self.binned_dev = self._on_mesh(feat_binned)
+            else:
+                self.binned_dev, sent = fold_rows.take(
+                    ds, self._use_efb, self._rows_at(),
+                    col_pad=self._feat_pad)
             self._na_part = self._on_mesh(self._na_grow)
             self._nb_grow = self._on_mesh(self._nb_grow, self._dist_axis)
             self._na_grow = self._on_mesh(self._na_grow, self._dist_axis)
             if self._ic_grow is not None:
                 self._ic_grow = self._on_mesh(self._ic_grow, self._dist_axis)
-        else:
+        elif fold_rows is None:
             self.binned_dev = jnp.asarray(feat_binned)
+        else:
+            self.binned_dev, sent = fold_rows.take(ds, self._use_efb,
+                                                   self._rows_at())
         if self._sparse:
             # wrap the (possibly sharded) flat entry matrix as the pytree
             # the grower/traversal paths dispatch on
@@ -502,7 +512,9 @@ class GBDTModel:
                 self.binned_dev, jnp.asarray(ds.binned_sparse.default_bin),
                 ds.binned_sparse.stride, self.num_features)
         if obs is not None:
-            obs.end_to_device(_sp, self.binned_dev)
+            # a gathered matrix was sent as its row numbers
+            obs.end_to_device(_sp, self.binned_dev if fold_rows is None
+                              else sent)
 
         # split_batch resolution (config.py): 0 = auto -> strict leaf-wise
         # below 64 leaves, K-way super-steps above (the
@@ -1331,12 +1343,45 @@ class GBDTModel:
         return self._on_mesh(x) if self._dist == "feature" \
             else jnp.asarray(x)
 
+    def _rows_at(self):
+        """Where ``_row_state`` puts rows: the mesh's replicated sharding,
+        or None for the default device."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        return NamedSharding(self._mesh, PartitionSpec(None)) \
+            if self._dist == "feature" else None
+
     def _on_mesh(self, x, axis: Optional[str] = None):
         """``x`` placed on the learner's mesh and committed there:
         replicated, or its leading axis split over ``axis``."""
         from jax.sharding import NamedSharding, PartitionSpec
         return jax.device_put(
             x, NamedSharding(self._mesh, PartitionSpec(axis)))
+
+    def _fold_rows_of(self, ds: Dataset):
+        """The ``lgb.cv`` call's placement that ``ds``'s rows are gathered
+        from (fold_rows.py), or None where they go from the host.  The rule:
+        ``ds`` is a row subset of the matrix that call placed, nothing has
+        read its bins, and the learner reads dense rows that every device
+        holds whole from one process (the serial learner, EFB groups or
+        not, and the feature-sharded one); sparse k-hot rows, the
+        row-sharded learners (row padding, ``shard_rows``) and several
+        processes take the host's path.  The matrix is placed here, before
+        the caller's own placement span opens, where this call has not yet
+        placed it; a fold's matrix counts once in
+        ``cv.fold_rows{source=device|host}``."""
+        src = ds._fold_rows
+        if src is None:
+            return None
+        if not (not self._sparse and self._dist in (None, "feature")
+                and self._pc == 1 and src.serves(ds)):
+            src = None
+        if self._obs is not None:
+            self._obs.metrics.counter(
+                "cv.fold_rows",
+                source="host" if src is None else "device").inc()
+        if src is not None:
+            src.place(self._use_efb, self._rows_at(), self._obs)
+        return src
 
     @staticmethod
     def _interaction_allow(config: Config, ds: Dataset):
@@ -1404,12 +1449,12 @@ class GBDTModel:
         nv = valid.num_data
         pad = 0
         obs = self._obs
+        fold_rows = self._fold_rows_of(valid)
         if obs is not None:
             _sp = obs.span("booster.to_device", what="valid")
         if valid.binned_sparse is not None:
             binned = valid.binned_sparse.to_device()
         else:
-            vb = valid.binned if self._use_efb else valid.feature_binned()
             if self._trace_buckets and nv <= (1 << 20):
                 # row-bucket the valid set (utils/shapes.py pow2 policy)
                 # so the per-iteration score-update traversal — and
@@ -1421,11 +1466,17 @@ class GBDTModel:
                 # one-time retrace, so huge valid sets keep exact shapes.
                 from ..utils.shapes import bucket_rows
                 pad = bucket_rows(nv, min_bucket=256) - nv
+            # held-out rows lie where the training rows do (_followers)
+            if fold_rows is not None:
+                binned, sent = fold_rows.take(valid, self._use_efb,
+                                              self._rows_at(), pad_rows=pad)
+            else:
+                vb = valid.binned if self._use_efb \
+                    else valid.feature_binned()
                 if pad:
                     vb = np.concatenate(
                         [vb, np.zeros((pad, vb.shape[1]), vb.dtype)])
-            # held-out rows lie where the training rows do (_followers)
-            binned = self._row_state(vb)
+                binned = self._row_state(vb)
         init = np.zeros((nv + pad, self.num_class), np.float32)
         if valid.metadata.init_score is not None:
             init[:nv] += np.asarray(valid.metadata.init_score, np.float32) \
@@ -1447,7 +1498,8 @@ class GBDTModel:
                                  * self.models[ti].predict(raw))
         score = self._row_state(init)
         if obs is not None:
-            obs.end_to_device(_sp, (binned, score))
+            obs.end_to_device(
+                _sp, (binned if fold_rows is None else sent, score))
         # replay existing device trees (continued training)
         for ti, dt in enumerate(self.device_trees):
             mi = n_host_only + ti

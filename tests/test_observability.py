@@ -605,17 +605,23 @@ class TestJobSpans:
                     assert parent_name(e) == "booster.init"
                 elif name == "booster.to_device":
                     assert parent_name(e) == {
+                        "parent": "booster.init",
                         "binned": "booster.init", "row_state": "booster.init",
                         "valid": "cv.fold_setup"}[e["args"]["what"]]
+            # the call's matrix is placed once, in the first fold's set-up
+            assert [e["args"]["what"] for e in spans
+                    if e["name"] == "lgbtpu.booster.to_device"
+                    and e["args"]["what"] == "parent"] \
+                == (["parent"] if fold == 0 else [])
             setup = next(e for e in spans
                          if e["name"] == "lgbtpu.cv.fold_setup")
             assert setup["args"] == {"fold": fold}
             subsets = [e for e in spans
                        if e["name"] == "lgbtpu.dataset.subset"]
             assert sorted(e["args"]["rows"] for e in subsets) == [600, 600]
-            # the gathered rows: uint8 bins and the float64 raw values
-            assert all(e["args"]["bytes"] == 600 * 8 * (1 + 8)
-                       for e in subsets)
+            # the rows gathered on the host: the float64 raw values (the
+            # fold's bins are gathered on the device)
+            assert all(e["args"]["bytes"] == 600 * 8 * 8 for e in subsets)
             evals = [e for e in spans if e["name"] == "lgbtpu.eval"]
             assert [e["args"]["rows"] for e in evals] == [600, 600]
         # the shared sink holds both boosters' spans, told apart by id
@@ -629,10 +635,16 @@ class TestJobSpans:
         for fold, bst in enumerate(boosters):
             m, snap = bst._model, bst.telemetry_snapshot()
             spans = _spans(bst)
-            placed = [m.binned_dev, m.score, m.objective.label] \
-                + [a for _, vb, vs in m.valid_sets for a in (vb, vs)]
-            assert snap["xfer.h2d_bytes"]["value"] \
-                == sum(int(a.nbytes) for a in placed)
+            # the call's matrix once (the first fold's), then a fold's row
+            # numbers where its matrices are gathered on the device, and
+            # its row state
+            rows = [m.num_data, m.valid_sets[0][1].shape[0]]
+            placed = 4 * sum(rows) + sum(int(a.nbytes) for a in (
+                [m.score, m.objective.label] + [vs for _, _, vs in
+                                                m.valid_sets]))
+            if fold == 0:
+                placed += int(bst.train_set.reference.binned.nbytes)
+            assert snap["xfer.h2d_bytes"]["value"] == placed
             assert sum(e["args"]["bytes"] for e in spans
                        if e["name"] == "lgbtpu.booster.to_device") \
                 == snap["xfer.h2d_bytes"]["value"]
@@ -642,7 +654,8 @@ class TestJobSpans:
             assert snap[f"grower.memo{{result={result}}}"]["value"] == 1.0
             assert ("jax.traces{name=grower}" in snap) == (fold == 0)
             for stage, n in (("fold_setup", 1), ("subset", 2),
-                             ("booster_init", 1), ("to_device", 3),
+                             ("booster_init", 1),
+                             ("to_device", 4 if fold == 0 else 3),
                              ("grower_make", 1)):
                 h = snap[f"train.setup_seconds{{stage={stage}}}"]
                 assert h["count"] == n
