@@ -11,6 +11,7 @@ round-trip and remain ecosystem-readable.
 
 from __future__ import annotations
 
+import functools
 import io
 import os
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
@@ -248,7 +249,7 @@ class Booster:
         m = self._model
         obs = m._obs
         if obs is not None:
-            _sp = obs.span("eval", rows=sum(
+            _sp = obs.span("eval", mirror=False, rows=sum(
                 vs[0].num_data for vs in m.valid_sets))
         spec = tuple(
             (vi, name, mt.name, mt.is_higher_better)
@@ -258,8 +259,14 @@ class Booster:
         svecs = tuple(vs[:, 0] for _, _, vs in m.valid_sets)
         ops = tuple(m._se_valid_dev(vi)
                     for vi in range(len(m.valid_sets)))
-        vals = m._eget(fn(svecs, ops), "traced_eval")
+        dev = fn(svecs, ops)
         if obs is not None:
+            # the metric runs on the device (scope ``lgbtpu.eval``): the
+            # host's part is the fetch that waits for it
+            _fp = obs.span("eval.fetch")
+        vals = m._eget(dev, "traced_eval")
+        if obs is not None:
+            obs.end_eval_part(_fp)
             obs.end_eval(_sp)
         return [(name, mn, float(vals[e]), hib)
                 for e, (vi, name, mn, hib) in enumerate(spec)]
@@ -457,31 +464,45 @@ class Booster:
 
     # ------------------------------------------------------------------
     def eval_train(self, feval=None) -> List[Tuple]:
-        obs = self._model._obs
-        if obs is not None:
-            _sp = obs.span("eval", rows=self.train_set.num_data)
-        score = self._model.train_score()
-        out = self._eval_set(getattr(self, "_train_data_name", "training"),
-                             score, self._train_metrics,
-                             self.train_set, feval)
-        if obs is not None:
-            obs.end_eval(_sp)
-        return out
+        m = self._model
+        return self._eval_sets([(
+            getattr(self, "_train_data_name", "training"), m.score,
+            m.train_score, self._train_metrics, self.train_set)], feval)
 
     def eval_valid(self, feval=None) -> List[Tuple]:
+        m = self._model
+        return self._eval_sets([
+            (name, m.valid_sets[i][2], functools.partial(m.valid_score, i),
+             self._valid_metrics[i], m.valid_sets[i][0])
+            for i, name in enumerate(self._valid_names)], feval)
+
+    def _eval_sets(self, sets, feval) -> List[Tuple]:
+        """``_eval_set`` of each ``(name, dev, fetch, metrics, dataset)``
+        in ``sets``: ``fetch()`` brings the device score ``dev`` to the
+        host.  With telemetry on, one ``eval`` span, and in it, a set
+        after another, the two spans that partition it: ``eval.fetch``
+        (the transfer, which blocks by itself; its bytes, the row
+        bucket's padding too, go to ``xfer.d2h_bytes{site=eval}``) and
+        ``eval.metric`` (the host metrics and ``feval``, which queue no
+        device work).  No fence."""
         obs = self._model._obs
         if obs is not None:
-            # the score's fetch and the host metric: no fence needed
-            _sp = obs.span("eval", rows=sum(
-                vs[0].num_data for vs in self._model.valid_sets))
+            sp = obs.span("eval", mirror=False,
+                          rows=sum(ds.num_data for *_, ds in sets))
         out = []
-        for i, name in enumerate(self._valid_names):
-            score = self._model.valid_score(i)
-            ds = self._model.valid_sets[i][0]
-            out.extend(self._eval_set(name, score, self._valid_metrics[i],
-                                      ds, feval))
+        for name, dev, fetch, metrics, ds in sets:
+            if obs is not None:
+                part = obs.span("eval.fetch")
+            score = fetch()
+            if obs is not None:
+                obs.end_eval_part(part)
+                obs.count_d2h("eval", dev)
+                part = obs.span("eval.metric")
+            out.extend(self._eval_set(name, score, metrics, ds, feval))
+            if obs is not None:
+                obs.end_eval_part(part)
         if obs is not None:
-            obs.end_eval(_sp)
+            obs.end_eval(sp)
         return out
 
     def _eval_set(self, name, score, metrics, dataset, feval) -> List[Tuple]:

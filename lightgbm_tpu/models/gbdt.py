@@ -1158,11 +1158,17 @@ class GBDTModel:
         inside the collective deadline (``parallel/elastic.guarded_get``:
         a hung collective materializes at this blocking fetch, gets
         stack-dumped, abandoned, and classified as an ElasticFailure
-        instead of hanging the run); otherwise a plain device fetch."""
+        instead of hanging the run); otherwise a plain device fetch.
+        With telemetry on, the fetched bytes go to
+        ``xfer.d2h_bytes{site=}``."""
         if self._elastic is not None and self._elastic_timeout > 0:
-            return self._elastic.guarded_get(x, self._elastic_timeout,
+            host = self._elastic.guarded_get(x, self._elastic_timeout,
                                              site=site)
-        return jax.device_get(x)
+        else:
+            host = jax.device_get(x)
+        if self._obs is not None:
+            self._obs.count_d2h(site, host)
+        return host
 
     def integrity_boundary_check(self) -> None:
         """Shadow-verify the newest committed tree right before a

@@ -74,10 +74,12 @@ class ObsSession:
     The GBDT driver holds ``self._obs`` (None when ``telemetry=false``)
     and brackets its work through ``span`` + ``end_setup`` (once a
     booster), ``iter_begin`` / ``phase`` + ``end_phase`` / ``iter_end``
-    (every iteration) and ``span`` + ``end_eval`` — see models/gbdt.py,
+    (every iteration) and ``span`` + ``end_eval`` around ``span`` +
+    ``end_eval_part`` (every evaluation) — see models/gbdt.py,
     booster.py, engine.cv.  A span goes to the tracer, to the profiler's
-    host plane (all but the enclosing ``iter``) and, through its
-    ``end_*``, to the registry.  All methods here may sync the device
+    host plane (all but the enclosing ``iter`` and ``eval``) and, through
+    its ``end_*``, to the registry; ``count_d2h`` counts what a fetch
+    brought to the host.  All methods here may sync the device
     (that is their job: attributing time to phases needs fences); none
     of them run when telemetry is off.
     """
@@ -107,10 +109,10 @@ class ObsSession:
     # -- spans -------------------------------------------------------------
     def span(self, name: str, mirror: bool = True, **args) -> Span:
         """Open ``lgbtpu.<name>`` on the tracer and, unless it encloses
-        other spans' whole job (``iter``, a super-epoch:
+        other spans' whole job (``iter``, ``eval``, a super-epoch:
         ``mirror=False``), on the profiler's host plane.  Close it
-        through ``end_setup`` / ``end_phase`` / ``end_eval`` so that the
-        registry sees it too."""
+        through ``end_setup`` / ``end_phase`` / ``end_eval`` /
+        ``end_eval_part`` so that the registry sees it too."""
         return self.tracer.span(SPAN_PREFIX + name, mirror=mirror, **args)
 
     def end_setup(self, sp: Span, **args) -> float:
@@ -191,10 +193,33 @@ class ObsSession:
 
     def end_eval(self, sp: Span) -> float:
         """Close an ``eval`` span (a metric evaluation and its fetch: it
-        runs between iterations, so it has a family of its own)."""
+        runs between iterations, so it has a family of its own).  It
+        encloses its ``eval.fetch`` and ``eval.metric`` children, so it is
+        opened with ``mirror=False``, as ``iter`` is."""
         dur = sp.end()
         self.metrics.histogram("train.eval_seconds").observe(dur)
         return dur
+
+    def end_eval_part(self, sp: Span) -> float:
+        """Close an ``eval.fetch`` or ``eval.metric`` span, the two parts
+        of an ``eval``, into ``train.eval_part_seconds{part=}``.  Not
+        fenced: a fetch blocks by itself and the host metric queues no
+        device work."""
+        dur = sp.end()
+        self.metrics.histogram(
+            "train.eval_part_seconds",
+            part=sp.name[len(SPAN_PREFIX + "eval."):]).observe(dur)
+        return dur
+
+    def count_d2h(self, site: str, host) -> None:
+        """Count the bytes a fetch brought to the host into
+        ``xfer.d2h_bytes{site=}``, the twin of ``xfer.h2d_bytes``: the
+        ``nbytes`` of the arrays fetched (host or device: the same
+        number), so no device work and no sync."""
+        import jax
+        nbytes = sum(int(getattr(a, "nbytes", 0))
+                     for a in jax.tree_util.tree_leaves(host))
+        self.metrics.counter("xfer.d2h_bytes", site=site).inc(nbytes)
 
     def adopt_construct_seconds(self, dataset) -> None:
         """The seconds ``Dataset.construct`` kept of its own stages, from

@@ -230,23 +230,6 @@ class Tracer:
                 self._sink = None
 
 
-def timed_fenced(fn, iters: int = 10, tracer: Optional[Tracer] = None,
-                 name: str = "timed") -> tuple:
-    """Run ``fn`` ``iters`` times, fencing its return value each rep;
-    returns (min_seconds, avg_seconds).  The successor of the private
-    ``bench_phase`` helpers in tools/ — one definition of "how we time a
-    device-side phase"."""
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        fence(fn())
-        dt = time.perf_counter() - t0
-        ts.append(dt)
-        if tracer is not None:
-            tracer._emit(name, t0, dt, {})
-    return min(ts), sum(ts) / len(ts)
-
-
 # -- JSONL <-> Perfetto ----------------------------------------------------
 
 def read_jsonl(path: str) -> List[Dict[str, Any]]:

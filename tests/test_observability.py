@@ -31,7 +31,7 @@ from lightgbm_tpu.obs import ObsSession, maybe_session
 from lightgbm_tpu.obs.comm import CommLedger, wire_bytes
 from lightgbm_tpu.obs.metrics import MetricsRegistry, aggregate_snapshots
 from lightgbm_tpu.obs.trace import (Tracer, fence, jsonl_to_chrome,
-                                    read_jsonl, timed_fenced)
+                                    read_jsonl)
 from lightgbm_tpu.utils.log import Log
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
@@ -211,14 +211,6 @@ class TestTrace:
         assert fence(x) is x
         assert fence(None) is None
         assert fence({"a": x, "b": 3}) is not None    # non-arrays pass
-
-    def test_timed_fenced(self):
-        import jax.numpy as jnp
-        tr = Tracer()
-        mn, avg = timed_fenced(lambda: jnp.arange(4.0) + 1, iters=3,
-                               tracer=tr, name="probe")
-        assert 0 < mn <= avg
-        assert len(tr.durations("probe")) == 3
 
     def test_training_emits_phase_spans(self, tmp_path):
         sink = str(tmp_path / "train.jsonl")
@@ -531,18 +523,21 @@ class TestSpanRecord:
         tr.instant("marker")
         assert "id" not in tr.events[-1]
 
-    def test_self_time_is_duration_less_children(self):
-        import time
+    def test_self_time_is_duration_less_children(self, monkeypatch):
+        # an injected clock: "layer" opens at 0 and closes at 13 ms,
+        # "below" runs from 2 to 12 ms, whatever the machine's load
+        clock = iter([0.0, 0.002, 0.012, 0.013])
+        monkeypatch.setattr(Tracer, "now", staticmethod(lambda: next(clock)))
         tr = Tracer(pid=0)
         with tr.span("layer"):
-            time.sleep(0.002)
             with tr.span("below"):
-                time.sleep(0.01)
+                pass
         layer, below = (next(e for e in tr.events if e["name"] == n)
                         for n in ("layer", "below"))
         own = layer["dur"] - sum(e["dur"] for e in tr.events
                                  if e.get("parent") == layer["id"])
-        assert 0 < own < below["dur"]
+        assert (layer["dur"], below["dur"]) == (13000.0, 10000.0)
+        assert own == 3000.0
 
     def test_a_span_left_open_by_an_exception_is_dropped(self):
         tr = Tracer(pid=0)
@@ -603,6 +598,8 @@ class TestJobSpans:
                     assert parent_name(e) == "cv.fold_setup"
                 elif name == "grower.make":
                     assert parent_name(e) == "booster.init"
+                elif name in ("eval.fetch", "eval.metric"):
+                    assert parent_name(e) == "eval"
                 elif name == "booster.to_device":
                     assert parent_name(e) == {
                         "parent": "booster.init",
@@ -724,9 +721,9 @@ class TestJobSpans:
 class TestProfilerClock:
     def test_host_plane_holds_the_partition_and_no_enclosing_span(self):
         """Under a profiler session (as benchmarks/run.py::Trace opens
-        it) the program's spans are host events beside jax's; the span
-        that encloses a whole iteration is not among them, or it would
-        name every idle gap."""
+        it) the program's spans are host events beside jax's; the spans
+        that enclose a whole iteration or evaluation are not among them,
+        or they would name every idle gap in their children's place."""
         import jax
         from jax._src.lib import _profiler
         x, y = _small_data()
@@ -746,10 +743,14 @@ class TestProfilerClock:
                 if ev.name.startswith("lgbtpu.")]
         assert host.count("lgbtpu.cv.fold_setup") == 2
         assert host.count("lgbtpu.grow") == 4
-        assert host.count("lgbtpu.eval") == 4
-        assert "lgbtpu.iter" not in host
+        # an evaluation is its two parts on the host plane, not the span
+        # that encloses them
+        assert host.count("lgbtpu.eval.fetch") == 4
+        assert host.count("lgbtpu.eval.metric") == 4
+        assert "lgbtpu.iter" not in host and "lgbtpu.eval" not in host
         assert set(host) == {"lgbtpu." + n for n in
-                             SETUP_SPANS | set(ITER_PHASES) | {"eval"}}
+                             SETUP_SPANS | set(ITER_PHASES)
+                             | {"eval.fetch", "eval.metric"}}
 
     def test_no_annotation_without_telemetry(self, monkeypatch):
         import jax.profiler
