@@ -20,7 +20,8 @@ import numpy as np
 
 from .config import Config
 from .dataset import Dataset
-from .metrics import Metric, create_metric
+from .metrics import (AUC_DEVICE_MAX_ROWS, AUCMetric, Metric, auc_classes,
+                      auc_from_parts, auc_rank_parts, create_metric)
 from .models import create_boosting
 from .objectives import create_objective
 from .tree_model import Tree
@@ -169,11 +170,26 @@ class Booster:
             raise ValueError("cannot add validation data to a loaded model")
         data.reference = self.train_set
         data.construct(self.config)
-        self._model.add_valid_set(data)
+        metrics = self._make_metrics(data.metadata, data.num_data)
+        ranked = [m for m in metrics if self._ranks_on_device(m)]
+        classes = self._model.add_valid_set(
+            data, auc_classes(ranked[0].label) if ranked else None)
+        for m in ranked:
+            m.classes = classes
         self._valid_names.append(name)
-        self._valid_metrics.append(self._make_metrics(data.metadata,
-                                                      data.num_data))
+        self._valid_metrics.append(metrics)
         return self
+
+    def _ranks_on_device(self, metric: Metric) -> bool:
+        """Whether a held-out set's ``metric`` is an AUC that the device
+        ranks (``metrics.auc_rank_parts``): unweighted, one tree a round,
+        not ``rf`` (whose host score ``valid_score`` divides by the
+        rounds), and no more rows than ``_auc`` counts exactly.  The rest
+        keep the host's ``_auc``, to the same value."""
+        return (isinstance(metric, AUCMetric) and metric.weight is None
+                and self._num_tree_per_iteration == 1
+                and self._model.config.boosting != "rf"
+                and metric.num_data <= AUC_DEVICE_MAX_ROWS)
 
     def _make_metrics(self, metadata, num_data) -> List:
         """Configured metric objects bound to one dataset's metadata."""
@@ -479,37 +495,62 @@ class Booster:
     def _eval_sets(self, sets, feval) -> List[Tuple]:
         """``_eval_set`` of each ``(name, dev, fetch, metrics, dataset)``
         in ``sets``: ``fetch()`` brings the device score ``dev`` to the
-        host.  With telemetry on, one ``eval`` span, and in it, a set
-        after another, the two spans that partition it: ``eval.fetch``
-        (the transfer, which blocks by itself; its bytes, the row
-        bucket's padding too, go to ``xfer.d2h_bytes{site=eval}``) and
-        ``eval.metric`` (the host metrics and ``feval``, which queue no
-        device work).  No fence."""
+        host.  An AUC whose ``classes`` the device holds is ranked there
+        (``metrics.auc_rank_parts``) and only its exact parts come back;
+        the score crosses only where a host metric or ``feval`` reads it.
+        With telemetry on, one ``eval`` span, and in it, a set after
+        another, the two spans that partition it: ``eval.fetch`` (the wait
+        for the device's parts and the score's transfer, which block by
+        themselves; their bytes, the row bucket's padding too, go to
+        ``xfer.d2h_bytes{site=eval}``) and ``eval.metric`` (the division
+        of the parts, the host metrics and ``feval``, which queue no
+        device work); each AUC counts once in
+        ``eval.auc_route{route=device|host}``.  No fence."""
         obs = self._model._obs
         if obs is not None:
             sp = obs.span("eval", mirror=False,
                           rows=sum(ds.num_data for *_, ds in sets))
         out = []
         for name, dev, fetch, metrics, ds in sets:
+            ranked = [m for m in metrics
+                      if getattr(m, "classes", None) is not None]
+            launched = auc_rank_parts(dev, ranked[0].classes) \
+                if ranked else None
+            on_host = feval is not None or len(ranked) < len(metrics)
             if obs is not None:
                 part = obs.span("eval.fetch")
-            score = fetch()
+            parts = self._model._eget(launched, "eval") if ranked else None
+            score = fetch() if on_host else None
             if obs is not None:
                 obs.end_eval_part(part)
-                obs.count_d2h("eval", dev)
+                if on_host:
+                    obs.count_d2h("eval", dev)
+                for m in metrics:
+                    if isinstance(m, AUCMetric):
+                        obs.metrics.counter(
+                            "eval.auc_route",
+                            route="device" if m in ranked else "host").inc()
                 part = obs.span("eval.metric")
-            out.extend(self._eval_set(name, score, metrics, ds, feval))
+            out.extend(self._eval_set(name, score, metrics, ds, feval,
+                                      ranked, parts))
             if obs is not None:
                 obs.end_eval_part(part)
         if obs is not None:
             obs.end_eval(sp)
         return out
 
-    def _eval_set(self, name, score, metrics, dataset, feval) -> List[Tuple]:
-        s = score[:, 0] if self._num_tree_per_iteration == 1 else score
+    def _eval_set(self, name, score, metrics, dataset, feval,
+                  ranked=(), parts=None) -> List[Tuple]:
+        """The values of ``metrics`` and ``feval`` over the host
+        ``score``; the AUCs in ``ranked`` take theirs from ``parts``,
+        ``auc_rank_parts``' fetched output."""
+        s = score[:, 0] if score is not None \
+            and self._num_tree_per_iteration == 1 else score
         results = []
         for m in metrics:
-            for mname, val, hib in m.eval(s):
+            got = [(m.name, auc_from_parts(*parts), True)] \
+                if m in ranked else m.eval(s)
+            for mname, val, hib in got:
                 results.append((name, mname, val, hib))
         if feval is not None:
             for fe in (feval if isinstance(feval, (list, tuple)) else [feval]):

@@ -1450,7 +1450,13 @@ class GBDTModel:
                 "hist.compact_steps", rung=f"N/{2 ** rung}").inc(
                     int(steps[rung]))
 
-    def add_valid_set(self, valid: Dataset) -> None:
+    def add_valid_set(self, valid: Dataset,
+                      classes: Optional[np.ndarray] = None
+                      ) -> Optional[jax.Array]:
+        """Place held-out set ``valid``: its bins and its score, and
+        ``classes`` (``metrics.auc_classes`` of its rows, where an AUC of
+        it is ranked on the device) padded like the score; returns those
+        classes on the device, or None."""
         valid.construct(self.config)
         nv = valid.num_data
         pad = 0
@@ -1503,9 +1509,11 @@ class GBDTModel:
                 init[:nv, k] += (self.tree_weights[ti]
                                  * self.models[ti].predict(raw))
         score = self._row_state(init)
+        if classes is not None:
+            classes = self._row_state(np.pad(classes, (0, pad)))
         if obs is not None:
             obs.end_to_device(
-                _sp, (binned if fold_rows is None else sent, score))
+                _sp, (binned if fold_rows is None else sent, score, classes))
         # replay existing device trees (continued training)
         for ti, dt in enumerate(self.device_trees):
             mi = n_host_only + ti
@@ -1524,6 +1532,7 @@ class GBDTModel:
                     score[:, k], binned, dt, self.na_bin_dev,
                     self.tree_weights[mi], self.efb_maps))
         self.valid_sets.append((valid, binned, score))
+        return classes
 
     # -- sampling (gbdt.cpp:230 Bagging + goss.hpp) ------------------------
     @property

@@ -130,7 +130,8 @@ def test_the_matrix_is_placed_once_a_booster(data, placements):
     # every device is sent every row: the counter holds the matrix, the
     # held-out rows and the small row state once for each device
     snap = bst.telemetry_snapshot()
-    small = 4 * (3000 + 3000 + 1024)       # score, label, valid score
+    # score, label, valid score; the held-out AUC's int8 class codes
+    small = 4 * (3000 + 3000 + 1024) + 1024
     assert snap["xfer.h2d_bytes"]["value"] \
         == WORKERS * (matrix + 1024 * F + small)
     assert placements.count(matrix) == 1

@@ -205,8 +205,9 @@ def test_a_jobs_transfers_are_one_matrix_and_the_row_state():
         held = model.valid_sets[0]
         state = [model.score, model.objective.label, held[2]]
         rows = model.num_data + held[1].shape[0]       # the row numbers
+        # and the held-out AUC's class codes, one byte a held-out row
         assert snap["xfer.h2d_bytes"]["value"] == 4 * rows + sum(
-            int(a.nbytes) for a in state) \
+            int(a.nbytes) for a in state) + held[2].shape[0] \
             + (int(ds.binned.nbytes) if fold == 0 else 0)
         # the placement is a span of the first fold's set-up, under it
         spans = {e["id"]: e for e in model._obs.tracer.events

@@ -634,11 +634,12 @@ class TestJobSpans:
             spans = _spans(bst)
             # the call's matrix once (the first fold's), then a fold's row
             # numbers where its matrices are gathered on the device, and
-            # its row state
+            # its row state, with the held-out AUC's int8 class codes
             rows = [m.num_data, m.valid_sets[0][1].shape[0]]
             placed = 4 * sum(rows) + sum(int(a.nbytes) for a in (
                 [m.score, m.objective.label] + [vs for _, _, vs in
-                                                m.valid_sets]))
+                                                m.valid_sets]
+                + [bst._valid_metrics[0][0].classes]))
             if fold == 0:
                 placed += int(bst.train_set.reference.binned.nbytes)
             assert snap["xfer.h2d_bytes"]["value"] == placed
@@ -657,6 +658,9 @@ class TestJobSpans:
                 h = snap[f"train.setup_seconds{{stage={stage}}}"]
                 assert h["count"] == n
             assert snap["train.eval_seconds"]["count"] == 2
+            # each held-out AUC ranked on the device, none on the host
+            assert snap["eval.auc_route{route=device}"]["value"] == 2
+            assert "eval.auc_route{route=host}" not in snap
             # Dataset.construct's own stages, from the root of the
             # fold's reference chain
             root = bst.train_set.get_ref_chain()[-1]
